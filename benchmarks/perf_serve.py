@@ -453,11 +453,12 @@ def measure_combo_kernel(batch: int = 8, shape: tuple[int, int] = (4, 12),
                          *, tile: int = 256, repeat: int = 5) -> dict:
     """Combo-reuse batched kernel vs the legacy ``(B, tiles)`` grid.
 
-    Both wrappers sit behind the same ops-level guards and are bit-
-    identical (``tests/test_kernel_parity.py``); this prices the reuse:
+    Both wrappers sit behind the same ops-level guards and agree to f32
+    rounding (``tests/test_kernel_parity.py``); this prices the reuse:
     unranking/selectors/signs paid once per rank tile instead of B
     times.  Timed in alternating pairs so machine-load drift lands on
-    both sides equally.
+    both sides equally.  CPU interpret mode only: the legacy grid does
+    not compile for the chip (ROADMAP queue 3).
     """
     import jax
     import jax.numpy as jnp
@@ -639,12 +640,25 @@ def measure_join_warmstart(families=((3, 12), (4, 10), (5, 9), (6, 8)), *,
                 front.poll(timeout=0)
                 before = set(front.alive_workers)
                 t0 = time.perf_counter()
+                # the joiner's compile cache: the store's own when warm,
+                # an empty one when cold (the entry point defers to it)
+                env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=(
+                    os.path.join(store, "xla-cache") if warm
+                    else tempfile.mkdtemp(prefix="xla_cold_", dir=store)))
+                err = tempfile.TemporaryFile(mode="w+")
                 proc = subprocess.Popen(
                     [sys.executable, "-m", "repro.launch.det_serve",
                      "--join", front.accept_address],
-                    stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+                    stdout=subprocess.DEVNULL, stderr=err, env=env)
                 deadline = time.monotonic() + 600.0
                 while len(front.alive_workers) <= len(before):
+                    if proc.poll() is not None:
+                        # e.g. a chip host whose chip this process holds:
+                        # the joiner cannot open it and says so
+                        err.seek(0)
+                        raise RuntimeError(
+                            f"joiner exited with code {proc.returncode} "
+                            f"before admission:\n{err.read()[-2000:]}")
                     if time.monotonic() > deadline:
                         raise TimeoutError("joiner never admitted")
                     time.sleep(0.005)

@@ -14,8 +14,12 @@ Two modes:
   grains via a vectorized ``scan``.  This is the faithful port of the
   paper's per-processor loop.
 * ``"flat"`` — every rank is unranked independently on-device (the
-  maximally-parallel PRAM-CRCW shape).  Requires ``C(n,m) < 2**31`` per
-  the int32 note in DESIGN.md; supports the fused Pallas kernel backend.
+  maximally-parallel PRAM-CRCW shape).  Requires ``C(n,m) < 2**31``
+  (int32 ranks); supports the fused Pallas kernel backend.
+
+With ``backend="pallas"`` both modes run the fused kernel: a device's
+grains are one contiguous rank range, handed to the kernel as
+``(q_start, count)``, so the int32 limit applies to grains too.
 
 This module is the engine's mesh backend (DESIGN_ENGINE.md): the
 ``make_*_evaluator`` makers bind the plan-time half — validation, grain
@@ -92,9 +96,12 @@ def make_distributed_evaluator(
     """
     axes = tuple(axis_names if axis_names is not None else mesh.axis_names)
     D = math.prod(mesh.shape[a] for a in axes)
+    # With the Pallas backend a device's grains are one contiguous rank
+    # range, which the kernel takes as (q_start, count): the int32 path.
+    kernel = mode == "flat" or backend == "pallas"
     total = validate_rank_space(m, n, backend=backend,
-                                mesh_grains=(mode == "grains"))
-    if mode == "flat":
+                                mesh_grains=not kernel)
+    if kernel:
         return _make_flat(m, n, mesh, axes, D, total, chunk, backend)
     G = D * grains_per_device
     if total < G:  # degenerate: fewer subsets than grains
@@ -109,6 +116,7 @@ def make_distributed_evaluator(
     spec_g = P(axes)
     rep = P()
 
+    @jax.jit
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(rep, spec_g, spec_g), out_specs=rep)
@@ -151,6 +159,7 @@ def _make_flat(m, n, mesh, axes, D, total, chunk, backend):
     num_chunks = -(-max_len // chunk)
 
     # check_vma=False: pallas_call outputs don't carry vma metadata yet
+    @jax.jit
     @functools.partial(
         shard_map, mesh=mesh, check_vma=False,
         in_specs=(P(), P(), P(axes), P(axes)), out_specs=P())
@@ -216,6 +225,7 @@ def make_batched_distributed_evaluator(
     chunk = int(min(chunk, max(max_len, 1)))
     num_chunks = -(-max_len // chunk)
 
+    @jax.jit
     @functools.partial(
         shard_map, mesh=mesh, check_vma=False,
         in_specs=(P(batch_axis), P(), P(rank_axes), P(rank_axes)),
@@ -292,6 +302,7 @@ def make_batched_distributed_grad_evaluator(
     chunk = int(min(chunk, max(max_len, 1)))
     num_chunks = -(-max_len // chunk)
 
+    @jax.jit
     @functools.partial(
         shard_map, mesh=mesh, check_vma=False,
         in_specs=(P(batch_axis), P(batch_axis), P(), P(rank_axes),

@@ -596,7 +596,7 @@ class DetEngine:
         total = validate_rank_space(
             m, n, backend=key.backend,
             mesh_grains=key.mesh is not None and not key.batched
-            and key.mode == "grains")
+            and key.mode == "grains" and key.backend == "jnp")
         if m > n:
             exe = _zeros_batched if key.batched else _zeros_scalar
             def execute(A, _exe=exe):
@@ -685,6 +685,11 @@ class DetEngine:
                     _t=table)
                 lowered = True
             except Exception:  # noqa: BLE001 — AOT is an optimization only
+                # ...on the CPU test backend.  On an accelerator a failed
+                # compile is a fault of the program, and serving the
+                # traced program instead would hide it.
+                if jax.default_backend() != "cpu":
+                    raise
                 execute, grad_execute = execute_traced, grad_traced
         return DetPlan(key=key, total=total, chunk=chunk, degenerate=False,
                        lowered=lowered, table=table, executable=execute,

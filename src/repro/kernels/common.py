@@ -1,8 +1,17 @@
-"""Shared in-kernel routines (pure jnp on loaded VMEM values).
+"""Shared in-kernel routines, lane-major.
 
-These run inside Pallas kernel bodies *and* inside plain jit (they are
-ordinary jnp programs), so the fused kernel and its oracle share one
-implementation of the math while the memory movement differs.
+The rank tile lives on the 128-wide lane axis: ``T`` ranks form one
+``(1, T)`` row, and a per-matrix quantity is an ``(S, T)`` slab with one
+matrix per sublane.  An m×m matrix is a python list of m·m slabs, so the
+Gaussian elimination, the products and the row swaps are static unrolls
+over the small m of elementwise ops on whole slabs.  The whole set of
+constructs is what Mosaic lowers: elementwise ops, plain 2-D matmuls
+without batch dims, static slices and scalar table reads — no
+``dynamic_slice``, no batched ``dot_general``, no ``reduce_prod``.
+
+The same functions run as ordinary jnp inside jit, so the fused kernels,
+their CPU interpret mode and the ``(T, …)``-layout adapters at the bottom
+share one implementation of the math.
 """
 
 from __future__ import annotations
@@ -10,115 +19,158 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["batched_det_ge", "unrank_tile", "onehot_selectors",
-           "onehot_gather_minors", "radic_signs"]
+__all__ = ["det_slabs", "unrank_lanes", "selector_slabs", "minor_slabs",
+           "sign_lanes",
+           "batched_det_ge", "unrank_tile", "onehot_gather_minors",
+           "radic_signs"]
 
+
+def det_slabs(M: list[list[jax.Array]]) -> jax.Array:
+    """Determinants of m×m matrices held as slabs: ``M[r][c]`` is an
+    ``(S, T)`` slab, one matrix per (sublane, lane).  Returns ``(S, T)``.
+
+    Gaussian elimination with partial pivoting, vectorized over every
+    slab element — the paper's reference [7] PRAM determinant replaced by
+    lane parallelism.  The pivot is the first row of largest magnitude
+    (``argmax`` semantics).  A zero pivot leaves a zero on the diagonal,
+    so a singular minor gets det 0, the exact answer.  Columns left of
+    the pivot are never read again, so swaps and updates skip them.
+    """
+    m = len(M)
+    M = [list(row) for row in M]
+    sign = jnp.ones_like(M[0][0])
+    for k in range(m - 1):
+        best = jnp.abs(M[k][k])
+        piv = jnp.full(best.shape, k, jnp.int32)
+        for r in range(k + 1, m):
+            cand = jnp.abs(M[r][k])
+            better = cand > best
+            best = jnp.where(better, cand, best)
+            piv = jnp.where(better, r, piv)
+        sign = jnp.where(piv == k, sign, -sign)
+        hits = [piv == r for r in range(k + 1, m)]
+        for c in range(k, m):
+            top = M[k][c]
+            for r, hit in zip(range(k + 1, m), hits):
+                M[k][c] = jnp.where(hit, M[r][c], M[k][c])
+                M[r][c] = jnp.where(hit, top, M[r][c])
+        pivval = M[k][k]
+        safe = jnp.where(pivval == 0, jnp.ones_like(pivval), pivval)
+        for r in range(k + 1, m):
+            f = M[r][k] / safe
+            for c in range(k + 1, m):
+                M[r][c] = M[r][c] - f * M[k][c]
+    det = sign
+    for k in range(m):
+        det = det * M[k][k]
+    return det
+
+
+def unrank_lanes(qs: jax.Array, n: int, m: int, table) -> list[jax.Array]:
+    """Combinatorial addition on a lane row: ranks ``qs (S, T)`` int32 ->
+    the 1-indexed combination as m ``(S, T)`` rows (element k of every
+    lane's combination in row k).
+
+    ``table`` is the flattened Pascal table, ``table[i * (m + 1) + j] =
+    C(i, j)``, read one scalar at a time: an SMEM ref inside a kernel, an
+    array elsewhere.  Step v looks up ``C(n - v, m - 1 - pos)`` per lane
+    as a select over the m positions a lane can be at.
+    """
+
+    def step(v, carry):
+        pos, q, combo = carry
+        row = (n - v) * (m + 1) + m - 1
+        at = [pos == p for p in range(m)]
+        cnt = jnp.zeros_like(q)
+        for p in range(m):
+            cnt = jnp.where(at[p], table[row - p], cnt)
+        active = pos < m
+        place = active & (q < cnt)
+        combo = tuple(jnp.where(place & at[p], v, c)
+                      for p, c in enumerate(combo))
+        q = jnp.where(active & ~place, q - cnt, q)
+        return pos + place.astype(pos.dtype), q, combo
+
+    zero = jnp.zeros_like(qs)
+    _, _, combo = jax.lax.fori_loop(1, n + 1, step,
+                                    (zero, qs, (zero,) * m))
+    return list(combo)
+
+
+def selector_slabs(combos: list[jax.Array], n: int) -> list[jax.Array]:
+    """One-hot column selectors of a tile: m ``(1, T)`` 1-indexed rows ->
+    m ``(n, T)`` f32 slabs, ``sel[k][j, t] = (combos[k][t] == j + 1)``.
+    They depend on the rank tile only, so a kernel builds them once and
+    gathers every matrix of its batch with them."""
+    T = combos[0].shape[1]
+    jidx = jax.lax.broadcasted_iota(jnp.int32, (n, T), 0)
+    return [(jidx == c - 1).astype(jnp.float32) for c in combos]
+
+
+def minor_slabs(A: jax.Array, sel: list[jax.Array]
+                ) -> list[list[jax.Array]]:
+    """Column gather as MXU matmuls: ``A (m·S, n)`` holds row a of matrix
+    s at row ``a·S + s``; ``sel`` are the m selectors of
+    :func:`selector_slabs`.
+
+    Returns slabs ``M[k][a] (S, T) = A_s[a, combos[k] - 1]`` — the
+    transposed minor, which has the same determinant.  Each k is one
+    ``(m·S, n) @ (n, T)`` one-hot matmul at full f32 precision, so the
+    selected entries are exact.
+    """
+    m = len(sel)
+    S = A.shape[0] // m
+    M = []
+    for oh in sel:
+        cols = jax.lax.dot(A, oh, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+        M.append([cols[a * S:(a + 1) * S] for a in range(m)])
+    return M
+
+
+def sign_lanes(combos: list[jax.Array], m: int) -> jax.Array:
+    """(−1)^(r+s) per lane as f32, ``r = m(m+1)/2``, ``s`` the column sum."""
+    s = combos[0]
+    for c in combos[1:]:
+        s = s + c
+    parity = (s + m * (m + 1) // 2) & 1
+    return (1 - 2 * parity).astype(jnp.float32)
+
+
+# ------------------------------------------------- (T, …)-layout adapters
+# The standalone minor_det/unrank kernels, the legacy by-grid batched
+# kernel and the stage benchmark keep the rank-major ``(T, m)`` layout;
+# they call the lane-major math above through these adapters.
 
 def batched_det_ge(M: jax.Array) -> jax.Array:
-    """Batched determinant via Gaussian elimination w/ partial pivoting.
-
-    ``M (T, m, m) -> (T,)``.  Vectorized across the T lane dimension —
-    this replaces the paper's reference [7] PRAM determinant (see
-    DESIGN.md §2): TPUs have no per-element processors, so throughput
-    comes from lanes, not elimination-depth parallelism.  A zero pivot
-    leaves a zero on the diagonal => det 0, the mathematically correct
-    answer for a singular minor.
-    """
+    """``M (T, m, m) -> (T,)`` through :func:`det_slabs`."""
     T, m, m2 = M.shape
     assert m == m2, M.shape
-    dtype = M.dtype
     if m == 0:
-        return jnp.ones((T,), dtype)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (T, m), 1)
-
-    def step(k, carry):
-        M, sign = carry
-        colsel = (rows == k).astype(dtype)               # (T, m) picks col k
-        colM = jnp.einsum("tmn,tn->tm", M, colsel)       # column k of M
-        cand = jnp.where(rows >= k, jnp.abs(colM), -1.0)
-        piv = jnp.argmax(cand, axis=1).astype(jnp.int32)  # (T,)
-        oh_piv = rows == piv[:, None]
-        oh_k = rows == k
-        sign = sign * jnp.where(piv == k, 1.0, -1.0).astype(dtype)
-        row_piv = jnp.einsum("tm,tmn->tn", oh_piv.astype(dtype), M)
-        row_k = jnp.einsum("tm,tmn->tn", oh_k.astype(dtype), M)
-        M = jnp.where(oh_k[:, :, None], row_piv[:, None, :], M)
-        M = jnp.where(oh_piv[:, :, None] & ~oh_k[:, :, None],
-                      row_k[:, None, :], M)
-        pivval = jnp.sum(row_piv * colsel, axis=1)        # (T,)
-        safe = jnp.where(pivval == 0, 1.0, pivval).astype(dtype)
-        colM2 = jnp.einsum("tmn,tn->tm", M, colsel)
-        factors = jnp.where(rows > k, colM2 / safe[:, None], 0.0)
-        M = M - factors[:, :, None] * row_piv[:, None, :]
-        return M, sign
-
-    M, sign = jax.lax.fori_loop(0, m - 1, step,
-                                (M, jnp.ones((T,), dtype)))
-    eye = jnp.eye(m, dtype=dtype)
-    diag = jnp.sum(M * eye[None], axis=2)                 # (T, m)
-    return sign * jnp.prod(diag, axis=1)
+        return jnp.ones((T,), M.dtype)
+    slabs = [[M[:, r, c][None, :] for c in range(m)] for r in range(m)]
+    return det_slabs(slabs)[0]
 
 
 def unrank_tile(qs: jax.Array, n: int, m: int, table: jax.Array
                 ) -> jax.Array:
-    """Tile-vectorized combinatorial addition: ``(T,) -> (T, m)`` 1-indexed.
-
-    Same walk as :func:`repro.core.unrank.unrank_jnp`; kept separate so the
-    kernel body has no dependency on jit-level helpers.
-    """
-    pos = (qs * 0).astype(jnp.int32)
-    combo = jnp.broadcast_to(pos[:, None], (qs.shape[0], m))
-    cols = jax.lax.broadcasted_iota(jnp.int32, (qs.shape[0], m), 1)
-
-    def step(s, carry):
-        pos, q_rem, combo = carry
-        v = s + 1
-        colidx = jnp.clip(m - 1 - pos, 0, m)              # (T,)
-        # gather C(n-v, m-1-pos) from the table row via one-hot dot
-        row = jax.lax.dynamic_slice_in_dim(table, n - v, 1, 0)[0]  # (m+1,)
-        sel = jax.lax.broadcasted_iota(jnp.int32, (qs.shape[0], m + 1), 1)
-        # dtype pinned to the carry: under x64 an unpinned integer sum
-        # promotes int32 -> int64 and breaks the fori_loop carry type
-        cnt = jnp.sum(jnp.where(sel == colidx[:, None], row[None, :], 0),
-                      axis=1, dtype=q_rem.dtype)
-        active = pos < m
-        place = active & (q_rem < cnt)
-        combo = jnp.where(place[:, None] & (cols == pos[:, None]), v, combo)
-        q_rem = jnp.where(active & ~place, q_rem - cnt, q_rem)
-        pos = pos + place.astype(jnp.int32)
-        return pos, q_rem, combo
-
-    _, _, combo = jax.lax.fori_loop(0, n, step, (pos, qs, combo))
-    return combo
-
-
-def onehot_selectors(combos: jax.Array, n: int, dtype) -> jax.Array:
-    """One-hot column selectors: ``combos (T,m) 1-indexed -> (T,m,n)``.
-
-    Split out of :func:`onehot_gather_minors` so the combo-reuse batched
-    kernel can build the selectors once per rank tile and contract them
-    against every matrix in the batch (the selector depends only on the
-    tile, not on A).
-    """
-    T, m = combos.shape
-    jidx = jax.lax.broadcasted_iota(jnp.int32, (T, m, n), 2)
-    return (combos[:, :, None] - 1 == jidx).astype(dtype)
+    """``qs (T,) -> (T, m)`` 1-indexed, through :func:`unrank_lanes`;
+    ``table`` is the ``(n + 1, m + 1)`` Pascal table."""
+    rows = unrank_lanes(qs[None, :], n, m, table.reshape(-1))
+    return jnp.concatenate(rows, axis=0).T
 
 
 def onehot_gather_minors(A: jax.Array, combos: jax.Array) -> jax.Array:
-    """Column gather as an MXU matmul: ``A (m,n), combos (T,m) -> (T,m,m)``.
-
-    Builds one-hot selectors and contracts over n, so minors are produced
-    by the systolic array instead of scatter/gather (DESIGN.md §2).  The
-    result is the *transposed* minor — determinant-invariant.
-    """
-    oh = onehot_selectors(combos, A.shape[1], A.dtype)
-    return jnp.einsum("tkn,an->tka", oh, A,
-                      preferred_element_type=A.dtype)
+    """Column gather as a matmul: ``A (m,n), combos (T,m) -> (T,m,m)``,
+    the transposed minor (determinant-invariant)."""
+    T, m = combos.shape
+    jidx = jax.lax.broadcasted_iota(jnp.int32, (T, m, A.shape[1]), 2)
+    oh = (combos[:, :, None] - 1 == jidx).astype(A.dtype)
+    return jnp.einsum("tkn,an->tka", oh, A, preferred_element_type=A.dtype)
 
 
 def radic_signs(combos: jax.Array, m: int, dtype=jnp.float32) -> jax.Array:
-    """(−1)^(r+s) per lane."""
+    """(−1)^(r+s) per row of ``combos (T, m)``."""
     r = m * (m + 1) // 2
     parity = (jnp.sum(combos, axis=1) + r) & 1
     return (1 - 2 * parity).astype(dtype)

@@ -1,5 +1,6 @@
-"""jit'd public wrappers around the Pallas kernels (tiling/padding policy,
-interpret-mode fallback on non-TPU backends, dtype policy)."""
+"""Public wrappers around the Pallas kernels: plan-time rank guards,
+Pascal table, tile padding and dtype policy.  The kernels compile for
+the TPU and run in interpret mode on other backends (CPU tests)."""
 
 from __future__ import annotations
 
@@ -64,8 +65,7 @@ def radic_det_batched_pallas(As: jax.Array, q_start: int = 0,
     """Batched Radic determinants (or rank-range partials) for a
     shape-uniform stack ``As (B, m, n)`` via the combo-reuse fused kernel
     -> ``(B,)``.  The rank tile is unranked once per grid step and shared
-    across the batch; bit-identical to the legacy grid of
-    :func:`radic_det_batched_pallas_bygrid`."""
+    across the batch."""
     B, m, n = As.shape
     if m > n:
         return jnp.zeros((B,), As.dtype)

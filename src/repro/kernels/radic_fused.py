@@ -1,21 +1,28 @@
-"""Flagship fused Radic-partial Pallas kernel.
+"""Fused Radic-partial Pallas kernels: forward and cofactor-form backward.
 
 One kernel = the paper's whole per-processor pipeline, fused so minors
 never touch HBM:
 
-    rank tile ──unrank (VPU, n lane-uniform steps)──► combos (VMEM)
-              ──one-hot × Aᵀ (MXU matmul)──────────► minors (VMEM)
-              ──pivoted GE (VPU lanes)─────────────► dets
-              ──sign · mask · reduce───────────────► f32 partial (VMEM acc)
+    rank tile ──unrank (VPU, n static steps)────► combos, m (1, T) rows
+              ──one-hot matmuls (MXU)───────────► minor slabs (8, T)
+              ──pivoted GE (VPU, unrolled in m)─► dets (8, T)
+              ──sign · mask─────────────────────► per-lane partials
 
-HBM traffic per tile: *zero* input bytes beyond the replicated A
-(m·n·4B) and Pascal table — ranks are generated from the grid index.
-Arithmetic intensity is therefore ~(2m²n + ⅔m³ + O(mn)) FLOPs per 0
-streamed bytes: firmly compute-bound, the best case for the roofline
-(see EXPERIMENTS.md §Perf for the measured terms).
+Layout: the T ranks of a tile sit on the 128-wide lane axis and the
+batch on the sublanes, 8 matrices (one f32 sublane tile) per group.  A
+grid step unranks its tile once, builds the selectors and signs once,
+then walks the batch group by group, so that shared work is paid once
+per tile and not once per matrix.  The batch enters the kernel
+group-major, ``(G, 8·m, n)`` with row ``a·8 + s`` of group g holding row
+a of matrix ``8g + s``; the wrappers pad B up to a multiple of 8 with
+zero matrices, whose results are sliced off.
 
-The accumulator uses the sequential-grid guarantee on TPU: grid step 0
-zeroes the (1,1) output block, every step adds its partial.
+``q_start``/``count`` and the flattened Pascal table are scalar-prefetch
+operands (SMEM).  The outputs stay resident across the sequential grid:
+the forward adds its masked per-lane terms into a ``(G, 8, T)`` block
+that the wrapper sums over lanes, the backward adds ``(G, 8·m, n)``
+gradient partials.  HBM input per tile is zero beyond the resident A
+and table — ranks are generated from the grid index.
 """
 
 from __future__ import annotations
@@ -25,80 +32,188 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import (batched_det_ge, onehot_gather_minors, onehot_selectors,
-                     radic_signs, unrank_tile)
+from .common import (batched_det_ge, det_slabs, minor_slabs,
+                     onehot_gather_minors, radic_signs, selector_slabs,
+                     sign_lanes, unrank_lanes, unrank_tile)
 
-__all__ = ["radic_fused_kernel", "radic_partial_pallas",
-           "radic_batched_kernel", "radic_batched_partial_pallas_bygrid",
-           "radic_batched_combo_kernel", "radic_batched_partial_pallas",
+__all__ = ["radic_partial_pallas", "radic_batched_combo_kernel",
+           "radic_batched_partial_pallas",
            "radic_batched_grad_combo_kernel",
-           "radic_batched_grad_partial_pallas"]
+           "radic_batched_grad_partial_pallas", "radic_batched_kernel",
+           "radic_batched_partial_pallas_bygrid", "resolve_interpret"]
+
+SUBLANES = 8  # matrices per batch group: one f32 sublane tile
 
 
-def radic_fused_kernel(n: int, m: int, tile: int,
-                       qinfo_ref, a_ref, table_ref, out_ref):
-    pid = pl.program_id(0)
-    q_start = qinfo_ref[0]
-    count = qinfo_ref[1]
-    offs = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)[:, 0]
-    offs = pid * tile + offs
-    valid = offs < count
-    qs = q_start + jnp.where(valid, offs, 0)
-    # in-kernel (T, m) unranking; guarded at the ops.py entry points
-    combos = unrank_tile(qs, n, m, table_ref[...])  # reprolint: disable=overflow-guard
-    A = a_ref[...].astype(jnp.float32)
-    minors = onehot_gather_minors(A, combos)                # (T, m, m) MXU
-    dets = batched_det_ge(minors)                           # (T,) VPU
-    signs = radic_signs(combos, m, dets.dtype)
-    part = jnp.sum(jnp.where(valid, signs * dets, 0.0))
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` -> compile for the TPU when the backend is one, run the
+    Pallas interpreter otherwise (the CPU test path)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
-    @pl.when(pid == 0)
+
+def _tile_setup(n: int, m: int, tile: int, qinfo_ref, table_ref):
+    """The rank tile of this grid step: lane validity mask, selectors and
+    signs — shared by every matrix of the batch."""
+    offs = (pl.program_id(0) * tile
+            + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1))
+    valid = offs < qinfo_ref[1]
+    qs = qinfo_ref[0] + jnp.where(valid, offs, 0)
+    # in-kernel unranking; guarded at the ops.py entry points
+    combos = unrank_lanes(qs, n, m, table_ref)  # reprolint: disable=overflow-guard
+    return valid, selector_slabs(combos, n), sign_lanes(combos, m)
+
+
+def radic_batched_combo_kernel(n: int, m: int, tile: int, groups: int,
+                               qinfo_ref, table_ref, a_ref, out_ref):
+    """Forward: per-lane Σ sign·det of this tile for every matrix."""
+    valid, sel, signs = _tile_setup(n, m, tile, qinfo_ref, table_ref)
+
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[0, 0] += part
+    def group(g, carry):
+        dets = det_slabs(minor_slabs(a_ref[g], sel))
+        out_ref[g] += jnp.where(valid, signs * dets, 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0)
+
+
+def radic_batched_grad_combo_kernel(n: int, m: int, tile: int, groups: int,
+                                    qinfo_ref, table_ref, a_ref, ct_ref,
+                                    out_ref):
+    """Cofactor-form VJP: replays the forward tile exactly — same
+    unranking, selectors, signs and GE lanes — and pulls each group's
+    cotangents back through that tile's terms (DESIGN_GRAD.md).  No
+    residual crosses the tile boundary, so backward VMEM stays the
+    forward's O(8·T·m²) per group."""
+    valid, sel, signs = _tile_setup(n, m, tile, qinfo_ref, table_ref)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    def lane_terms(a):
+        return jnp.where(valid, signs * det_slabs(minor_slabs(a, sel)), 0.0)
+
+    def group(g, carry):
+        _, pull = jax.vjp(lane_terms, a_ref[g])
+        (ga,) = pull(jnp.broadcast_to(ct_ref[g], (SUBLANES, tile)))
+        out_ref[g] += ga
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0)
+
+
+def _grouped(As: jax.Array) -> jax.Array:
+    """``(B, m, n)`` -> group-major f32 ``(G, 8·m, n)``, B padded to 8G."""
+    B, m, n = As.shape
+    G = -(-B // SUBLANES)
+    As = jnp.pad(As.astype(jnp.float32),
+                 ((0, G * SUBLANES - B), (0, 0), (0, 0)))
+    return (As.reshape(G, SUBLANES, m, n).transpose(0, 2, 1, 3)
+            .reshape(G, m * SUBLANES, n))
+
+
+def _ungrouped(a: jax.Array, B: int, m: int, n: int) -> jax.Array:
+    G = a.shape[0]
+    return (a.reshape(G, m, SUBLANES, n).transpose(0, 2, 1, 3)
+            .reshape(G * SUBLANES, m, n)[:B])
+
+
+def _pallas_tiles(body, padded_count: int, tile: int, table, q_start, count,
+                  operands, out_shape, interpret):
+    """One sequential grid step per rank tile; every operand and the
+    output are whole-array blocks resident in VMEM."""
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
+
+    qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
+                       jnp.asarray(count, jnp.int32)])
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(max(1, -(-padded_count // tile)),),
+            in_specs=[whole(x.shape) for x in operands],
+            out_specs=whole(out_shape)),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=resolve_interpret(interpret),
+    )(qinfo, table.astype(jnp.int32).reshape(-1), *operands)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("padded_count", "tile", "interpret"))
+def radic_batched_partial_pallas(As: jax.Array, table: jax.Array,
+                                 q_start: jax.Array | int,
+                                 count: jax.Array | int,
+                                 padded_count: int, *, tile: int = 256,
+                                 interpret: bool | None = None) -> jax.Array:
+    """Per-matrix Σ sign·det over ranks [q_start, q_start+count) for a
+    shape-uniform stack ``As (B, m, n)`` -> ``(B,)``; ``padded_count`` is
+    the static grid extent (≥ count, tile-aligned)."""
+    B, m, n = As.shape
+    a = _grouped(As)
+    G = a.shape[0]
+    out = _pallas_tiles(
+        functools.partial(radic_batched_combo_kernel, n, m, tile, G),
+        padded_count, tile, table, q_start, count, [a],
+        (G, SUBLANES, tile), interpret)
+    return out.reshape(G * SUBLANES, tile)[:B].sum(axis=1).astype(As.dtype)
+
+
 def radic_partial_pallas(A: jax.Array, table: jax.Array,
                          q_start: jax.Array | int, count: jax.Array | int,
                          padded_count: int, *, tile: int = 256,
                          interpret: bool | None = None) -> jax.Array:
-    """Σ sign·det over ranks [q_start, q_start+count); ``padded_count`` is
-    the static grid extent (≥ count, tile-aligned)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    m, n = A.shape
-    grid = max(1, -(-padded_count // tile))
-    qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
-                       jnp.asarray(count, jnp.int32)])
-    out = pl.pallas_call(
-        functools.partial(radic_fused_kernel, n, m, tile),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((m, n), lambda i: (0, 0)),
-            pl.BlockSpec((n + 1, m + 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        interpret=interpret,
-    )(qinfo, A, table.astype(jnp.int32))
-    return out[0, 0].astype(A.dtype)
+    """Σ sign·det over ranks [q_start, q_start+count) of one ``A (m, n)``:
+    the batched kernel at B = 1."""
+    return radic_batched_partial_pallas(
+        A[None], table, q_start, count, padded_count, tile=tile,
+        interpret=interpret)[0]
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("padded_count", "tile", "interpret"))
+def radic_batched_grad_partial_pallas(As: jax.Array, cts: jax.Array,
+                                      table: jax.Array,
+                                      q_start: jax.Array | int,
+                                      count: jax.Array | int,
+                                      padded_count: int, *, tile: int = 256,
+                                      interpret: bool | None = None
+                                      ) -> jax.Array:
+    """Gradient partial over ranks [q_start, q_start+count): pulls the
+    per-matrix cotangents ``cts (B,)`` back through the rank range for a
+    stack ``As (B, m, n)`` -> ``(B, m, n)``."""
+    B, m, n = As.shape
+    a = _grouped(As)
+    G = a.shape[0]
+    c = jnp.pad(cts.astype(jnp.float32),
+                (0, G * SUBLANES - B)).reshape(G, SUBLANES, 1)
+    out = _pallas_tiles(
+        functools.partial(radic_batched_grad_combo_kernel, n, m, tile, G),
+        padded_count, tile, table, q_start, count, [a, c],
+        (G, SUBLANES * m, n), interpret)
+    return _ungrouped(out, B, m, n).astype(As.dtype)
+
+
+# ------------------------------------------------------ legacy by-grid path
 def radic_batched_kernel(n: int, m: int, tile: int,
                          qinfo_ref, a_ref, table_ref, out_ref):
     """Legacy batched variant: grid (B, num_tiles); block b sees matrix b.
 
-    The rank tile (unranking + signs + selectors) is recomputed per
-    (b, tile) cell.  Superseded as the default by
-    :func:`radic_batched_combo_kernel`, which hoists that shared work out
-    of the batch dimension; this grid is kept as the bit-identity
-    reference (``tests/test_kernel_parity.py``) and the benchmark
-    baseline the combo kernel is priced against.
+    Recomputes the rank tile per (b, tile) cell in the rank-major
+    ``(T, m)`` layout.  Interpret mode only — Mosaic refuses its 1-D
+    ``qinfo`` block and ``(1, 1)`` output block; kept as the CPU
+    reference the combo kernel is checked and priced against.
     """
     pid = pl.program_id(1)
     q_start = qinfo_ref[0]
@@ -110,8 +225,8 @@ def radic_batched_kernel(n: int, m: int, tile: int,
     # in-kernel (T, m) unranking; guarded at the ops.py entry points
     combos = unrank_tile(qs, n, m, table_ref[...])  # reprolint: disable=overflow-guard
     A = a_ref[0].astype(jnp.float32)                        # block (1, m, n)
-    minors = onehot_gather_minors(A, combos)                # (T, m, m) MXU
-    dets = batched_det_ge(minors)                           # (T,) VPU
+    minors = onehot_gather_minors(A, combos)                # (T, m, m)
+    dets = batched_det_ge(minors)                           # (T,)
     signs = radic_signs(combos, m, dets.dtype)
     part = jnp.sum(jnp.where(valid, signs * dets, 0.0))
 
@@ -132,8 +247,6 @@ def radic_batched_partial_pallas_bygrid(As: jax.Array, table: jax.Array,
                                         ) -> jax.Array:
     """Legacy (B, num_tiles)-grid batched partial — reference only; the
     serving path dispatches :func:`radic_batched_partial_pallas`."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, m, n = As.shape
     grid = (B, max(1, -(-padded_count // tile)))
     qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
@@ -148,166 +261,6 @@ def radic_batched_partial_pallas_bygrid(As: jax.Array, table: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        interpret=interpret,
-    )(qinfo, As, table.astype(jnp.int32))
-    return out[:, 0].astype(As.dtype)
-
-
-def radic_batched_combo_kernel(n: int, m: int, tile: int, batch: int,
-                               qinfo_ref, a_ref, table_ref, out_ref):
-    """Combo-reuse batched variant: grid (num_tiles,), batch in-kernel.
-
-    Each grid step unranks its rank tile *once*, builds the one-hot
-    column selectors and signs once, then contracts the selectors
-    against the whole VMEM-resident ``(B, m, n)`` stack in one MXU
-    einsum and runs one GE over the flattened ``(B·T, m, m)`` lanes —
-    the per-(b, tile) recompute of the legacy grid is gone, so the
-    shared VPU work (unranking, selectors, signs) is paid once per tile
-    instead of B times.  Per-lane math is unchanged (same contraction
-    order over n, same GE steps, same masked per-row reduce over T), so
-    results are bit-identical to the legacy grid; the parity tests
-    assert exact equality.
-
-    VMEM holds the batch block plus the (B·T, m, m) minor stack — fine
-    for serving capacities (``BucketPolicy.max_batch <= 64`` with small
-    m); huge B × tile products should shrink ``tile``.
-    """
-    pid = pl.program_id(0)
-    q_start = qinfo_ref[0]
-    count = qinfo_ref[1]
-    offs = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)[:, 0]
-    offs = pid * tile + offs
-    valid = offs < count
-    qs = q_start + jnp.where(valid, offs, 0)
-    # in-kernel (T, m) unranking; guarded at the ops.py entry points
-    combos = unrank_tile(qs, n, m, table_ref[...])  # reprolint: disable=overflow-guard
-    oh = onehot_selectors(combos, n, jnp.float32)           # (T, m, n) once
-    signs = radic_signs(combos, m, jnp.float32)             # (T,) once
-    As = a_ref[...].astype(jnp.float32)                     # (B, m, n)
-    minors = jnp.einsum("tkn,ban->btka", oh, As,
-                        preferred_element_type=jnp.float32)
-    dets = batched_det_ge(minors.reshape(batch * tile, m, m))
-    dets = dets.reshape(batch, tile)                        # (B, T) VPU
-    parts = jnp.sum(jnp.where(valid[None, :], signs[None, :] * dets, 0.0),
-                    axis=1)
-
-    @pl.when(pid == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += parts[:, None]
-
-
-def radic_batched_grad_combo_kernel(n: int, m: int, tile: int, batch: int,
-                                    qinfo_ref, a_ref, ct_ref, table_ref,
-                                    out_ref):
-    """Cofactor-form VJP of the combo-reuse batched kernel.
-
-    Each grid step replays its forward tile exactly — same unranking,
-    same one-hot selectors, same signs, same GE lanes — then pulls the
-    per-matrix cotangents ``(B,)`` back through that tile's minor-sum
-    with ``jax.vjp`` and accumulates ``(B, m, n)`` gradient partials in
-    the sequential-grid output block.  The rank walk is shared with the
-    forward by construction (DESIGN_GRAD.md): no residual minors cross
-    the tile boundary, so backward VMEM is the same O(B·T·m²) as
-    forward.
-    """
-    pid = pl.program_id(0)
-    q_start = qinfo_ref[0]
-    count = qinfo_ref[1]
-    offs = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)[:, 0]
-    offs = pid * tile + offs
-    valid = offs < count
-    qs = q_start + jnp.where(valid, offs, 0)
-    # in-kernel (T, m) unranking; guarded at the ops.py entry points
-    combos = unrank_tile(qs, n, m, table_ref[...])  # reprolint: disable=overflow-guard
-    oh = onehot_selectors(combos, n, jnp.float32)           # (T, m, n) once
-    signs = radic_signs(combos, m, jnp.float32)             # (T,) once
-    As = a_ref[...].astype(jnp.float32)                     # (B, m, n)
-    cts = ct_ref[...].astype(jnp.float32)                   # (B,)
-
-    def tile_partials(a):
-        minors = jnp.einsum("tkn,ban->btka", oh, a,
-                            preferred_element_type=jnp.float32)
-        dets = batched_det_ge(minors.reshape(batch * tile, m, m))
-        dets = dets.reshape(batch, tile)                    # (B, T)
-        return jnp.sum(
-            jnp.where(valid[None, :], signs[None, :] * dets, 0.0), axis=1)
-
-    _, pull = jax.vjp(tile_partials, As)
-    (gAs,) = pull(cts)
-
-    @pl.when(pid == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += gAs
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("padded_count", "tile", "interpret"))
-def radic_batched_grad_partial_pallas(As: jax.Array, cts: jax.Array,
-                                      table: jax.Array,
-                                      q_start: jax.Array | int,
-                                      count: jax.Array | int,
-                                      padded_count: int, *, tile: int = 256,
-                                      interpret: bool | None = None
-                                      ) -> jax.Array:
-    """Gradient partial over ranks [q_start, q_start+count): pulls the
-    per-matrix cotangents ``cts (B,)`` back through the rank range for a
-    stack ``As (B, m, n)`` -> ``(B, m, n)``."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    B, m, n = As.shape
-    grid = (max(1, -(-padded_count // tile)),)
-    qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
-                       jnp.asarray(count, jnp.int32)])
-    out = pl.pallas_call(
-        functools.partial(radic_batched_grad_combo_kernel, n, m, tile, B),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((B, m, n), lambda i: (0, 0, 0)),
-            pl.BlockSpec((B,), lambda i: (0,)),
-            pl.BlockSpec((n + 1, m + 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((B, m, n), lambda i: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, m, n), jnp.float32),
-        interpret=interpret,
-    )(qinfo, As, cts, table.astype(jnp.int32))
-    return out.astype(As.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("padded_count", "tile", "interpret"))
-def radic_batched_partial_pallas(As: jax.Array, table: jax.Array,
-                                 q_start: jax.Array | int,
-                                 count: jax.Array | int,
-                                 padded_count: int, *, tile: int = 256,
-                                 interpret: bool | None = None) -> jax.Array:
-    """Per-matrix Σ sign·det over ranks [q_start, q_start+count) for a
-    shape-uniform stack ``As (B, m, n)`` -> ``(B,)``.
-
-    Dispatches the combo-reuse kernel (tile in the grid axis, batch in a
-    VMEM-resident in-kernel loop); bit-identical to the legacy
-    ``(B, num_tiles)`` grid of :func:`radic_batched_partial_pallas_bygrid`.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    B, m, n = As.shape
-    grid = (max(1, -(-padded_count // tile)),)
-    qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
-                       jnp.asarray(count, jnp.int32)])
-    out = pl.pallas_call(
-        functools.partial(radic_batched_combo_kernel, n, m, tile, B),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((B, m, n), lambda i: (0, 0, 0)),
-            pl.BlockSpec((n + 1, m + 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((B, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qinfo, As, table.astype(jnp.int32))
     return out[:, 0].astype(As.dtype)

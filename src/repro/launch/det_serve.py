@@ -56,6 +56,7 @@ import numpy as np
 from repro.core import comb, radic_det_batched
 from repro.launch.det_queue import (BucketPolicy, DetQueue, LoadShedError,
                                     bucket_by_shape, pad_capacity)
+from repro.parallel.compat import enable_entry_point_cache
 
 __all__ = ["bucket_by_shape", "pad_capacity", "drain_queue", "main"]
 
@@ -293,6 +294,7 @@ def main(argv=None):
         ap.error("--grad-frac must be in [0, 1]")
     if args.grad_frac > 0 and args.sync:
         ap.error("--grad-frac needs the async or front path (drop --sync)")
+    enable_entry_point_cache()  # before any engine exists
 
     if args.listen:
         # worker daemon mode: no synthetic queue, no report — just a

@@ -7,14 +7,23 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_rules"]
+__all__ = ["make_mesh", "make_production_mesh", "make_rules"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules place arrays
+    with ``NamedSharding`` and let the compiler propagate the rest (jax
+    0.9 defaults to Explicit axes, which would demand sharding-typed
+    operands everywhere)."""
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_rules(cfg, mesh, *, log_fallbacks: bool = False):

@@ -360,7 +360,20 @@ def _local_worker_main(worker_id: int, cfg: WorkerConfig, req_q, resp_conn,
             os.sched_setaffinity(0, {worker_id % (os.cpu_count() or 1)})
         except OSError:
             pass
+    from repro.parallel.compat import enable_entry_point_cache
+    enable_entry_point_cache()
     cfg.apply_x64()
+    # Open the device before anything else and tell the front how that
+    # went (LocalLink.await_up): a chip belongs to one process, and a
+    # worker that cannot have it must fail the pool's start-up with its
+    # own error rather than die later and be re-routed around.
+    try:
+        import jax
+        platform = jax.devices()[0].platform
+    except Exception as e:  # noqa: BLE001 — reported, then re-raised
+        resp_conn.send(("down", worker_id, f"{type(e).__name__}: {e}"))
+        raise
+    resp_conn.send(("up", worker_id, platform))
     reader = None
     recv, recv_nowait = req_q.get, req_q.get_nowait
     if shm_name is not None:
@@ -512,6 +525,33 @@ class LocalLink(WorkerLink):
         # always surfaces results that beat the crash
         return msgs, not self.process.is_alive()
 
+    def await_up(self, timeout: float) -> str:
+        """Block until the worker reports on its device; returns the
+        platform it opened.  Raises :class:`TransportError` with the
+        worker's own error if it could not open one, exited, or stayed
+        silent past ``timeout`` (as a worker waiting on a chip that
+        another process holds would)."""
+        deadline = time.monotonic() + timeout
+        while not self._conn.poll(0.1):
+            if not self.process.is_alive():
+                raise TransportError(
+                    f"worker {self.id} exited with code "
+                    f"{self.process.exitcode} before opening its device")
+            if time.monotonic() > deadline:
+                raise TransportError(
+                    f"worker {self.id} did not open its device within "
+                    f"{timeout:.0f} s; a chip belongs to one process, "
+                    "and another may hold it")
+        try:
+            msg = self._conn.recv()
+        except (EOFError, OSError) as e:
+            raise TransportError(
+                f"worker {self.id} exited before opening its device") from e
+        if msg[0] != "up":
+            raise TransportError(
+                f"worker {self.id} could not open its device: {msg[2]}")
+        return msg[2]
+
     def kill(self) -> None:
         self.process.kill()
 
@@ -555,22 +595,42 @@ class LocalTransport(Transport):
         send_conn.close()  # child owns the send end now
         return LocalLink(wid, proc, req_q, recv_conn)
 
+    # spawn + jax import + device open on a loaded host
+    UP_TIMEOUT_S = 300.0
+
+    def _up(self, links: list[LocalLink]) -> list[LocalLink]:
+        """Wait until every new worker has opened its device, all on one
+        platform; on any failure stop them all and raise."""
+        try:
+            platforms = [link.await_up(self.UP_TIMEOUT_S) for link in links]
+            if len(set(platforms)) > 1:
+                raise TransportError(
+                    f"workers opened different platforms {platforms}")
+        except TransportError:
+            for link in links:
+                link.kill()
+                link.close()
+                link.join(timeout=5)
+            raise
+        return links
+
     def start(self, cfg: WorkerConfig) -> list[WorkerLink]:
         self._cfg = cfg
-        return [self._spawn(wid, cfg) for wid in range(self.workers)]
+        return self._up([self._spawn(wid, cfg)
+                         for wid in range(self.workers)])
 
     def redial(self, wid: int) -> WorkerLink | None:
         """Respawn a dead worker's process under the same id."""
         if self._cfg is None:
             return None
-        return self._spawn(wid, self._cfg)
+        return self._up([self._spawn(wid, self._cfg)])[0]
 
     def dial_new(self, wid: int, prefill=None) -> WorkerLink | None:
         """Spawn one more worker process (scale-up is unbounded locally;
         the autoscaler's ``max_workers`` is the policy bound)."""
         if self._cfg is None:
             return None
-        return self._spawn(wid, self._cfg, prefill)
+        return self._up([self._spawn(wid, self._cfg, prefill)])[0]
 
 
 # ------------------------------------------------------- shared-memory ring
@@ -1089,6 +1149,14 @@ def _serve_front_session(conn: socket.socket, addr, log) -> None:
     heartbeat_s = float(wire_cfg.get("heartbeat_s", 1.0))
     conn.settimeout(None)
     cfg.apply_x64()
+    try:
+        # the device first, as a local worker does: a daemon that cannot
+        # open its chip answers the front with its own error, not ready
+        import jax
+        jax.devices()
+    except Exception as e:  # noqa: BLE001 — reported, then re-raised
+        conn.sendall(encode_frame(("down", wid, f"{type(e).__name__}: {e}")))
+        raise
     q = cfg.make_queue()
     prefill = wire_cfg.get("prefill")
     if prefill:
@@ -1252,6 +1320,10 @@ def spawn_worker_daemon(host: str = "127.0.0.1", port: int = 0, *,
             break
         m = re.search(r"det-worker listening on ([\d.]+):(\d+)", line)
         if m:
+            # keep draining the daemon's output: a full pipe would block
+            # its next log line, and with it the daemon
+            threading.Thread(target=proc.stdout.read, name="det-daemon-out",
+                             daemon=True).start()
             return proc, f"{m.group(1)}:{m.group(2)}"
     proc.kill()
     raise TransportError(

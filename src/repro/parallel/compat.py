@@ -1,141 +1,84 @@
-"""Version-portable parallel primitives — the single jax-compat seam.
+"""The single seam between the repo and jax's per-device SPMD surface.
 
-jax has renamed its per-device SPMD surface twice across the versions this
-repo must run on; every call site in the repo goes through this module so
-the rest of the codebase is spelled one way.
+Every mesh call site goes through this module, so the rest of the code
+is spelled one way and a jax rename touches one file.  The supported
+jax is the installed one, 0.9 (DESIGN_COMPAT.md):
 
-Compat policy (the supported spellings):
-
-* ``shard_map`` — resolves, in order:
-
-  1. ``jax.shard_map`` (jax >= 0.5 public API), keyword-only params,
-     replication check spelled ``check_vma``;
-  2. ``jax.experimental.shard_map.shard_map`` (jax 0.4.x), positional
-     params, replication check spelled ``check_rep``.
-
-  The wrapper accepts *either* ``check_vma`` or ``check_rep`` and
-  translates to whatever the resolved function understands.  If the
-  native function understands neither (a future rename), the flag is
-  dropped: the check is purely diagnostic, never load-bearing.
-
-* ``pvary`` — marks a replicated value as device-varying so it can enter
-  collectives under the new varying-manual-axes (VMA) type system.
-  Resolves ``jax.lax.pvary`` → ``jax.lax.pcast(..., to="varying")``
-  (transitional spelling) → identity (jax 0.4.x has no VMA types, so
-  replicated values flow into collectives unannotated).
-
+* ``shard_map`` — ``jax.shard_map`` with its ``check_vma`` flag.
+* ``pvary`` — marks a value as device-varying so it can enter
+  collectives under the varying-manual-axes (VMA) type system.  Only the
+  axes the value does not already vary over are cast, with
+  ``jax.lax.pcast(..., to="varying")``; a cast over an axis the operand
+  already varies over is an error in jax.
 * ``psum_scalar`` — ``pvary`` + one ``psum`` per mesh axis name.  This is
   the repo's reduction idiom for grain/chunk partials; keeping it here
   means call sites never touch ``jax.lax.psum`` axis plumbing directly.
+* ``axis_size`` — ``jax.lax.axis_size``.
 
 No other module may read ``jax.shard_map`` / ``jax.experimental.
-shard_map`` / ``jax.lax.pvary`` / ``jax.lax.pcast`` — tests enforce the
-``shard_map`` half of that by grepping the source tree.
+shard_map`` / ``jax.lax.pvary`` / ``jax.lax.pcast`` — reprolint's
+``compat-seam`` pass enforces the ``shard_map`` half.
 """
 
 from __future__ import annotations
 
-import inspect
 import os
+from pathlib import Path
 from typing import Callable, Sequence
 
 import jax
 
 __all__ = ["shard_map", "pvary", "psum_scalar", "axis_size",
-           "native_shard_map_source", "export_supported",
-           "serialize_lowered", "deserialize_exported",
-           "enable_compilation_cache"]
-
-
-def _native_shard_map() -> tuple[Callable, str]:
-    """The installed jax's shard_map and where it came from.
-
-    Resolved per call (it is trace-time only, cost is negligible) so tests
-    can monkeypatch either spelling.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, "jax.shard_map"
-    from jax.experimental import shard_map as _sm  # jax 0.4.x
-    return _sm.shard_map, "jax.experimental.shard_map.shard_map"
-
-
-def native_shard_map_source() -> str:
-    """Which spelling this process resolved to (for logs/diagnostics)."""
-    return _native_shard_map()[1]
+           "export_supported", "serialize_lowered", "deserialize_exported",
+           "enable_compilation_cache",
+           "enable_entry_point_cache"]
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_vma: bool | None = None,
-              check_rep: bool | None = None, **kwargs):
-    """Portable ``shard_map``: maps ``f`` over shards of a mesh.
-
-    Accepts the replication-check flag under either historical name
-    (``check_vma`` — new jax; ``check_rep`` — jax 0.4.x) and forwards it
-    under whichever name the installed jax understands.  ``f`` is the only
+              check_vma: bool | None = None):
+    """``jax.shard_map`` of ``f`` over ``mesh``.  ``f`` is the only
     positional argument, so ``functools.partial(shard_map, mesh=...,
-    in_specs=..., out_specs=...)`` works as a decorator on every version.
-    """
-    if check_vma is not None and check_rep is not None:
-        raise TypeError("pass check_vma or check_rep, not both")
-    check = check_vma if check_vma is not None else check_rep
-    fn, _ = _native_shard_map()
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-    if check is not None:
-        params = inspect.signature(fn).parameters
-        if "check_vma" in params:
-            kw["check_vma"] = check
-        elif "check_rep" in params:
-            kw["check_rep"] = check
-        # else: diagnostic flag unknown to this jax — drop it.
-    return fn(f, **kw)
+    in_specs=..., out_specs=...)`` works as a decorator; ``check_vma``
+    left at None keeps jax's default."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def pvary(x, axis_names: Sequence[str]):
-    """Mark ``x`` as varying over ``axis_names`` inside shard_map.
-
-    Identity on jax versions without the VMA type system.
-    """
-    axes = tuple(axis_names)
-    if not axes:
+    """Mark ``x`` as varying over ``axis_names`` inside shard_map; axes
+    ``x`` already varies over are left as they are."""
+    vma = jax.typeof(x).vma
+    missing = tuple(a for a in axis_names if a not in vma)
+    if not missing:
         return x
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)
-    if hasattr(jax.lax, "pcast"):  # transitional spelling
-        return jax.lax.pcast(x, axes, to="varying")
-    return x
+    return jax.lax.pcast(x, missing, to="varying")
 
 
 def axis_size(axis_name: str):
-    """Size of a bound mesh axis (``jax.lax.axis_size`` is new-jax only).
-
-    The jax 0.4.x fallback ``psum(1, axis)`` yields the same value as a
-    (constant) array, which every call site uses purely arithmetically.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of a bound mesh axis."""
+    return jax.lax.axis_size(axis_name)
 
 
 # --------------------------------------------------------- AOT export seam
 #
-# ``jax.export`` (serialize a lowered/compiled program to bytes, reload it
-# in another process) stabilized across 0.4.x under several spellings and
-# may be absent entirely.  The plan store treats export as a pure
+# ``jax.export`` serializes a lowered/compiled program to bytes and
+# reloads it in another process.  The plan store treats export as a pure
 # acceleration: when any of these return None the store falls back to
 # metadata-only persistence (re-lower from cached statics), which is
 # always correct — so every failure path below degrades, never raises.
 #
-# Blob reload is additionally **opt-in** (``REPRO_PLAN_BLOBS=1``): on the
-# pinned 0.4.x CPU leg a reloaded executable whose program contains LAPACK
-# custom calls (every ``jnp.linalg`` LU — i.e. every determinant program in
-# this repo) segfaults at first call, because the serialized form bakes in
-# native custom-call pointers that do not survive the process boundary.
-# That failure is a hard crash, not an exception, so it cannot be caught
-# and degraded at use time — it has to be gated off up front.  The safe
-# cross-process compile-skip on such legs is the XLA persistent
-# compilation cache (:func:`enable_compilation_cache` below), which is
-# content-addressed and re-links custom calls at load.
+# Blob reload is additionally **opt-in** (``REPRO_PLAN_BLOBS=1``): on
+# jax 0.4.37's CPU backend (measured there, not since) a reloaded
+# executable whose program contains LAPACK custom calls (every
+# ``jnp.linalg`` LU — i.e. every determinant program in this repo)
+# segfaulted at first call, because the serialized form bakes in native
+# custom-call pointers that do not survive the process boundary.  That
+# failure is a hard crash, not an exception, so it cannot be caught and
+# degraded at use time — it has to be gated off up front.  The safe
+# cross-process compile-skip is the XLA persistent compilation cache
+# (:func:`enable_compilation_cache` below), which is content-addressed
+# and re-links custom calls at load.
 
 _BLOBS_ENV = "REPRO_PLAN_BLOBS"
 
@@ -158,39 +101,45 @@ def export_supported() -> bool:
     return _export_module() is not None
 
 
+def enable_entry_point_cache() -> bool:
+    """The entry points' compile cache, set before any engine exists:
+    the dir ``JAX_COMPILATION_CACHE_DIR`` names (jax reads it itself),
+    else ``<checkout>/.jax_cache`` — a fixed path, because the path is
+    part of the cache key.  A process pinned to the CPU
+    (``JAX_PLATFORMS=cpu``: tests, CPU debugging) gets none — its
+    compiles are cheap, and a shared on-disk cache would couple the
+    timing of one run to the runs before it."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return False
+    return enable_compilation_cache(
+        str(Path(__file__).resolve().parents[3] / ".jax_cache"))
+
+
 def enable_compilation_cache(path: str) -> bool:
     """Point jax's persistent compilation cache at ``path``; True if on.
 
-    The plan store calls this with ``<persist_dir>/xla-cache`` so that a
-    warm-started process skips the XLA compile of every program any prior
-    process against the same store already built — the compile-skip
-    channel that works even where blob reload is unsafe (see above).
-    Idempotent and deferential: an already-configured cache dir (user or
-    earlier engine) is left untouched, and missing config options on
-    older jax degrade to False, never raise.
+    Deferential: a cache dir already configured — by
+    ``JAX_COMPILATION_CACHE_DIR``, the user, or an earlier call — is left
+    untouched.  Entry points call it through
+    :func:`enable_entry_point_cache`, the plan store with
+    ``<persist_dir>/xla-cache`` (DESIGN_PERSIST.md),
+    which therefore applies only where nothing was configured before.
+    Failures raise: a misconfigured cache must not turn into a silent
+    cold compile of every program.
     """
-    try:
-        if jax.config.jax_compilation_cache_dir is not None:
-            return True
-        jax.config.update("jax_compilation_cache_dir", path)
-    except Exception:
-        return False
-    try:
-        # Plan-family compiles are the whole point of the cache here, and
-        # some are quick — cache everything, not just slow compiles.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:
-        pass  # thresholds unavailable: defaults still cache slow compiles
-    try:
-        # jax latches cache-off at the first compile of the process; if
-        # anything compiled before us (warm-up jits, an import-time
-        # trace), the dir we just set is silently ignored.  reset_cache
-        # drops the latch so the next compile re-reads the config.
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass  # no latch on this jax: the config update alone suffices
+    if jax.config.jax_compilation_cache_dir is not None:
+        return True
+    jax.config.update("jax_compilation_cache_dir", path)
+    # Plan-family compiles are the whole point of the cache here, and
+    # some are quick — cache everything, not just slow compiles.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # jax latches cache-off at the first compile of the process; if
+    # anything compiled before us (warm-up jits, an import-time trace),
+    # the dir just set is ignored.  reset_cache drops the latch so the
+    # next compile re-reads the config.
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+    _cc.reset_cache()
     return True
 
 
@@ -233,9 +182,9 @@ def deserialize_exported(blob: bytes):
 def psum_scalar(x, axis_names: Sequence[str]):
     """Sum ``x`` over every named mesh axis (inside shard_map).
 
-    Works on replicated *or* varying operands on both old and new jax:
-    the operand is first ``pvary``'d (no-op where unsupported/already
-    varying), then reduced one axis at a time.
+    Works on replicated *or* varying operands: the operand is first
+    ``pvary``'d over the axes it does not vary over yet, then reduced
+    one axis at a time.
     """
     axes = tuple(axis_names)
     if not axes:

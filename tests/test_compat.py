@@ -1,15 +1,13 @@
-"""Direct unit tests for the jax-version compat seam.
+"""Direct unit tests for the jax seam.
 
 The subprocess mesh tests (test_distributed / test_pipeline) prove the
-end-to-end paths, but bury any compat regression inside an ``assert "OK"
-in stdout``.  These tests exercise both historical shard_map spellings
-in-process via monkeypatch so a translation bug fails with a readable
-message, plus the pvary/pcast/no-op ladder and the reduction helpers on
-a real single-device mesh.
+end-to-end paths, but bury any seam regression inside an ``assert "OK"
+in stdout``.  These tests check the forwarding to ``jax.shard_map`` via
+monkeypatch, and ``pvary`` plus the reduction helpers on a real
+single-device mesh, so a regression fails with a readable message.
 """
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -24,99 +22,104 @@ def _ident(x):
     return x
 
 
-def test_resolves_installed_jax():
-    fn, src = compat._native_shard_map()
-    assert callable(fn)
-    assert src in ("jax.shard_map", "jax.experimental.shard_map.shard_map")
-    assert compat.native_shard_map_source() == src
-
-
-def test_new_spelling_gets_check_vma(monkeypatch):
-    captured = {}
-
+def _fake_shard_map(monkeypatch, captured):
     def fake(f, *, mesh, in_specs, out_specs, check_vma=True):
         captured.update(f=f, mesh=mesh, in_specs=in_specs,
                         out_specs=out_specs, check_vma=check_vma)
         return "mapped"
 
-    monkeypatch.setattr(compat, "_native_shard_map",
-                        lambda: (fake, "jax.shard_map"))
-    out = compat.shard_map(_ident, mesh="M", in_specs=(P(),),
-                           out_specs=P(), check_vma=False)
-    assert out == "mapped"
-    assert captured["check_vma"] is False
-    assert captured["mesh"] == "M" and captured["f"] is _ident
-
-    # old-spelling kwarg from a caller is translated forward
-    compat.shard_map(_ident, mesh="M", in_specs=(), out_specs=P(),
-                     check_rep=False)
-    assert captured["check_vma"] is False
+    monkeypatch.setattr(jax, "shard_map", fake)
 
 
-def test_old_spelling_gets_check_rep(monkeypatch):
+def test_resolves_installed_jax(monkeypatch):
     captured = {}
+    _fake_shard_map(monkeypatch, captured)
+    assert compat.shard_map(_ident, mesh="M", in_specs=(P(),),
+                            out_specs=P()) == "mapped"
+    assert captured["f"] is _ident and captured["mesh"] == "M"
+    assert captured["in_specs"] == (P(),) and captured["out_specs"] == P()
 
-    def fake(f, mesh, in_specs, out_specs, check_rep=True,
-             auto=frozenset()):
-        captured.update(f=f, mesh=mesh, check_rep=check_rep)
-        return "mapped"
 
-    monkeypatch.setattr(compat, "_native_shard_map",
-                        lambda: (fake, "jax.experimental.shard_map.shard_map"))
+def test_new_spelling_gets_check_vma(monkeypatch):
+    captured = {}
+    _fake_shard_map(monkeypatch, captured)
     out = compat.shard_map(_ident, mesh="M", in_specs=(P(),),
                            out_specs=P(), check_vma=False)
     assert out == "mapped"
-    assert captured["check_rep"] is False
-    assert "check_vma" not in inspect.signature(fake).parameters
+    assert captured["check_vma"] is False
 
 
-def test_unknown_check_param_is_dropped(monkeypatch):
-    def fake(f, *, mesh, in_specs, out_specs):  # neither spelling
-        return "mapped"
+def _two_axis_mesh():
+    return Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("a", "b"))
 
-    monkeypatch.setattr(compat, "_native_shard_map",
-                        lambda: (fake, "jax.shard_map"))
-    assert compat.shard_map(_ident, mesh="M", in_specs=(),
-                            out_specs=P(), check_vma=False) == "mapped"
+
+def test_old_spelling_gets_check_rep():
+    """A value already varying over every requested axis comes back
+    as it is: jax refuses a varying cast over an axis it varies over."""
+    mesh = _two_axis_mesh()
+
+    @functools.partial(compat.shard_map, mesh=mesh,
+                       in_specs=(P(("a", "b")),), out_specs=P(("a", "b")))
+    def f(x):
+        y = compat.pvary(x, ("a", "b"))
+        assert y is x
+        assert jax.typeof(y).vma == {"a", "b"}
+        return y
+
+    np.testing.assert_array_equal(np.asarray(f(jnp.arange(4.0))),
+                                  np.arange(4.0))
+
+
+def test_pvary_prefers_pvary_then_pcast():
+    """A replicated value is cast to varying over every requested axis."""
+    mesh = _two_axis_mesh()
+
+    @functools.partial(compat.shard_map, mesh=mesh,
+                       in_specs=(P(),), out_specs=P(("a", "b")))
+    def f(x):
+        assert jax.typeof(x).vma == frozenset()
+        y = compat.pvary(x, ("a", "b"))
+        assert jax.typeof(y).vma == {"a", "b"}
+        return y
+
+    np.testing.assert_array_equal(np.asarray(f(jnp.arange(4.0))),
+                                  np.arange(4.0))
 
 
 def test_both_check_spellings_rejected():
-    with pytest.raises(TypeError, match="not both"):
+    """The jax 0.4 ``check_rep`` spelling is gone from the seam."""
+    with pytest.raises(TypeError):
         compat.shard_map(_ident, mesh="M", in_specs=(), out_specs=P(),
-                         check_vma=False, check_rep=False)
+                         check_rep=False)
 
 
 def test_check_flag_omitted_means_native_default(monkeypatch):
     captured = {}
-
-    def fake(f, *, mesh, in_specs, out_specs, check_vma=True):
-        captured["check_vma"] = check_vma
-        return "mapped"
-
-    monkeypatch.setattr(compat, "_native_shard_map",
-                        lambda: (fake, "jax.shard_map"))
+    _fake_shard_map(monkeypatch, captured)
     compat.shard_map(_ident, mesh="M", in_specs=(), out_specs=P())
     assert captured["check_vma"] is True  # native default untouched
 
 
-def test_pvary_prefers_pvary_then_pcast(monkeypatch):
+def test_pvary_casts_only_missing_axes(monkeypatch):
+    """A value varying over some of the axes is cast over the rest
+    only, through ``pcast(..., to="varying")``."""
     calls = []
-    monkeypatch.setattr(jax.lax, "pvary",
-                        lambda x, axes: calls.append(("pvary", axes)) or x,
-                        raising=False)
-    assert compat.pvary(3, ("a", "b")) == 3
-    assert calls == [("pvary", ("a", "b"))]
+    real = jax.lax.pcast
 
-    monkeypatch.delattr(jax.lax, "pvary", raising=False)
-    monkeypatch.setattr(
-        jax.lax, "pcast",
-        lambda x, axes, to: calls.append(("pcast", axes, to)) or x,
-        raising=False)
-    assert compat.pvary(3, ("a",)) == 3
-    assert calls[-1] == ("pcast", ("a",), "varying")
+    def spy(x, axes, *, to):
+        calls.append((tuple(axes), to))
+        return real(x, axes, to=to)
 
-    monkeypatch.delattr(jax.lax, "pcast", raising=False)
-    assert compat.pvary(3, ("a",)) == 3  # identity on jax 0.4.x
+    monkeypatch.setattr(jax.lax, "pcast", spy)
+    mesh = _two_axis_mesh()
+
+    @functools.partial(compat.shard_map, mesh=mesh,
+                       in_specs=(P("a"),), out_specs=P(("a", "b")))
+    def f(x):
+        return compat.pvary(x, ("a", "b"))
+
+    f(jnp.arange(4.0))
+    assert calls == [(("b",), "varying")]
     assert compat.pvary(7, ()) == 7      # no axes -> always identity
 
 
@@ -157,3 +160,40 @@ def test_no_direct_shard_map_access_outside_compat():
     findings, n_files = lint_paths([str(root / "src")], [CompatSeamPass()])
     assert n_files > 0
     assert not findings, "\n".join(f.render() for f in findings)
+
+
+@pytest.fixture
+def cache_config():
+    """Restore the process-global cache options the seam sets."""
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in names}
+    jax.config.update("jax_compilation_cache_dir", None)
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("case", ["unset", "env", "cpu"])
+def test_entry_point_cache_placement(case, cache_config, monkeypatch,
+                                     tmp_path):
+    """Unset: one fixed dir inside the checkout.  A dir already given
+    (``JAX_COMPILATION_CACHE_DIR``, which jax reads into this option):
+    kept, no other set.  A CPU-pinned process: no cache at all."""
+    import pathlib
+    checkout = pathlib.Path(compat.__file__).resolve().parents[3]
+    if case == "cpu":
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    else:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    if case == "env":
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    want = {"unset": str(checkout / ".jax_cache"), "env": str(tmp_path),
+            "cpu": None}[case]
+    assert compat.enable_entry_point_cache() is (case != "cpu")
+    assert jax.config.jax_compilation_cache_dir == want
+    compat.enable_entry_point_cache()  # a second entry point: no change
+    assert jax.config.jax_compilation_cache_dir == want

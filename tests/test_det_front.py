@@ -188,10 +188,13 @@ def test_front_retire_worker_drains_and_requeues(rng):
     """The graceful-downscale path: retire_worker hands the un-staged
     backlog back via DetQueue.drain_pending, the ring drops the worker,
     and everything still resolves bit-identically on the survivor."""
-    mats = [rng.normal(size=(3, 7)).astype(np.float32) for _ in range(16)]
+    mats = [rng.normal(size=(3, 7)).astype(np.float32)
+            for _ in range(CAP - 2)]
     want = _queue_reference(mats)
-    # linger keeps the worker's backlog un-staged long enough for the
-    # retire to deterministically catch requests in drain_pending
+    # fewer requests than a batch, and linger keeps that partial batch
+    # un-staged long enough for the retire to deterministically catch
+    # it in drain_pending (a full batch would stage at once: workers are
+    # up, their device open, before the front takes a request)
     with DetFront(workers=2, chunk=CHUNK, policy=PINNED,
                   linger_s=3.0) as front:
         victim = front.owner_of((3, 7))
@@ -419,3 +422,15 @@ def test_front_join_with_prefill_warms_before_admission(rng, tmp_path):
                 for f in front.submit_many(mats[12:])]
     joiner.join(timeout=30)
     assert first + rest == want
+
+
+@pytest.mark.parametrize("shm", [False, True])
+def test_worker_without_its_device_fails_start(monkeypatch, shm):
+    """A chip belongs to one process: a worker that cannot open its
+    device (here: a platform this host does not have) fails the pool's
+    start-up with its own error, and is never re-routed around."""
+    from repro.launch.transport import TransportError
+    monkeypatch.setenv("JAX_PLATFORMS", "nonexistent")  # spawned workers
+    with pytest.raises(TransportError,
+                       match="could not open its device.*nonexistent"):
+        DetFront(workers=2, chunk=CHUNK, policy=PINNED, shm=shm)

@@ -58,9 +58,10 @@ DRYRUN_TINY = textwrap.dedent("""
     import jax
     from unittest import mock
     import repro.launch.dryrun as dr
+    from repro.launch.mesh import make_mesh
     # shrink the production mesh so the cell compiles quickly under test
     with mock.patch.object(dr, "make_production_mesh",
-                           lambda multi_pod=False: jax.make_mesh(
+                           lambda multi_pod=False: make_mesh(
                                (2, 2, 2) if multi_pod else (4, 2),
                                ("pod", "data", "model") if multi_pod
                                else ("data", "model"))):

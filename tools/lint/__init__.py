@@ -7,7 +7,7 @@ Five passes (see DESIGN_LINT.md for the catalog and the rationale):
 * ``lock-discipline`` — ``_GUARDED_BY`` attributes touched only under lock
 * ``wire-safety``     — link.send() payloads built from plain types
 * ``tracer-hygiene``  — no Python control flow / host escapes on tracers
-* ``overflow-guard``  — binom_table/unrank_tile dominated by a rank guard
+* ``overflow-guard``  — binom_table/unrank_* dominated by a rank guard
 
 Pure stdlib: importing this package must never import jax/numpy, so the
 CI lint job runs on a bare Python with no wheel install.
