@@ -1,0 +1,22 @@
+"""The fused value kernel's share of its roofline in a solve loop: the
+algorithmic work of the window's solves (bench/work.py) at the chip's
+published peak, over the kernel's device time summed over every device
+(device trace).  Compute bound: the kernel reads each matrix once."""
+
+from bench import work
+
+from . import kernel_seconds, traced
+
+
+def read(run):
+    if not traced(run) or not run.rec.get("vals"):
+        return None
+    t_kernel = sum(kernel_seconds(run))
+    if t_kernel <= 0:
+        return None
+    m, n = run.config["shape"]
+    solves = len(run.rec["vals"])
+    t_min, _ = work.roofline_seconds(work.det_flops(m, n, solves),
+                                     work.det_bytes(m, n, solves),
+                                     run.device_kind)
+    return 100.0 * t_min / t_kernel
