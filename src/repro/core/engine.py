@@ -357,7 +357,9 @@ class DetEngine:
              mode: Literal["grains", "flat"] = "grains",
              grains_per_device: int = 1) -> DetPlan:
         """Return the cached plan for this configuration, building it if
-        absent.  All validation happens here, before backend dispatch."""
+        absent.  All validation happens here, before backend dispatch.
+        A miss restores or builds inside a ``det.plan.build`` profiler
+        span (the mesh backend's grain planning falls inside it)."""
         if backend not in ("jnp", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
         if kahan and batched:
@@ -379,19 +381,20 @@ class DetEngine:
                 self._plans.move_to_end(key)
                 self._hits += 1
                 return plan
-        built = None
-        consulted = self.store is not None and self._persistable(key)
-        if consulted:
-            built = self._restore_from_store(key)
-            with self._lock:
-                if built is not None:
-                    self._store_hits += 1
-                else:
-                    self._store_misses += 1
-        if built is None:
-            built = self._build(key)
+        with jax.profiler.TraceAnnotation("det.plan.build"):
+            built = None
+            consulted = self.store is not None and self._persistable(key)
             if consulted:
-                self._persist_async(key, built)
+                built = self._restore_from_store(key)
+                with self._lock:
+                    if built is not None:
+                        self._store_hits += 1
+                    else:
+                        self._store_misses += 1
+            if built is None:
+                built = self._build(key)
+                if consulted:
+                    self._persist_async(key, built)
         with self._lock:
             plan = self._plans.get(key)
             if plan is not None:  # racing build: first insert wins

@@ -127,9 +127,11 @@ def _ungrouped(a: jax.Array, B: int, m: int, n: int) -> jax.Array:
 
 
 def _pallas_tiles(body, padded_count: int, tile: int, table, q_start, count,
-                  operands, out_shape, interpret):
+                  operands, out_shape, interpret, name: str):
     """One sequential grid step per rank tile; every operand and the
-    output are whole-array blocks resident in VMEM."""
+    output are whole-array blocks resident in VMEM.  ``name`` is the HLO
+    instruction's name, which a device trace shows and the benchmark's
+    kernel readers match (``tests/test_tpu_compile.py`` pins it)."""
 
     def whole(shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
@@ -147,6 +149,7 @@ def _pallas_tiles(body, padded_count: int, tile: int, table, q_start, count,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=resolve_interpret(interpret),
+        name=name,
     )(qinfo, table.astype(jnp.int32).reshape(-1), *operands)
 
 
@@ -166,7 +169,7 @@ def radic_batched_partial_pallas(As: jax.Array, table: jax.Array,
     out = _pallas_tiles(
         functools.partial(radic_batched_combo_kernel, n, m, tile, G),
         padded_count, tile, table, q_start, count, [a],
-        (G, SUBLANES, tile), interpret)
+        (G, SUBLANES, tile), interpret, "radic_batched_partial_pallas")
     return out.reshape(G * SUBLANES, tile)[:B].sum(axis=1).astype(As.dtype)
 
 
@@ -201,7 +204,7 @@ def radic_batched_grad_partial_pallas(As: jax.Array, cts: jax.Array,
     out = _pallas_tiles(
         functools.partial(radic_batched_grad_combo_kernel, n, m, tile, G),
         padded_count, tile, table, q_start, count, [a, c],
-        (G, SUBLANES * m, n), interpret)
+        (G, SUBLANES * m, n), interpret, "radic_batched_grad_partial_pallas")
     return _ungrouped(out, B, m, n).astype(As.dtype)
 
 

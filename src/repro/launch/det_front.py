@@ -998,7 +998,9 @@ class DetFront:
             for shape, b in snap.get("buckets", {}).items():
                 agg = total["buckets"].setdefault(
                     shape, {"count": 0, "batches": 0, "ranks": 0,
-                            "wait_s": 0.0})
+                            "wait_s": 0.0, "queued_s": 0.0,
+                            "staging_s": 0.0, "inflight_s": 0.0,
+                            "delivery_s": 0.0})
                 for k in agg:
                     agg[k] += b.get(k, 0)
             pc = snap.get("plan_cache", {})

@@ -19,6 +19,17 @@ three-thread pipeline connected by bounded queues:
 * **completer** blocks on the oldest in-flight result, unpacks it and
   resolves the per-request futures (and the ``poll()`` response queue).
 
+Each phase runs inside a ``jax.profiler.TraceAnnotation`` span with a
+fixed name (``det.stage`` per stager round; ``det.stage.batch``,
+``det.dispatch``, ``det.complete.wait`` and ``det.complete.deliver``
+per batch, carrying the round number as metadata; ``gc.collect`` per
+garbage collection of the process).  They record only while a
+profiler runs; otherwise each costs about a microsecond of host time.
+Each request's time in the queue is split at the same points: per
+bucket, ``queued_s`` (submit → round start), ``staging_s`` (round start
+→ dispatch), ``inflight_s`` (dispatch → device ready) and
+``delivery_s`` (ready → delivered) add up to ``wait_s``.
+
 Staging and dispatch share one thread on purpose: dispatch through a
 compiled executable is ~50 µs of python, far too little to earn a third
 thread's context-switch traffic on small hosts; the bounded ``inflight``
@@ -49,6 +60,7 @@ directly.
 
 from __future__ import annotations
 
+import gc
 import queue
 import threading
 import time
@@ -295,6 +307,11 @@ class StagePlan:
     capacity: int
     merged_count: int          # how many requests were column-padded here
     grad: bool = False         # gradient batch: dispatches plan.grad
+    # stamped by the stager: its round, the round's start and the moment
+    # the executable call returned (perf_counter seconds)
+    round: int = 0
+    t_round: float = 0.0
+    t_dispatch: float = 0.0
 
     @property
     def merged(self) -> bool:
@@ -344,6 +361,50 @@ def plan_buckets(requests: list[Request], policy: BucketPolicy,
                 merged_count=sum(1 for r in grp if r.shape != target),
                 grad=grad))
     return plans
+
+
+class _GcClock:
+    """Garbage-collection pauses of the process, from one ``gc.callbacks``
+    hook: a ``gc.collect`` span around each collection, and running
+    totals that every :meth:`DetQueue.snapshot` reports as ``"gc"``.
+
+    The collector runs one collection at a time, so only the hook writes
+    the totals; it swaps in one tuple per collection, which a reader
+    takes whole.  It takes no lock: a collection can start at any
+    allocation, in a thread that already holds that lock.
+    """
+
+    def __init__(self):
+        self.totals = (0, 0.0)     # (collections, pause seconds)
+        self._t0 = 0.0
+        self._span = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            self._span = jax.profiler.TraceAnnotation("gc.collect")
+            self._span.__enter__()
+        elif self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+            n, pause = self.totals
+            self.totals = (n + 1, pause + time.perf_counter() - self._t0)
+
+    def snapshot(self) -> dict:
+        n, pause = self.totals
+        return {"collections": n, "pause_s": pause}
+
+
+# gc.callbacks is per process, so the clock is too: the first DetQueue
+# installs it, and every queue reports the same totals
+_GC_CLOCK = _GcClock()
+_GC_INSTALL = threading.Lock()
+
+
+def _install_gc_clock() -> None:
+    with _GC_INSTALL:
+        if not any(cb is _GC_CLOCK for cb in gc.callbacks):
+            gc.callbacks.append(_GC_CLOCK)
 
 
 class _Shutdown:
@@ -442,6 +503,7 @@ class DetQueue:
         self._resp_cv = threading.Condition()
 
         self.stats = self._zero_stats()
+        _install_gc_clock()
 
         self._threads = [
             threading.Thread(target=self._stager, name="det-stager",
@@ -575,15 +637,18 @@ class DetQueue:
             "submitted": 0, "completed": 0, "batches": 0, "dispatches": 0,
             "merged_requests": 0, "padded_slots": 0, "ranks": 0,
             "responses_dropped": 0, "shed": 0, "backlog_peak": 0,
-            "stage_s": 0.0, "complete_s": 0.0,
             "buckets": {},
         }
 
     def snapshot(self) -> dict:
+        """The counters, the engine's ``plan_cache`` info and ``gc``: the
+        process's garbage ``collections`` and their ``pause_s``, counted
+        since the first queue was built (``reset_stats`` leaves them)."""
         with self._lock:
             s = dict(self.stats)
             s["buckets"] = {k: dict(v) for k, v in self.stats["buckets"].items()}
         s["plan_cache"] = self.engine.cache_info()
+        s["gc"] = _GC_CLOCK.snapshot()
         return s
 
     def reset_stats(self):
@@ -763,17 +828,29 @@ class DetQueue:
             self._resp_cv.notify_all()
 
     def _deliver(self, plan: StagePlan, outs: list[float], *, ranks: int = 0,
-                 complete_s: float = 0.0, count_batch: bool = False):
+                 t_ready: float | None = None, count_batch: bool = False):
         """Deliver one finished batch — ``poll()`` responses and stats
         strictly before the futures resolve: a caller woken by the
         batch's last future must observe the batch fully counted and its
         responses visible (``serve()`` and the stats assertions in the
         tests rely on this).  ``count_batch`` is for paths that bypass
         the stager's batch accounting (the trivial m > n short-circuit).
+
+        ``t_ready`` is when the device finished the batch; None (the
+        trivial path, no device work) puts everything after the round's
+        start in ``staging_s``.  The four segments are reckoned once per
+        batch from its stamps, and sum to ``wait_s``.
         """
         k = len(plan.requests)
         now = time.perf_counter()
-        wait = sum(now - r.t_submit for r in plan.requests)
+        t_dispatch = plan.t_dispatch
+        if t_ready is None:
+            t_dispatch = t_ready = now
+        submitted = sum(r.t_submit for r in plan.requests)
+        queued = k * plan.t_round - submitted
+        staging = k * (t_dispatch - plan.t_round)
+        inflight = k * (t_ready - t_dispatch)
+        delivery = k * (now - t_ready)
         # drop accounting under the response cv so concurrent deliverers
         # (stager's trivial path + completer) don't both read a stale
         # length; an active poller draining in parallel can still make
@@ -789,15 +866,20 @@ class DetQueue:
             st["batches"] += 1 if count_batch else 0
             st["completed"] += k
             st["ranks"] += ranks
-            st["complete_s"] += complete_s
             st["responses_dropped"] += dropped
             b = st["buckets"].setdefault(
                 plan.shape, {"count": 0, "batches": 0, "ranks": 0,
-                             "wait_s": 0.0})
+                             "wait_s": 0.0, "queued_s": 0.0,
+                             "staging_s": 0.0, "inflight_s": 0.0,
+                             "delivery_s": 0.0})
             b["count"] += k
             b["batches"] += 1
             b["ranks"] += ranks
-            b["wait_s"] += wait
+            b["wait_s"] += k * now - submitted
+            b["queued_s"] += queued
+            b["staging_s"] += staging
+            b["inflight_s"] += inflight
+            b["delivery_s"] += delivery
         for r, val in zip(plan.requests, outs):
             self._resolve(r.future, val)
 
@@ -821,20 +903,23 @@ class DetQueue:
         whatever the pullback produces for the all-zero padding matrices
         never reaches a caller.
         """
-        m, n = plan.shape
-        stack = np.zeros((plan.capacity, m, n), dtype=self.dtype)
-        for j, r in enumerate(plan.requests):
-            rm, rn = r.shape
-            stack[j, :rm, :rn] = r.array   # zero col-pad is det-exact
-        dev = jax.device_put(stack)
-        if not plan.grad:
-            return dev, None
-        cts = np.zeros((plan.capacity,), dtype=self.dtype)
-        for j, r in enumerate(plan.requests):
-            cts[j] = r.ct
-        return dev, jax.device_put(cts)
+        with jax.profiler.TraceAnnotation("det.stage.batch",
+                                          round=plan.round):
+            m, n = plan.shape
+            stack = np.zeros((plan.capacity, m, n), dtype=self.dtype)
+            for j, r in enumerate(plan.requests):
+                rm, rn = r.shape
+                stack[j, :rm, :rn] = r.array   # zero col-pad is det-exact
+            dev = jax.device_put(stack)
+            if not plan.grad:
+                return dev, None
+            cts = np.zeros((plan.capacity,), dtype=self.dtype)
+            for j, r in enumerate(plan.requests):
+                cts[j] = r.ct
+            return dev, jax.device_put(cts)
 
     def _stager(self):
+        rounds = 0
         try:
             while True:
                 with self._wake:
@@ -858,48 +943,57 @@ class DetQueue:
                     reqs, self._pending = self._pending, []
                     closing = self._closing
                 if reqs:
-                    t0 = time.perf_counter()
-                    depth = len(reqs)
-                    for plan in plan_buckets(reqs, self.policy, depth):
-                        if plan.capacity == 0:
-                            continue  # empty buckets dispatch nothing
-                        if plan.shape[0] > plan.shape[1]:
-                            # paper: det = 0 for m > n — known at plan
-                            # time, so no stack, no upload, no pipeline
-                            self._complete_trivial(plan)
-                            continue
-                        try:
-                            dev, cts = self._stage_one(plan)
-                            exe = self._plan(plan.shape, plan.capacity)
-                            # async dispatch: device work only — grad
-                            # batches enter the plan's VJP program, value
-                            # batches the forward executable
-                            dets = exe.grad(dev, cts) if plan.grad \
-                                else exe(dev)
-                        except Exception as e:  # noqa: BLE001 — batch-local
-                            # e.g. C(n, m) overflowing int32 for one weird
-                            # shape: fail this batch, keep serving the rest
-                            self._fail_plan(plan, e)
-                            continue
-                        # stats strictly before the hand-off: a caller woken
-                        # by the batch's last future must see it counted
-                        with self._lock:
-                            st = self.stats
-                            st["batches"] += 1
-                            st["dispatches"] += 1  # m > n handled above
-                            st["merged_requests"] += plan.merged_count
-                            st["padded_slots"] += (plan.capacity
-                                                   - len(plan.requests))
-                        if not self._put_alive(self._inflight, (plan, dets)):
-                            self._fail_plan(plan, self._fatal_now())
-                            return
-                    with self._lock:
-                        self.stats["stage_s"] += time.perf_counter() - t0
+                    rounds += 1
+                    if not self._stage_round(reqs, rounds):
+                        return
                 if closing:
                     self._put_alive(self._inflight, _SHUTDOWN)
                     return
         except BaseException as e:  # noqa: BLE001 — must not hang futures
             self._fail_all(e)  # also plants a shutdown sentinel downstream
+
+    def _stage_round(self, reqs: list[Request], rnd: int) -> bool:
+        """Plan, stage and dispatch one pending snapshot; False once the
+        pipeline has died (the in-hand batch is failed)."""
+        t_round = time.perf_counter()
+        with jax.profiler.TraceAnnotation("det.stage", round=rnd):
+            for plan in plan_buckets(reqs, self.policy, len(reqs)):
+                if plan.capacity == 0:
+                    continue  # empty buckets dispatch nothing
+                plan.round, plan.t_round = rnd, t_round
+                if plan.shape[0] > plan.shape[1]:
+                    # paper: det = 0 for m > n — known at plan time, so
+                    # no stack, no upload, no pipeline
+                    self._complete_trivial(plan)
+                    continue
+                try:
+                    dev, cts = self._stage_one(plan)
+                    with jax.profiler.TraceAnnotation("det.dispatch",
+                                                      round=rnd):
+                        exe = self._plan(plan.shape, plan.capacity)
+                        # async dispatch: device work only — grad batches
+                        # enter the plan's VJP program, value batches the
+                        # forward executable
+                        dets = exe.grad(dev, cts) if plan.grad else exe(dev)
+                    plan.t_dispatch = time.perf_counter()
+                except Exception as e:  # noqa: BLE001 — batch-local
+                    # e.g. C(n, m) overflowing int32 for one weird shape:
+                    # fail this batch, keep serving the rest
+                    self._fail_plan(plan, e)
+                    continue
+                # stats strictly before the hand-off: a caller woken by
+                # the batch's last future must see it counted
+                with self._lock:
+                    st = self.stats
+                    st["batches"] += 1
+                    st["dispatches"] += 1  # m > n handled above
+                    st["merged_requests"] += plan.merged_count
+                    st["padded_slots"] += (plan.capacity
+                                           - len(plan.requests))
+                if not self._put_alive(self._inflight, (plan, dets)):
+                    self._fail_plan(plan, self._fatal_now())
+                    return False
+        return True
 
     def _completer(self):
         try:
@@ -908,19 +1002,27 @@ class DetQueue:
                 if isinstance(item, _Shutdown):
                     return
                 plan, dets = item
-                t0 = time.perf_counter()
                 try:
-                    vals = np.asarray(jax.block_until_ready(dets))
+                    with jax.profiler.TraceAnnotation("det.complete.wait",
+                                                      round=plan.round):
+                        jax.block_until_ready(dets)
                 except Exception as e:  # noqa: BLE001 — batch-local
                     self._fail_plan(plan, e)
                     continue
-                k = len(plan.requests)
-                m, n = plan.shape
-                # grad batches deliver the (m, n) arrays themselves;
-                # value batches unpack the (capacity,) dets to floats
-                outs = list(vals[:k]) if plan.grad else vals[:k].tolist()
-                self._deliver(plan, outs,
-                              ranks=comb(n, m) * k,
-                              complete_s=time.perf_counter() - t0)
+                t_ready = time.perf_counter()
+                with jax.profiler.TraceAnnotation("det.complete.deliver",
+                                                  round=plan.round):
+                    try:
+                        vals = np.asarray(dets)
+                    except Exception as e:  # noqa: BLE001 — batch-local
+                        self._fail_plan(plan, e)
+                        continue
+                    k = len(plan.requests)
+                    m, n = plan.shape
+                    # grad batches deliver the (m, n) arrays themselves;
+                    # value batches unpack the (capacity,) dets to floats
+                    outs = list(vals[:k]) if plan.grad else vals[:k].tolist()
+                    self._deliver(plan, outs, ranks=comb(n, m) * k,
+                                  t_ready=t_ready)
         except BaseException as e:  # noqa: BLE001
             self._fail_all(e)

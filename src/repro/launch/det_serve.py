@@ -126,6 +126,20 @@ def _serve_tolerating_sheds(q, mats, grads=None):
     return dets
 
 
+# a request's mean wait, and the four segments it splits into
+_WAITS = ("wait_s", "queued_s", "staging_s", "inflight_s", "delivery_s")
+
+
+def _print_buckets(buckets: dict) -> None:
+    """The per-bucket CSV table: counts and mean seconds per request."""
+    print("bucket_m,bucket_n,count,batches,ranks,"
+          + ",".join("mean_" + w for w in _WAITS))
+    for (m, n), b in sorted(buckets.items()):
+        k = max(1, b["count"])
+        print(f"{m},{n},{b['count']},{b['batches']},{b['ranks']},"
+              + ",".join(f"{b[w] / k:.4f}" for w in _WAITS))
+
+
 def _serve_front(front, mats, label: str, num: int, backend: str,
                  grads=None):
     """Warm + timed pass through any DetFront, then the front report
@@ -157,10 +171,7 @@ def _serve_front(front, mats, label: str, num: int, backend: str,
         print(f"{wid},{f['routed'].get(wid, 0)},{snap['completed']},"
               f"{snap['batches']},{snap['shed']},"
               f"{snap['backlog_peak']},{snap['plan_cache']['size']}")
-    print("bucket_m,bucket_n,count,batches,ranks,mean_wait_s")
-    for (m, n), b in sorted(tot["buckets"].items()):
-        print(f"{m},{n},{b['count']},{b['batches']},{b['ranks']},"
-              f"{b['wait_s'] / max(1, b['count']):.4f}")
+    _print_buckets(tot["buckets"])
     return dets, stats, wall
 
 
@@ -386,10 +397,7 @@ def main(argv=None):
               f"shed={stats['shed']} backlog_peak={stats['backlog_peak']} "
               f"plan_cache={stats['plan_cache']['size']}/"
               f"{stats['plan_cache']['max_plans']}")
-        print("bucket_m,bucket_n,count,batches,ranks,mean_wait_s")
-        for (m, n), b in sorted(stats["buckets"].items()):
-            print(f"{m},{n},{b['count']},{b['batches']},{b['ranks']},"
-                  f"{b['wait_s'] / max(1, b['count']):.4f}")
+        _print_buckets(stats["buckets"])
     print(f"total,{args.num} mats,{wall:.4f}s,{args.num / wall:.1f} mats/s")
 
     if args.verify:

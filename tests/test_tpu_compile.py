@@ -63,6 +63,14 @@ def _check(compiled, name):
     print(name, compiled.memory_analysis())
 
 
+def _op_names(compiled) -> set[str]:
+    """The HLO instruction names as a device trace names its ops, read
+    the way the benchmark's kernel readers read them."""
+    from bench.trace import op_name
+    return {op_name(line.strip()) for line in compiled.as_text().splitlines()
+            if " = " in line}
+
+
 def _rank_space(m, n):
     total = comb(n, m)
     assert total < 2 ** 31
@@ -87,6 +95,8 @@ def test_batched_combo_kernel_max_batch(one_chip, no_persistent_cache):
     compiled = f.lower(_spec((B, m, n), one_chip),
                        _spec((n + 1, m + 1), one_chip, jnp.int32)).compile()
     _check(compiled, "batched combo (64, 8, 24)")
+    # radic_combo_roofline.* match this name in the device trace
+    assert "radic_batched_partial_pallas" in _op_names(compiled)
 
 
 def test_grad_combo_kernel(one_chip, no_persistent_cache):
@@ -97,6 +107,7 @@ def test_grad_combo_kernel(one_chip, no_persistent_cache):
     compiled = f.lower(_spec((B, m, n), one_chip), _spec((B,), one_chip),
                        _spec((n + 1, m + 1), one_chip, jnp.int32)).compile()
     _check(compiled, "grad combo (16, 8, 24)")
+    assert "radic_batched_grad_partial_pallas" in _op_names(compiled)
 
 
 def test_mesh_grains_four_chips(topo, no_persistent_cache, monkeypatch):
@@ -113,3 +124,4 @@ def test_mesh_grains_four_chips(topo, no_persistent_cache, monkeypatch):
         _spec((12, 32), NamedSharding(mesh, P()))).compile()
     _check(compiled, "mesh grains (12, 32) on 2x2")
     assert "all-reduce" in compiled.as_text()
+    assert "radic_batched_partial_pallas" in _op_names(compiled)
