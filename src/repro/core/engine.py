@@ -195,6 +195,23 @@ def stable_key_hash(key) -> int:
                           "big")
 
 
+def kernel_rank_rows(key: PlanKey) -> int | None:
+    """Rank rows per sublane group of the value kernel a plan runs: 8
+    where each kernel call sees one matrix (scalar plans, on one device or
+    per mesh device, and batched plans at capacity 1), 1 where it sees a
+    stack, ``None`` where no kernel runs or the batch is left to the call
+    (no capacity)."""
+    if key.backend != "pallas" or key.m > key.n:
+        return None
+    batch = 1 if not key.batched else key.capacity
+    if batch is None:
+        return None
+    if key.batched and key.mesh is not None and key.batch_axis:
+        batch //= key.mesh.shape[key.batch_axis]
+    from repro.kernels.radic_fused import rank_rows  # kernels import core
+    return rank_rows(batch)
+
+
 def _make_differentiable(primal: Callable, grad_fn: Callable) -> Callable:
     """Wrap a plan's *traced* primal closure in a ``jax.custom_vjp`` whose
     backward pass is the plan's cofactor-form grad program.
@@ -283,6 +300,12 @@ class DetPlan:
     def backend(self) -> str:
         return self.key.backend
 
+    @property
+    def rank_rows(self) -> int | None:
+        """The engagement record of the value kernel's layout: 8 rank
+        rows per sublane group, 1, or ``None`` (:func:`kernel_rank_rows`)."""
+        return kernel_rank_rows(self.key)
+
     def __call__(self, A: jax.Array) -> jax.Array:
         return self.executable(A)
 
@@ -359,7 +382,8 @@ class DetEngine:
         """Return the cached plan for this configuration, building it if
         absent.  All validation happens here, before backend dispatch.
         A miss restores or builds inside a ``det.plan.build`` profiler
-        span (the mesh backend's grain planning falls inside it)."""
+        span (the mesh backend's grain planning falls inside it), whose
+        metadata ``rank_rows`` is the plan's :attr:`DetPlan.rank_rows`."""
         if backend not in ("jnp", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
         if kahan and batched:
@@ -381,7 +405,8 @@ class DetEngine:
                 self._plans.move_to_end(key)
                 self._hits += 1
                 return plan
-        with jax.profiler.TraceAnnotation("det.plan.build"):
+        with jax.profiler.TraceAnnotation("det.plan.build",
+                                          rank_rows=kernel_rank_rows(key)):
             built = None
             consulted = self.store is not None and self._persistable(key)
             if consulted:

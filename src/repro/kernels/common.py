@@ -1,8 +1,10 @@
 """Shared in-kernel routines, lane-major.
 
 The rank tile lives on the 128-wide lane axis: ``T`` ranks form one
-``(1, T)`` row, and a per-matrix quantity is an ``(S, T)`` slab with one
-matrix per sublane.  An m×m matrix is a python list of m·m slabs, so the
+``(1, T)`` row shared by the matrices of a batch, or ``(S, T)`` rows
+when one matrix fills the sublanes with S rank rows of its own.  A
+per-minor quantity is an ``(S, T)`` slab, one matrix and rank per
+(sublane, lane).  An m×m matrix is a python list of m·m slabs, so the
 Gaussian elimination, the products and the row swaps are static unrolls
 over the small m of elementwise ops on whole slabs.  The whole set of
 constructs is what Mosaic lowers: elementwise ops, plain 2-D matmuls
@@ -20,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["det_slabs", "unrank_lanes", "selector_slabs", "minor_slabs",
-           "sign_lanes",
+           "rank_row_slabs", "sign_lanes",
            "batched_det_ge", "unrank_tile", "onehot_gather_minors",
            "radic_signs"]
 
@@ -125,6 +127,29 @@ def minor_slabs(A: jax.Array, sel: list[jax.Array]
         cols = jax.lax.dot(A, oh, precision=jax.lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
         M.append([cols[a * S:(a + 1) * S] for a in range(m)])
+    return M
+
+
+def rank_row_slabs(A: jax.Array, combos: list[jax.Array], n: int
+                   ) -> list[list[jax.Array]]:
+    """Column gather for one matrix whose sublanes hold S rows of ranks:
+    ``A (m, n)``, ``combos`` m ``(S, T)`` rows -> slabs ``M[k][a] (S, T)
+    = A[a, combos[k][s, t] - 1]``, the transposed minors of every rank,
+    as :func:`minor_slabs` lays them out.
+
+    The selectors differ per rank row, so each k takes S one-hot matmuls
+    ``(m, n) @ (n, T)`` at full f32 precision, exact as in
+    :func:`minor_slabs`, and row a of the S products fills the sublanes
+    of ``M[k][a]``."""
+    S = combos[0].shape[0]
+    M = []
+    for c in combos:
+        sel = selector_slabs([c[s:s + 1] for s in range(S)], n)
+        cols = [jax.lax.dot(A, oh, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+                for oh in sel]
+        M.append([jnp.concatenate([r[a:a + 1] for r in cols], axis=0)
+                  for a in range(A.shape[0])])
     return M
 
 

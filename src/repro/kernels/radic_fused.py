@@ -3,26 +3,37 @@
 One kernel = the paper's whole per-processor pipeline, fused so minors
 never touch HBM:
 
-    rank tile ──unrank (VPU, n static steps)────► combos, m (1, T) rows
+    rank tile ──unrank (VPU, n static steps)────► combos, m (R, T) rows
               ──one-hot matmuls (MXU)───────────► minor slabs (8, T)
               ──pivoted GE (VPU, unrolled in m)─► dets (8, T)
               ──sign · mask─────────────────────► per-lane partials
 
-Layout: the T ranks of a tile sit on the 128-wide lane axis and the
-batch on the sublanes, 8 matrices (one f32 sublane tile) per group.  A
-grid step unranks its tile once, builds the selectors and signs once,
-then walks the batch group by group, so that shared work is paid once
-per tile and not once per matrix.  The batch enters the kernel
-group-major, ``(G, 8·m, n)`` with row ``a·8 + s`` of group g holding row
-a of matrix ``8g + s``; the wrappers pad B up to a multiple of 8 with
-zero matrices, whose results are sliced off.
+Two layouts of the 8 f32 sublanes, chosen from the static batch size
+(:func:`rank_rows`):
 
-``q_start``/``count`` and the flattened Pascal table are scalar-prefetch
-operands (SMEM).  The outputs stay resident across the sequential grid:
-the forward adds its masked per-lane terms into a ``(G, 8, T)`` block
-that the wrapper sums over lanes, the backward adds ``(G, 8·m, n)``
-gradient partials.  HBM input per tile is zero beyond the resident A
-and table — ranks are generated from the grid index.
+* B ≥ 2, matrices on sublanes (R = 1): the T ranks of a tile sit on the
+  128-wide lane axis and the batch on the sublanes, 8 matrices per
+  group.  A grid step unranks its tile once, builds the selectors and
+  signs once, then walks the batch group by group, so that shared work
+  is paid once per tile and not once per matrix.  The batch enters the
+  kernel group-major, ``(G, 8·m, n)`` with row ``a·8 + s`` of group g
+  holding row a of matrix ``8g + s``; the wrappers pad B up to a
+  multiple of 8 with zero matrices, whose results are sliced off.
+* B = 1, rank rows on sublanes (R = 8): sublane s of a grid step holds
+  ranks ``step·8T + s·T + lane`` of the one matrix, so a step covers 8T
+  ranks and no sublane computes a zero matrix.  The selectors differ per
+  sublane, so the gather takes one ``(m, n) @ (n, T)`` matmul per rank
+  row and fills the sublanes of each slab from the 8 products
+  (``common.rank_row_slabs``); the elimination reads the same slabs.
+  The matrix enters as it is, ``(1, m, n)``.
+
+``q_start``, the whole steps and the tail of ``count``, and the
+flattened Pascal table are scalar-prefetch operands (SMEM).  The
+outputs stay resident across the sequential grid: the forward adds its
+masked per-lane terms into a ``(G, 8, T)`` block that the wrapper sums
+over lanes (and over sublanes at B = 1), the backward adds
+``(G, 8·m, n)`` gradient partials.  HBM input per tile is zero beyond
+the resident A and table — ranks are generated from the grid index.
 """
 
 from __future__ import annotations
@@ -35,16 +46,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import (batched_det_ge, det_slabs, minor_slabs,
-                     onehot_gather_minors, radic_signs, selector_slabs,
-                     sign_lanes, unrank_lanes, unrank_tile)
+                     onehot_gather_minors, radic_signs, rank_row_slabs,
+                     selector_slabs, sign_lanes, unrank_lanes, unrank_tile)
 
 __all__ = ["radic_partial_pallas", "radic_batched_combo_kernel",
            "radic_batched_partial_pallas",
            "radic_batched_grad_combo_kernel",
            "radic_batched_grad_partial_pallas", "radic_batched_kernel",
-           "radic_batched_partial_pallas_bygrid", "resolve_interpret"]
+           "radic_batched_partial_pallas_bygrid", "rank_rows",
+           "resolve_interpret"]
 
-SUBLANES = 8  # matrices per batch group: one f32 sublane tile
+SUBLANES = 8  # an f32 sublane tile: matrices per group, rank rows at B = 1
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -55,26 +67,70 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return interpret
 
 
-def _tile_setup(n: int, m: int, tile: int, qinfo_ref, table_ref):
-    """The rank tile of this grid step: lane validity mask, selectors and
-    signs — shared by every matrix of the batch."""
-    offs = (pl.program_id(0) * tile
-            + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1))
-    valid = offs < qinfo_ref[1]
-    qs = qinfo_ref[0] + jnp.where(valid, offs, 0)
+def rank_rows(batch: int) -> int:
+    """Rank rows per sublane group of the value kernel for a stack of
+    ``batch`` matrices: 8 when one matrix fills the sublanes with its own
+    ranks (B = 1), 1 when the matrices share each rank row."""
+    return SUBLANES if batch == 1 else 1
+
+
+def _steps(count, step: int) -> list[jax.Array]:
+    """``count`` ranks as whole grid steps of ``step`` ranks and a tail:
+    the scalars :func:`lane_ranks` reads, so no rank offset in the kernel
+    is ever formed past ``count``."""
+    count = jnp.asarray(count, jnp.int32)
+    return [count // step, count % step]
+
+
+def lane_ranks(pid, q_start, full, tail, rows: int, tile: int):
+    """Validity mask and ranks ``(rows, tile)`` of grid step ``pid``, for
+    ``count = full·step + tail`` ranks from ``q_start``, ``step =
+    rows·tile``: lane ``(s, t)`` is rank ``q_start + pid·step + s·tile +
+    t`` where that lies inside the range.
+
+    Every offset is bounded by ``count``: the base is taken at
+    ``min(pid, full)`` steps and a masked lane adds nothing, so a grid
+    that runs past the range — the padded grid extent, or a shard's
+    shorter grain — forms no offset that could wrap int32.  A masked lane
+    unranks a rank of ``[q_start, q_start + count]`` and is dropped."""
+    step = rows * tile
+    local = (jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 0) * tile
+             + jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1))
+    limit = jnp.where(pid < full, step, jnp.where(pid == full, tail, 0))
+    valid = local < limit
+    base = jnp.minimum(pid, full) * step
+    return valid, q_start + base + jnp.where(valid, local, 0)
+
+
+def _tile_setup(n: int, m: int, tile: int, rows: int, qinfo_ref,
+                table_ref):
+    """The rank tile of this grid step: lane validity mask, combinations
+    and signs, ``(rows, tile)`` — shared by every matrix of the batch
+    when ``rows`` is 1."""
+    valid, qs = lane_ranks(pl.program_id(0), qinfo_ref[0], qinfo_ref[1],
+                           qinfo_ref[2], rows, tile)
     # in-kernel unranking; guarded at the ops.py entry points
     combos = unrank_lanes(qs, n, m, table_ref)  # reprolint: disable=overflow-guard
-    return valid, selector_slabs(combos, n), sign_lanes(combos, m)
+    return valid, combos, sign_lanes(combos, m)
 
 
-def radic_batched_combo_kernel(n: int, m: int, tile: int, groups: int,
-                               qinfo_ref, table_ref, a_ref, out_ref):
-    """Forward: per-lane Σ sign·det of this tile for every matrix."""
-    valid, sel, signs = _tile_setup(n, m, tile, qinfo_ref, table_ref)
+def radic_batched_combo_kernel(n: int, m: int, tile: int, rows: int,
+                               groups: int, qinfo_ref, table_ref, a_ref,
+                               out_ref):
+    """Forward: per-lane Σ sign·det of this tile for every matrix, or at
+    ``rows`` = 8 for the rank rows of the one matrix."""
+    valid, combos, signs = _tile_setup(n, m, tile, rows, qinfo_ref,
+                                       table_ref)
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
+
+    if rows > 1:
+        dets = det_slabs(rank_row_slabs(a_ref[0], combos, n))
+        out_ref[0] += jnp.where(valid, signs * dets, 0.0)
+        return
+    sel = selector_slabs(combos, n)
 
     def group(g, carry):
         dets = det_slabs(minor_slabs(a_ref[g], sel))
@@ -92,7 +148,8 @@ def radic_batched_grad_combo_kernel(n: int, m: int, tile: int, groups: int,
     cotangents back through that tile's terms (DESIGN_GRAD.md).  No
     residual crosses the tile boundary, so backward VMEM stays the
     forward's O(8·T·m²) per group."""
-    valid, sel, signs = _tile_setup(n, m, tile, qinfo_ref, table_ref)
+    valid, combos, signs = _tile_setup(n, m, tile, 1, qinfo_ref, table_ref)
+    sel = selector_slabs(combos, n)
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -126,9 +183,9 @@ def _ungrouped(a: jax.Array, B: int, m: int, n: int) -> jax.Array:
             .reshape(G * SUBLANES, m, n)[:B])
 
 
-def _pallas_tiles(body, padded_count: int, tile: int, table, q_start, count,
-                  operands, out_shape, interpret, name: str):
-    """One sequential grid step per rank tile; every operand and the
+def _pallas_tiles(body, padded_count: int, step: int, table, q_start,
+                  count, operands, out_shape, interpret, name: str):
+    """One sequential grid step per ``step`` ranks; every operand and the
     output are whole-array blocks resident in VMEM.  ``name`` is the HLO
     instruction's name, which a device trace shows and the benchmark's
     kernel readers match (``tests/test_tpu_compile.py`` pins it)."""
@@ -137,12 +194,12 @@ def _pallas_tiles(body, padded_count: int, tile: int, table, q_start, count,
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
 
     qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
-                       jnp.asarray(count, jnp.int32)])
+                       *_steps(count, step)])
     return pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(max(1, -(-padded_count // tile)),),
+            grid=(max(1, -(-padded_count // step)),),
             in_specs=[whole(x.shape) for x in operands],
             out_specs=whole(out_shape)),
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
@@ -162,15 +219,20 @@ def radic_batched_partial_pallas(As: jax.Array, table: jax.Array,
                                  interpret: bool | None = None) -> jax.Array:
     """Per-matrix Σ sign·det over ranks [q_start, q_start+count) for a
     shape-uniform stack ``As (B, m, n)`` -> ``(B,)``; ``padded_count`` is
-    the static grid extent (≥ count, tile-aligned)."""
+    the static grid extent (≥ count, tile-aligned).  At B = 1 the kernel
+    puts 8 rank rows of the one matrix on the sublanes (:func:`rank_rows`)
+    and a grid step covers ``8·tile`` ranks."""
     B, m, n = As.shape
-    a = _grouped(As)
+    rows = rank_rows(B)
+    a = _grouped(As) if rows == 1 else As.astype(jnp.float32)
     G = a.shape[0]
     out = _pallas_tiles(
-        functools.partial(radic_batched_combo_kernel, n, m, tile, G),
-        padded_count, tile, table, q_start, count, [a],
+        functools.partial(radic_batched_combo_kernel, n, m, tile, rows, G),
+        padded_count, rows * tile, table, q_start, count, [a],
         (G, SUBLANES, tile), interpret, "radic_batched_partial_pallas")
-    return out.reshape(G * SUBLANES, tile)[:B].sum(axis=1).astype(As.dtype)
+    if rows > 1:  # the sublanes first: a range under one row keeps its sum
+        out = out.sum(axis=1)
+    return out.reshape(-1, tile)[:B].sum(axis=1).astype(As.dtype)
 
 
 def radic_partial_pallas(A: jax.Array, table: jax.Array,
@@ -178,7 +240,7 @@ def radic_partial_pallas(A: jax.Array, table: jax.Array,
                          padded_count: int, *, tile: int = 256,
                          interpret: bool | None = None) -> jax.Array:
     """Σ sign·det over ranks [q_start, q_start+count) of one ``A (m, n)``:
-    the batched kernel at B = 1."""
+    the batched kernel at B = 1, 8 rank rows per grid step."""
     return radic_batched_partial_pallas(
         A[None], table, q_start, count, padded_count, tile=tile,
         interpret=interpret)[0]

@@ -253,3 +253,41 @@ def test_aot_donated_lowering_bit_identical(rng, monkeypatch):
         got = np.asarray(plan(jnp.asarray(As)))
     assert plan.lowered is True
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("plan_kw,rows", [
+    ({"batched": False, "backend": "pallas"}, 8),
+    ({"capacity": 1, "backend": "pallas"}, 8),
+    ({"capacity": 64, "backend": "pallas"}, 1),
+    ({"capacity": None, "backend": "pallas"}, None),
+    ({"batched": False, "backend": "jnp"}, None),
+    ({"batched": False, "backend": "pallas", "mesh": "mesh"}, 8),
+    ({"capacity": 1, "backend": "pallas", "mesh": "mesh",
+      "batch_axis": "workers"}, 8),
+])
+def test_plan_reports_kernel_rank_rows(plan_kw, rows):
+    """The engagement record of the value kernel's layout: 8 rank rows
+    of one matrix per sublane group where each kernel call sees B = 1
+    (the paper's solve, per device over a mesh too), 1 for a stack (a
+    serving bucket at capacity 64), None without a kernel or a fixed
+    batch."""
+    if plan_kw.get("mesh") == "mesh":
+        plan_kw = dict(plan_kw, mesh=_mesh())
+    plan = DetEngine().plan(3, 7, **plan_kw)
+    assert plan.rank_rows == rows
+
+
+def test_plan_build_span_carries_rank_rows(tmp_path):
+    from jax.profiler import ProfileData
+    eng = DetEngine()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.plan(3, 7, batched=False, backend="pallas")
+        eng.plan(3, 7, capacity=64, backend="pallas")
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    planes = ProfileData.from_file(str(path)).planes
+    stats = [dict(ev.stats) for plane in planes for line in plane.lines
+             for ev in line.events if ev.name == "det.plan.build"]
+    assert [s.get("rank_rows") for s in stats] == [8, 1]

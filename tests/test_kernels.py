@@ -84,3 +84,89 @@ def test_bf16_input_promoted(rng):
 def test_int32_guard():
     with pytest.raises(OverflowError):
         ops.radic_det_pallas(jnp.ones((16, 40), jnp.float32))
+
+
+# --------------------------------------------- B = 1: rank rows on sublanes
+# At B = 1 the value kernel puts 8 rank rows of the one matrix on its
+# sublanes, 8·tile ranks per grid step (radic_fused.rank_rows).  With
+# tile 8 a step covers 64 ranks: the counts below fall short of one row,
+# inside the first step, or past whole steps with a tail.
+
+@pytest.mark.parametrize("m,n", [(1, 5), (1, 9), (4, 4), (6, 6), (2, 12),
+                                 (3, 10), (5, 11)])
+def test_rank_rows_full(m, n, rng):
+    A = rng.normal(size=(m, n)).astype(np.float32)
+    got = float(ops.radic_det_pallas(jnp.asarray(A), tile=8))
+    want = ref.radic_det_oracle(A)
+    assert abs(got - want) <= 2e-3 * max(1.0, abs(want))
+
+
+@pytest.mark.parametrize("q0,cnt", [(3, 5), (5, 61), (13, 64), (61, 59),
+                                    (9, 111), (119, 1)])
+def test_rank_rows_partial_ranges(q0, cnt, rng):
+    """Ranges of C(10, 3) = 120 whose start falls inside a sub-tile."""
+    A = rng.normal(size=(3, 10)).astype(np.float32)
+    got = float(ops.radic_det_pallas(jnp.asarray(A), q_start=q0, count=cnt,
+                                     tile=8))
+    want = ref.radic_partial_ref(A, q0, cnt)
+    assert abs(got - want) <= 1e-3 * max(1.0, abs(want))
+
+
+@pytest.mark.parametrize("cuts", [[0, 5, 69, 70, 120], [0, 64, 120],
+                                  [0, 1, 2, 119, 120]])
+def test_rank_rows_partials_compose(cuts, rng):
+    A = rng.normal(size=(3, 10)).astype(np.float32)
+    parts = [float(ops.radic_det_pallas(jnp.asarray(A), q_start=a,
+                                        count=b - a, tile=8))
+             for a, b in zip(cuts[:-1], cuts[1:])]
+    whole = float(ops.radic_det_pallas(jnp.asarray(A), tile=8))
+    want = ref.radic_det_oracle(A)
+    assert abs(sum(parts) - want) <= 2e-3 * max(1.0, abs(want))
+    assert abs(sum(parts) - whole) <= 2e-3 * max(1.0, abs(want))
+
+
+@pytest.mark.parametrize("q0,cnt", [(0, 120), (7, 100)])
+def test_rank_rows_match_the_batch_layout(q0, cnt, rng):
+    """B = 1 (rank rows) and the same matrix inside a B = 8 stack
+    (matrices on sublanes) agree within the kernel's f32 tolerance."""
+    As = rng.normal(size=(8, 3, 10)).astype(np.float32)
+    one = float(ops.radic_det_batched_pallas(jnp.asarray(As[3:4]), q0, cnt,
+                                             tile=8)[0])
+    eight = float(ops.radic_det_batched_pallas(jnp.asarray(As), q0, cnt,
+                                               tile=8)[3])
+    assert abs(one - eight) <= 1e-5 * max(1.0, abs(eight))
+
+
+def test_rank_rows_by_batch():
+    from repro.kernels.radic_fused import rank_rows
+    assert [rank_rows(b) for b in (1, 2, 7, 8, 64)] == [8, 1, 1, 1, 1]
+
+
+@pytest.mark.parametrize("rows", [1, 8])
+def test_lane_ranks_bounded_by_count(rows):
+    """The in-kernel rank offsets, outside a kernel: a grid that runs
+    past a range ending near 2³¹ − 1 forms no offset beyond the range,
+    so nothing wraps int32, and exactly ``count`` lanes are valid."""
+    from repro.kernels.radic_fused import _steps, lane_ranks
+    tile = 256
+    step = rows * tile
+    q0 = 5
+    count = 2 ** 31 - 1 - q0 - 3            # ends 3 ranks short of 2³¹ − 1
+    full, tail = (int(x) for x in _steps(count, step))
+    assert full * step + tail == count
+    grid = -(-count // step) + 2            # two steps past the range
+    assert grid * step > 2 ** 31 - 1        # the old offsets would wrap
+    seen = 0
+    for pid in (0, full - 1, full, full + 1, grid - 1):
+        valid, qs = lane_ranks(jnp.int32(pid), jnp.int32(q0),
+                               jnp.int32(full), jnp.int32(tail), rows, tile)
+        valid, qs = np.asarray(valid), np.asarray(qs, np.int64)
+        assert (qs >= q0).all() and (qs <= q0 + count).all()
+        want = q0 + pid * step + np.arange(step).reshape(rows, tile)
+        np.testing.assert_array_equal(qs[valid], want[valid])
+        seen += int(valid.sum())
+        if pid < full:
+            assert valid.all()
+        elif pid > full:
+            assert not valid.any()
+    assert seen == 2 * step + tail

@@ -7,7 +7,9 @@ these tests guard every change at no chip time.  Interpret mode, which
 every other kernel test runs in, accepts all of those.
 
 Widths: the flat kernel at the paper's (12, 32) — C(32, 12) =
-225,792,840 < 2³¹ ranks; the batched combo kernel at the serving bound
+225,792,840 < 2³¹ ranks — and the same B = 1 stack through the batched
+entry point, whose 8 rank rows per step must stay one kernel under the
+benchmark's name; the batched combo kernel at the serving bound
 ``BucketPolicy.max_batch`` = 64 with (8, 24); the gradient kernel at
 B = 16 with (8, 24); and the four-chip grains program at (12, 32).
 """
@@ -63,12 +65,16 @@ def _check(compiled, name):
     print(name, compiled.memory_analysis())
 
 
-def _op_names(compiled) -> set[str]:
+def _op_list(compiled) -> list[str]:
     """The HLO instruction names as a device trace names its ops, read
     the way the benchmark's kernel readers read them."""
     from bench.trace import op_name
-    return {op_name(line.strip()) for line in compiled.as_text().splitlines()
-            if " = " in line}
+    return [op_name(line.strip()) for line in compiled.as_text().splitlines()
+            if " = " in line]
+
+
+def _op_names(compiled) -> set[str]:
+    return set(_op_list(compiled))
 
 
 def _rank_space(m, n):
@@ -85,6 +91,22 @@ def test_flat_kernel_paper_width(one_chip, no_persistent_cache):
     compiled = f.lower(_spec((m, n), one_chip),
                        _spec((n + 1, m + 1), one_chip, jnp.int32)).compile()
     _check(compiled, "flat (12, 32)")
+
+
+def test_rank_rows_kernel_paper_width(one_chip, no_persistent_cache):
+    """B = 1 at (12, 32), tile 256: 8 rank rows of the one matrix per
+    grid step, still exactly one kernel named as the value kernel, so
+    ``radic_combo_roofline.solve`` sees all of its time."""
+    m, n = 12, 32
+    total, padded = _rank_space(m, n)
+    assert rf.rank_rows(1) == 8
+    f = jax.jit(lambda A, t: rf.radic_batched_partial_pallas(
+        A, t, 0, total, padded, tile=TILE, interpret=False))
+    compiled = f.lower(_spec((1, m, n), one_chip),
+                       _spec((n + 1, m + 1), one_chip, jnp.int32)).compile()
+    _check(compiled, "rank rows (1, 12, 32)")
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert _op_list(compiled).count("radic_batched_partial_pallas") == 1
 
 
 def test_batched_combo_kernel_max_batch(one_chip, no_persistent_cache):
