@@ -32,6 +32,8 @@ jnp, scalar           ``_radic_det_flat`` closure (traced jit)
 jnp, batched, cap=C   the same program, AOT-lowered at (C, m, n)
 jnp, batched, cap=∅   ``_radic_det_batched_flat`` closure
 pallas                ``kernels.ops.radic_det[_batched]_pallas``
+pallas, scalar, past  ``kernels.ops.radic_det_pallas`` over the
+  ``WINDOW_RANKS``    plan's rank windows (values only)
 mesh                  ``core.distributed`` maker (via compat.py)
 ====================  ==========================================
 
@@ -59,6 +61,7 @@ from .pascal import INT32_MAX, binom_table, comb
 from .radic import (_radic_det_batched_flat, _radic_det_batched_flat_donated,
                     _radic_det_batched_grad_flat, _radic_det_flat,
                     _radic_det_grad_flat)
+from .unrank import unrank_py
 
 
 def _donation_supported() -> bool:
@@ -70,20 +73,33 @@ def _donation_supported() -> bool:
 
 __all__ = ["DetPlan", "DetEngine", "PlanKey", "default_engine",
            "set_default_engine", "stable_key_hash", "validate_rank_space",
-           "rank_table", "plan_statics"]
+           "rank_table", "plan_statics", "RankWindows", "WINDOW_RANKS",
+           "window_count", "window_slacks", "plan_rank_windows"]
 
 Backend = Literal["jnp", "pallas"]
 
 
 # --------------------------------------------------------- shared validation
+# The longest rank window of a windowed Pallas plan: every rank offset
+# the kernel forms inside a window stays in int32, with 2**16 to spare
+# for the padded grid step.
+WINDOW_RANKS = INT32_MAX + 1 - 2 ** 16
+
+
 def validate_rank_space(m: int, n: int, *, backend: str = "jnp",
-                        mesh_grains: bool = False) -> int:
+                        mesh_grains: bool = False,
+                        windowed: bool = False) -> int:
     """Validate that C(n, m) fits the target backend's rank-integer width
     and return it.  This runs at *plan* time, before any backend dispatch
     — no path may enter a kernel with an overflowing rank space.
 
-    * ``pallas`` — the TPU kernel casts ranks and table to int32
-      regardless of x64, so ``C(n, m) < 2**31`` is a hard requirement.
+    * ``pallas`` — the kernels compute ranks in int32 regardless of x64,
+      so batched, mesh and gradient programs require ``C(n, m) < 2**31``.
+      ``windowed`` is the one exception: the values of one matrix (B = 1)
+      past :data:`WINDOW_RANKS` ranks run as contiguous rank windows
+      (:func:`plan_rank_windows`), and the windowed kernel unranks each
+      lane relative to its window's start, in int32.  The caller plans
+      the windows; :func:`window_count` says whether there are any.
     * ``jnp`` — int32 ranks unless x64 is enabled (then int64).
     * ``mesh_grains`` — grain starts are unranked on the host with exact
       bigints; no width limit at all.
@@ -92,17 +108,86 @@ def validate_rank_space(m: int, n: int, *, backend: str = "jnp",
     if mesh_grains or m > n:
         return total
     if backend == "pallas":
-        if total > INT32_MAX:
+        if total > INT32_MAX and not windowed:
             raise OverflowError(
-                f"C({n},{m}) = {total} exceeds int32 (the Pallas kernel "
-                "computes ranks in int32 regardless of x64); use the "
-                "distributed grain mode.")
+                f"C({n},{m}) = {total} exceeds int32 (the Pallas kernels "
+                "compute ranks in int32 regardless of x64).  Past int32 "
+                "the Pallas backend computes the value of one matrix, in "
+                "rank windows (radic_det(A, backend='pallas')); batched, "
+                "mesh and gradient programs need C(n, m) < 2**31.  Use "
+                "the jnp distributed grain mode for those.")
     else:
         if total > INT32_MAX and not jax.config.jax_enable_x64:
             raise OverflowError(
                 f"C({n},{m}) = {total} exceeds int32; enable x64 or use "
                 "repro.core.distributed (mode='grains').")
     return total
+
+
+# ------------------------------------------------------------- rank windows
+def window_count(total: int) -> int | None:
+    """Rank windows of a B = 1 Pallas determinant over ``total`` ranks:
+    the fewest whose longest (``plan_grains`` cuts evenly) holds at most
+    :data:`WINDOW_RANKS`, or ``None`` where one int32 rank range holds
+    them all and the plain value kernel runs."""
+    if total <= WINDOW_RANKS:
+        return None
+    return -(-total // WINDOW_RANKS)
+
+
+def window_slacks(q0: int, n: int, m: int) -> np.ndarray:
+    """What the windowed kernel knows of the window starting at rank
+    ``q0``: ``(n,)`` int32, entry ``v − 1`` for column v.
+
+    Rank ``q0 + d`` walks the same path as ``S0 = unrank_py(q0)`` until
+    the first column v that S0 places where ``d`` reaches the slack
+    ``C(n − v, m − 1 − pos) − r_v``, ``r_v`` S0's remaining rank at v;
+    there it skips v and goes on as an ordinary walk of the rank
+    ``d − slack``.  A column S0 skips is skipped on the path too.  The
+    entry is that slack, clipped at ``INT32_MAX`` (``d`` never reaches
+    it), where S0 places v, and 0 where it skips v.  ``r_v`` follows
+    from S0 by the hockey-stick sums of :func:`~repro.core.unrank.rank_py`.
+    """
+    out = np.zeros(n, np.int64)
+    r, prev = q0, 0
+    for i, c in enumerate(unrank_py(q0, n, m)):
+        r -= comb(n - prev, m - i) - comb(n - c + 1, m - i)  # skipped
+        out[c - 1] = min(comb(n - c, m - 1 - i) - r, INT32_MAX)
+        prev = c
+    return out.astype(np.int32)
+
+
+@dataclass(frozen=True, eq=False)
+class RankWindows:
+    """Contiguous rank windows of one rank range: each window's start
+    (a host int, any width), its length (at most :data:`WINDOW_RANKS`)
+    and its :func:`window_slacks`, ``(W, n)`` int32."""
+
+    starts: tuple
+    lengths: tuple
+    slacks: np.ndarray = field(repr=False)
+
+    def __len__(self) -> int:
+        return len(self.starts)
+
+
+def plan_rank_windows(m: int, n: int, q_start: int,
+                      count: int) -> RankWindows:
+    """Cut ranks ``[q_start, q_start + count)`` into the fewest windows of
+    at most :data:`WINDOW_RANKS` ranks, ``plan_grains``' even cut, and
+    unrank each window's start on the host with exact ints.  Runs in a
+    ``det.plan.windows`` profiler span (metadata ``windows``,
+    ``window_ranks``: the count and the longest)."""
+    from .distributed import plan_grains  # lazy: distributed builds on us
+    W = window_count(count) or 1
+    with jax.profiler.TraceAnnotation("det.plan.windows", windows=W,
+                                      window_ranks=-(-count // W)):
+        starts, lengths = plan_grains(count, W)
+        starts = tuple(q_start + q for q in starts)
+        slacks = np.stack([window_slacks(q, n, m) if length
+                           else np.zeros(n, np.int32)
+                           for q, length in zip(starts, lengths)])
+    return RankWindows(starts=starts, lengths=tuple(lengths), slacks=slacks)
 
 
 def rank_table(n: int, m: int, *, backend: str = "jnp") -> jax.Array:
@@ -212,6 +297,16 @@ def kernel_rank_rows(key: PlanKey) -> int | None:
     return rank_rows(batch)
 
 
+def kernel_windows(key: PlanKey) -> int | None:
+    """Rank windows of the plan's value program: the scalar single-device
+    Pallas plans past :data:`WINDOW_RANKS` run in :func:`window_count`
+    windows; ``None`` for every other plan."""
+    if (key.backend != "pallas" or key.batched or key.mesh is not None
+            or key.m > key.n):
+        return None
+    return window_count(comb(key.n, key.m))
+
+
 def _make_differentiable(primal: Callable, grad_fn: Callable) -> Callable:
     """Wrap a plan's *traced* primal closure in a ``jax.custom_vjp`` whose
     backward pass is the plan's cofactor-form grad program.
@@ -235,6 +330,25 @@ def _make_differentiable(primal: Callable, grad_fn: Callable) -> Callable:
 
     def det_bwd(A, ct):
         return (grad_fn(A, ct),)
+
+    det_fn.defvjp(det_fwd, det_bwd)
+    return det_fn
+
+
+def _values_only(primal: Callable, why: str) -> Callable:
+    """``primal`` behind a ``jax.custom_vjp`` whose forward rule raises:
+    ``jax.grad`` of a plan with no gradient program fails at once, before
+    any kernel runs, and never returns a wrong value."""
+
+    @jax.custom_vjp
+    def det_fn(A):
+        return primal(A)
+
+    def det_fwd(A):
+        raise OverflowError(why)
+
+    def det_bwd(res, ct):
+        raise OverflowError(why)
 
     det_fn.defvjp(det_fwd, det_bwd)
     return det_fn
@@ -283,6 +397,9 @@ class DetPlan:
     # behind ``jax.grad(radic_det)`` / ``jax.grad(radic_det_batched)``.
     grad_executable: Callable = field(repr=False)
     differentiable: Callable = field(repr=False)
+    # Rank windows of the value program (:func:`kernel_windows`): W for a
+    # scalar Pallas plan past WINDOW_RANKS ranks, None for the others.
+    windows: int | None = None
 
     @property
     def m(self) -> int:
@@ -340,6 +457,7 @@ class DetEngine:
         "_evictions": ("_lock",),
         "_store_hits": ("_lock",),
         "_store_misses": ("_lock",),
+        "_windows": ("_lock",),
     }
 
     def __init__(self, max_plans: int = 128,
@@ -354,6 +472,7 @@ class DetEngine:
         self._evictions = 0
         self._store_hits = 0
         self._store_misses = 0
+        self._windows = 0
         # Optional durable plan store (DESIGN_PERSIST.md): consulted on
         # cache misses, written back asynchronously after fresh builds.
         self.store = None
@@ -382,8 +501,10 @@ class DetEngine:
         """Return the cached plan for this configuration, building it if
         absent.  All validation happens here, before backend dispatch.
         A miss restores or builds inside a ``det.plan.build`` profiler
-        span (the mesh backend's grain planning falls inside it), whose
-        metadata ``rank_rows`` is the plan's :attr:`DetPlan.rank_rows`."""
+        span (the mesh backend's grain planning and a windowed plan's
+        ``det.plan.windows`` fall inside it), whose metadata ``rank_rows``
+        and ``windows`` are the plan's :attr:`DetPlan.rank_rows` and
+        :attr:`DetPlan.windows`."""
         if backend not in ("jnp", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
         if kahan and batched:
@@ -406,7 +527,8 @@ class DetEngine:
                 self._hits += 1
                 return plan
         with jax.profiler.TraceAnnotation("det.plan.build",
-                                          rank_rows=kernel_rank_rows(key)):
+                                          rank_rows=kernel_rank_rows(key),
+                                          windows=kernel_windows(key)):
             built = None
             consulted = self.store is not None and self._persistable(key)
             if consulted:
@@ -452,12 +574,15 @@ class DetEngine:
 
     # ------------------------------------------------------------- the cache
     def cache_info(self) -> dict:
+        """Plan-cache counters; ``windows`` counts the rank windows that
+        windowed plans have dispatched (W per call)."""
         with self._lock:
             return {"size": len(self._plans), "max_plans": self.max_plans,
                     "hits": self._hits, "misses": self._misses,
                     "evictions": self._evictions,
                     "store_hits": self._store_hits,
-                    "store_misses": self._store_misses}
+                    "store_misses": self._store_misses,
+                    "windows": self._windows}
 
     def cached_keys(self) -> list[PlanKey]:
         """LRU order, oldest first (introspection/tests)."""
@@ -624,7 +749,8 @@ class DetEngine:
         total = validate_rank_space(
             m, n, backend=key.backend,
             mesh_grains=key.mesh is not None and not key.batched
-            and key.mode == "grains" and key.backend == "jnp")
+            and key.mode == "grains" and key.backend == "jnp",
+            windowed=kernel_windows(key) is not None)
         if m > n:
             exe = _zeros_batched if key.batched else _zeros_scalar
             def execute(A, _exe=exe):
@@ -727,6 +853,8 @@ class DetEngine:
 
     def _build_pallas(self, key: PlanKey, total: int) -> DetPlan:
         from repro.kernels import ops  # lazy: kernels depend on core
+        if kernel_windows(key) is not None:
+            return self._build_windowed(key, total, ops)
         fn = (ops.radic_det_batched_pallas if key.batched
               else ops.radic_det_pallas)
         gfn = (ops.radic_det_batched_grad_pallas if key.batched
@@ -739,6 +867,33 @@ class DetEngine:
                        executable=execute, grad_executable=grad_execute,
                        differentiable=_make_differentiable(
                            execute, grad_execute))
+
+    def _build_windowed(self, key: PlanKey, total: int, ops) -> DetPlan:
+        """One matrix past WINDOW_RANKS ranks: the windows are planned
+        here, once, and every call runs them all in one program of the
+        windowed kernel.  Values only: no gradient program is built."""
+        windows = plan_rank_windows(key.m, key.n, 0, total)
+        run = functools.partial(ops.radic_det_pallas, q_start=0,
+                                count=total, windows=windows)
+
+        def execute(A, _run=run, _w=len(windows)):
+            with self._lock:
+                self._windows += _w
+            return _run(A)
+
+        why = (f"C({key.n},{key.m}) = {total} exceeds int32: the windowed "
+               "Pallas plan computes values only; gradients past int32 "
+               "are not supported")
+
+        def grad_execute(A, ct):
+            raise OverflowError(why)
+
+        return DetPlan(key=key, total=total,
+                       chunk=int(min(key.chunk, total)), degenerate=False,
+                       lowered=False, table=None, executable=execute,
+                       grad_executable=grad_execute,
+                       differentiable=_values_only(execute, why),
+                       windows=len(windows))
 
     def _build_mesh(self, key: PlanKey, total: int) -> DetPlan:
         from .distributed import (make_batched_distributed_evaluator,

@@ -107,9 +107,12 @@ def radic_det(A: jax.Array, *, chunk: int = 2048, kahan: bool = False,
     """Radic determinant (paper Definition 3), rank-parallel flat mode.
 
     Single-device streaming evaluation; for mesh distribution see
-    :func:`repro.core.distributed.radic_det_distributed`.  Requires
-    ``C(n, m) < 2**31`` (int32 ranks) unless x64 is enabled — beyond that
-    use the distributed grain mode (bigint grain starts).  Routed through
+    :func:`repro.core.distributed.radic_det_distributed`.  The jnp
+    backend requires ``C(n, m) < 2**31`` (int32 ranks) unless x64 is
+    enabled — beyond that use the distributed grain mode (bigint grain
+    starts).  The pallas backend runs past int32 as rank windows
+    (``DetPlan.windows``, the engine's ``plan_rank_windows``): the value
+    only, ``jax.grad`` of such a plan raises.  Routed through
     the default :class:`~repro.core.engine.DetEngine`: the rank-width
     guards run at plan time, *before* backend dispatch, and the plan
     (Pascal table, clamped chunk, validated total) is cached per shape.

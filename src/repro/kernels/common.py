@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["det_slabs", "unrank_lanes", "selector_slabs", "minor_slabs",
-           "rank_row_slabs", "sign_lanes",
+           "rank_row_slabs", "sign_lanes", "unrank_window_lanes",
            "batched_det_ge", "unrank_tile", "onehot_gather_minors",
            "radic_signs"]
 
@@ -160,6 +160,46 @@ def sign_lanes(combos: list[jax.Array], m: int) -> jax.Array:
         s = s + c
     parity = (s + m * (m + 1) // 2) & 1
     return (1 - 2 * parity).astype(jnp.float32)
+
+
+def unrank_window_lanes(ds: jax.Array, n: int, m: int, table, slack,
+                        base=0) -> list[jax.Array]:
+    """Combinatorial addition relative to a window's start: local offsets
+    ``ds (S, T)`` int32 of the window starting at rank q0 -> the
+    combination of rank ``q0 + d`` as m ``(S, T)`` rows, as
+    :func:`unrank_lanes` lays them out.  No lane forms a global rank.
+
+    ``slack[base + v]`` is column v's entry of the window's
+    ``core.engine.window_slacks``: S0 = unrank(q0)'s slack where S0
+    places v, 0 where it skips v.  A lane starts on S0's path with
+    ``q = d``.  On the path it places v where S0 does and ``q`` is under
+    the slack, and otherwise leaves the path with ``q − slack``; off the
+    path it runs :func:`unrank_lanes`' walk.  ``q`` stays under 2³¹, so
+    ``table`` may be clipped at ``INT32_MAX``: a clipped entry only ever
+    meets a ``q`` below it, and is never subtracted.
+    """
+
+    def step(v, carry):
+        pos, q, on, combo = carry
+        row = (n - v) * (m + 1) + m - 1
+        at = [pos == p for p in range(m)]
+        cnt = jnp.zeros_like(q)
+        for p in range(m):
+            cnt = jnp.where(at[p], table[row - p], cnt)
+        cnt = jnp.where(on == 1, slack[base + v], cnt)
+        active = pos < m
+        place = active & (q < cnt)
+        combo = tuple(jnp.where(place & at[p], v, c)
+                      for p, c in enumerate(combo))
+        q = jnp.where(active & ~place, q - cnt, q)
+        # on the path, a column with slack 0 is one S0 skips: stay on it
+        on = jnp.where(place | (cnt == 0), on, 0)
+        return pos + place.astype(pos.dtype), q, on, combo
+
+    zero = jnp.zeros_like(ds)
+    _, _, _, combo = jax.lax.fori_loop(
+        1, n + 1, step, (zero, ds, jnp.ones_like(ds), (zero,) * m))
+    return list(combo)
 
 
 # ------------------------------------------------- (T, …)-layout adapters

@@ -8,18 +8,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.engine import validate_rank_space
-from repro.core.pascal import binom_table
+from repro.core.engine import (plan_rank_windows, validate_rank_space,
+                               window_count)
+from repro.core.pascal import INT32_MAX, binom_table
 
 from .minor_det import minor_det_pallas
 from .radic_fused import (radic_batched_grad_partial_pallas,
                           radic_batched_partial_pallas,
                           radic_batched_partial_pallas_bygrid,
                           radic_partial_pallas)
+from .radic_windowed import radic_windowed_partial_pallas
 from .unrank_kernel import unrank_pallas
 
 __all__ = ["minor_det", "unrank", "radic_partial_pallas",
-           "radic_det_pallas", "radic_batched_partial_pallas",
+           "radic_det_pallas", "capped_table",
+           "radic_windowed_partial_pallas", "radic_batched_partial_pallas",
            "radic_det_batched_pallas", "radic_det_batched_pallas_bygrid",
            "radic_det_grad_pallas", "radic_det_batched_grad_pallas"]
 
@@ -40,19 +43,44 @@ def unrank(qs: jax.Array, n: int, m: int, *, tile: int = 256,
     return unrank_pallas(qs, n, m, table, tile=tile, interpret=interpret)
 
 
+def capped_table(n: int, m: int) -> np.ndarray:
+    """The Pascal table of the windowed kernel: int32, entries clipped at
+    ``INT32_MAX`` (``common.unrank_window_lanes`` says why that is
+    exact)."""
+    # the windowed kernel's own guard: only int32 offsets meet this table
+    validate_rank_space(m, n, backend="pallas", windowed=True)
+    return np.minimum(binom_table(n, m, dtype=np.int64),
+                      INT32_MAX).astype(np.int32)
+
+
 def radic_det_pallas(A: jax.Array, q_start: int = 0, count: int | None = None,
-                     *, tile: int = 256,
-                     interpret: bool | None = None) -> jax.Array:
-    """Radic determinant (or a rank-range partial) via the fused kernel."""
+                     *, tile: int = 256, interpret: bool | None = None,
+                     windows=None) -> jax.Array:
+    """Radic determinant (or a rank-range partial) via the fused kernel.
+
+    Past ``WINDOW_RANKS`` ranks (C(n, m) past int32) the range runs as
+    rank windows in the windowed kernel, and ``q_start`` may be any host
+    int: ``windows`` takes the range's ``plan_rank_windows``, which a
+    plan computes once; without it they are planned here."""
     m, n = A.shape
     if m > n:
         return jnp.zeros((), A.dtype)
-    # shared plan validation: int32 rank width is a hard kernel limit
-    total = validate_rank_space(m, n, backend="pallas")
+    # shared plan validation: past int32 only as rank windows
+    total = validate_rank_space(m, n, backend="pallas", windowed=True)
     if count is None:
         count = total - q_start
     if q_start + count > total:
         raise ValueError("rank range exceeds C(n, m)")
+    if window_count(total) is not None:
+        if windows is None:
+            windows = plan_rank_windows(m, n, q_start, count)
+        longest = max(windows.lengths)
+        padded = max(tile, ((longest + tile - 1) // tile) * tile)
+        parts = radic_windowed_partial_pallas(
+            A, jnp.asarray(capped_table(n, m)),
+            np.asarray(windows.lengths, np.int32), windows.slacks, padded,
+            tile=tile, interpret=interpret)
+        return parts.sum().astype(A.dtype)
     table = jnp.asarray(binom_table(n, m, dtype=np.int32))
     padded = max(tile, ((count + tile - 1) // tile) * tile)
     return radic_partial_pallas(A, table, q_start, count, padded,

@@ -92,15 +92,20 @@ def test_pallas_routing_bit_identity(rng):
 def test_pallas_overflow_guard_runs_at_plan_time():
     """Regression (ISSUE 3 satellite): the pallas path historically
     dispatched before the C(n, m) width guard.  C(40, 16) > 2**31 must
-    raise OverflowError at *plan* time for every pallas entry point —
-    binding an evaluator must already fail, not its first call."""
+    raise OverflowError at *plan* time for every batched pallas entry
+    point — binding an evaluator must already fail, not its first call.
+    The scalar plan runs past int32 as rank windows, planned (not
+    dispatched) at plan time, and refuses a gradient before any kernel."""
     assert comb(40, 16) > INT32_MAX
     with pytest.raises(OverflowError):
         DetEngine().plan(16, 40, backend="pallas")
     with pytest.raises(OverflowError):
         make_batched_evaluator(16, 40, backend="pallas")
+    plan = DetEngine().plan(16, 40, batched=False, backend="pallas")
+    assert plan.windows == -(-comb(40, 16) // (2 ** 31 - 2 ** 16))
     with pytest.raises(OverflowError):
-        radic_det(jnp.ones((16, 40), jnp.float32), backend="pallas")
+        jax.grad(lambda A: radic_det(A, backend="pallas"))(
+            jnp.ones((16, 40), jnp.float32))
     with pytest.raises(OverflowError):
         radic_det_batched(jnp.ones((2, 16, 40), jnp.float32),
                           backend="pallas")

@@ -82,8 +82,12 @@ def test_bf16_input_promoted(rng):
 
 
 def test_int32_guard():
+    """Past int32 only the scalar value runs (as rank windows); the
+    batched and gradient entry points keep the int32 guard."""
     with pytest.raises(OverflowError):
-        ops.radic_det_pallas(jnp.ones((16, 40), jnp.float32))
+        ops.radic_det_batched_pallas(jnp.ones((1, 16, 40), jnp.float32))
+    with pytest.raises(OverflowError):
+        ops.radic_det_grad_pallas(jnp.ones((16, 40), jnp.float32), 1.0)
 
 
 # --------------------------------------------- B = 1: rank rows on sublanes

@@ -11,7 +11,9 @@ Widths: the flat kernel at the paper's (12, 32) — C(32, 12) =
 entry point, whose 8 rank rows per step must stay one kernel under the
 benchmark's name; the batched combo kernel at the serving bound
 ``BucketPolicy.max_batch`` = 64 with (8, 24); the gradient kernel at
-B = 16 with (8, 24); and the four-chip grains program at (12, 32).
+B = 16 with (8, 24); the four-chip grains program at (12, 32); and the
+windowed B = 1 program at (12, 38), C(38, 12) = 2,707,475,148 > 2³¹ − 1
+ranks, in two rank windows.
 """
 
 import jax
@@ -147,3 +149,43 @@ def test_mesh_grains_four_chips(topo, no_persistent_cache, monkeypatch):
     _check(compiled, "mesh grains (12, 32) on 2x2")
     assert "all-reduce" in compiled.as_text()
     assert "radic_batched_partial_pallas" in _op_names(compiled)
+
+
+@pytest.mark.parametrize("m,n,windows", [(12, 38, 2), (20, 40, 65)])
+def test_windowed_kernel_past_int32(one_chip, no_persistent_cache, m, n,
+                                    windows):
+    """(12, 38) and (20, 40), tile 256: the one matrix's rank windows run
+    in one program, exactly one kernel named as the windowed kernel, so
+    ``radic_window_roofline.solve`` sees all of its time."""
+    from repro.core.engine import plan_rank_windows
+    from repro.kernels.radic_windowed import radic_windowed_partial_pallas
+    total = comb(n, m)
+    assert total > 2 ** 31 - 1
+    w = plan_rank_windows(m, n, 0, total)
+    assert len(w) == windows
+    padded = -(-max(w.lengths) // TILE) * TILE
+    f = jax.jit(lambda A, t, c, s: radic_windowed_partial_pallas(
+        A, t, c, s, padded, tile=TILE, interpret=False))
+    compiled = f.lower(_spec((m, n), one_chip),
+                       _spec((n + 1, m + 1), one_chip, jnp.int32),
+                       _spec((len(w),), one_chip, jnp.int32),
+                       _spec((len(w), n), one_chip, jnp.int32)).compile()
+    _check(compiled, f"windowed ({m}, {n})")
+    ops_ = _op_list(compiled)
+    assert ops_.count("radic_windowed_partial_pallas") == 1
+    assert "radic_batched_partial_pallas" not in ops_
+
+
+def test_int32_paper_program_has_no_windowed_op(one_chip,
+                                                no_persistent_cache):
+    """The (12, 32) B = 1 program within int32 keeps its one value kernel
+    and holds no windowed op."""
+    m, n = 12, 32
+    total, padded = _rank_space(m, n)
+    f = jax.jit(lambda A, t: rf.radic_batched_partial_pallas(
+        A, t, 0, total, padded, tile=TILE, interpret=False))
+    compiled = f.lower(_spec((1, m, n), one_chip),
+                       _spec((n + 1, m + 1), one_chip, jnp.int32)).compile()
+    ops_ = _op_list(compiled)
+    assert ops_.count("radic_batched_partial_pallas") == 1
+    assert "radic_windowed_partial_pallas" not in ops_
