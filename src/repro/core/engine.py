@@ -297,6 +297,19 @@ def kernel_rank_rows(key: PlanKey) -> int | None:
     return rank_rows(batch)
 
 
+def kernel_gather(key: PlanKey) -> str | None:
+    """How the plan's value kernel gathers its minors: ``"bf16x3"`` where
+    it runs the rank-row layout (one bf16 pass over A's exact three-part
+    split, ``kernels.common.rank_row_slabs``; windowed plans too),
+    ``"f32_highest"`` where it gathers a stack at ``Precision.HIGHEST``
+    (``common.minor_slabs``, as every gradient kernel does), ``None``
+    where :func:`kernel_rank_rows` is ``None``."""
+    rows = kernel_rank_rows(key)
+    if rows is None:
+        return None
+    return "bf16x3" if rows > 1 else "f32_highest"
+
+
 def kernel_windows(key: PlanKey) -> int | None:
     """Rank windows of the plan's value program: the scalar single-device
     Pallas plans past :data:`WINDOW_RANKS` run in :func:`window_count`
@@ -423,6 +436,12 @@ class DetPlan:
         rows per sublane group, 1, or ``None`` (:func:`kernel_rank_rows`)."""
         return kernel_rank_rows(self.key)
 
+    @property
+    def gather(self) -> str | None:
+        """The engagement record of the value kernel's gather:
+        ``"bf16x3"``, ``"f32_highest"`` or ``None`` (:func:`kernel_gather`)."""
+        return kernel_gather(self.key)
+
     def __call__(self, A: jax.Array) -> jax.Array:
         return self.executable(A)
 
@@ -502,9 +521,9 @@ class DetEngine:
         absent.  All validation happens here, before backend dispatch.
         A miss restores or builds inside a ``det.plan.build`` profiler
         span (the mesh backend's grain planning and a windowed plan's
-        ``det.plan.windows`` fall inside it), whose metadata ``rank_rows``
-        and ``windows`` are the plan's :attr:`DetPlan.rank_rows` and
-        :attr:`DetPlan.windows`."""
+        ``det.plan.windows`` fall inside it), whose metadata ``rank_rows``,
+        ``gather`` and ``windows`` are the plan's :attr:`DetPlan.rank_rows`,
+        :attr:`DetPlan.gather` and :attr:`DetPlan.windows`."""
         if backend not in ("jnp", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
         if kahan and batched:
@@ -528,6 +547,7 @@ class DetEngine:
                 return plan
         with jax.profiler.TraceAnnotation("det.plan.build",
                                           rank_rows=kernel_rank_rows(key),
+                                          gather=kernel_gather(key),
                                           windows=kernel_windows(key)):
             built = None
             consulted = self.store is not None and self._persistable(key)

@@ -4,16 +4,16 @@ The rank tile lives on the 128-wide lane axis: ``T`` ranks form one
 ``(1, T)`` row shared by the matrices of a batch, or ``(S, T)`` rows
 when one matrix fills the sublanes with S rank rows of its own.  A
 per-minor quantity is an ``(S, T)`` slab, one matrix and rank per
-(sublane, lane).  An m×m matrix is a python list of m·m slabs, so the
-Gaussian elimination, the products and the row swaps are static unrolls
-over the small m of elementwise ops on whole slabs.  The whole set of
-constructs is what Mosaic lowers: elementwise ops, plain 2-D matmuls
-without batch dims, static slices and scalar table reads — no
-``dynamic_slice``, no batched ``dot_general``, no ``reduce_prod``.
-
+(sublane, lane); an m×m matrix is a list of m·m slabs, and the Gaussian
+elimination is a static unroll over m of elementwise ops on them, in
+what Mosaic lowers: no ``dynamic_slice``, batched ``dot_general`` or
+``reduce_prod``.  Column gathers are one-hot MXU matmuls whose entries
+are A's exactly: f32 at ``Precision.HIGHEST`` (:func:`minor_slabs`), or
+at B = 1 one bf16 pass over A's exact three-part split
+(:func:`rank_row_slabs`, :func:`split_bf16x3`).  Both lose only what the
+TPU flushes: an entry under about 2⁻¹⁰⁰ whose low part is subnormal.
 The same functions run as ordinary jnp inside jit, so the fused kernels,
-their CPU interpret mode and the ``(T, …)``-layout adapters at the bottom
-share one implementation of the math.
+their CPU interpret mode and the (T, …)-layout adapters share the math.
 """
 
 from __future__ import annotations
@@ -24,7 +24,7 @@ import jax.numpy as jnp
 __all__ = ["det_slabs", "unrank_lanes", "selector_slabs", "minor_slabs",
            "rank_row_slabs", "sign_lanes", "unrank_window_lanes",
            "batched_det_ge", "unrank_tile", "onehot_gather_minors",
-           "radic_signs"]
+           "radic_signs", "split_bf16x3", "rank_row_operand"]
 
 
 def det_slabs(M: list[list[jax.Array]]) -> jax.Array:
@@ -130,26 +130,26 @@ def minor_slabs(A: jax.Array, sel: list[jax.Array]
     return M
 
 
-def rank_row_slabs(A: jax.Array, combos: list[jax.Array], n: int
+def rank_row_slabs(parts: jax.Array, combos: list[jax.Array]
                    ) -> list[list[jax.Array]]:
     """Column gather for one matrix whose sublanes hold S rows of ranks:
-    ``A (m, n)``, ``combos`` m ``(S, T)`` rows -> slabs ``M[k][a] (S, T)
-    = A[a, combos[k][s, t] - 1]``, the transposed minors of every rank,
-    as :func:`minor_slabs` lays them out.
-
-    The selectors differ per rank row, so each k takes S one-hot matmuls
-    ``(m, n) @ (n, T)`` at full f32 precision, exact as in
-    :func:`minor_slabs`, and row a of the S products fills the sublanes
-    of ``M[k][a]``."""
-    S = combos[0].shape[0]
+    ``parts`` its :func:`rank_row_operand`, ``combos`` m ``(S, T)`` rows ->
+    slabs ``M[k][a] (S, T) = A[a, combos[k][s, t] - 1]`` bit for bit, as
+    :func:`minor_slabs` lays them out.  Per k, S single-pass bf16 matmuls
+    ``(m, 3·np) @ (3·np, T)`` against the one-hot stacked thrice: the f32
+    accumulator adds the entry's hi, mid and lo, exact in any order
+    (:func:`split_bf16x3`); row a of the S products fills ``M[k][a]``."""
+    S, T = combos[0].shape
+    jidx = jax.lax.broadcasted_iota(jnp.int32, (parts.shape[1] // 3, T), 0)
     M = []
     for c in combos:
-        sel = selector_slabs([c[s:s + 1] for s in range(S)], n)
-        cols = [jax.lax.dot(A, oh, precision=jax.lax.Precision.HIGHEST,
-                            preferred_element_type=jnp.float32)
-                for oh in sel]
-        M.append([jnp.concatenate([r[a:a + 1] for r in cols], axis=0)
-                  for a in range(A.shape[0])])
+        rows = []
+        for s in range(S):
+            oh = (jidx == c[s:s + 1] - 1).astype(jnp.bfloat16)
+            rows.append(jax.lax.dot(parts, jnp.concatenate([oh, oh, oh]),
+                                    preferred_element_type=jnp.float32))
+        M.append([jnp.concatenate([r[a:a + 1] for r in rows], axis=0)
+                  for a in range(len(combos))])
     return M
 
 
@@ -200,6 +200,44 @@ def unrank_window_lanes(ds: jax.Array, n: int, m: int, table, slack,
     _, _, _, combo = jax.lax.fori_loop(
         1, n + 1, step, (zero, ds, jnp.ones_like(ds), (zero,) * m))
     return list(combo)
+
+
+def split_bf16x3(A: jax.Array) -> jax.Array:
+    """``A (…)`` f32 -> its three bf16 parts ``(3, …)``, hi, mid and lo,
+    whose f32 sum is A exactly, in whichever order they are added.
+
+    Significand truncation: hi is A with the low 16 bits of its f32 word
+    cleared (sign, exponent and the top 7 stored bits), mid the same cut
+    of ``A − hi``, and ``lo = A − hi − mid``.  Each subtraction is exact,
+    each part holds its own 8 of A's 24 significant bits, so each is a
+    bf16 value and the f32 sums of the parts lose nothing.  A zero part
+    takes A's sign, so −0 re-adds to −0.  The one limit, shared with
+    ``Precision.HIGHEST``'s own bf16 passes: the TPU flushes a subnormal
+    part, so an entry under about 2⁻¹⁰⁰ may lose its low bits."""
+    A = A.astype(jnp.float32)
+
+    def trunc(x):
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+
+    hi = trunc(A)
+    rest = A - hi
+    mid = trunc(rest)
+    lo = rest - mid
+    return jnp.stack([jnp.copysign(p, A) for p in (hi, mid, lo)]
+                     ).astype(jnp.bfloat16)
+
+
+def rank_row_operand(A: jax.Array) -> jax.Array:
+    """The gather operand of :func:`rank_row_slabs` for ``A (m, n)``: its
+    three bf16 parts side by side ``(m, 3·np)``, part p in columns
+    ``[p·np, p·np + n)`` and zeros after, np = n rounded up to the 16
+    rows of a bf16 tile so that the one-hot stacks at tile boundaries."""
+    m, n = A.shape
+    np_ = -(-n // 16) * 16
+    parts = jnp.pad(split_bf16x3(A), ((0, 0), (0, 0), (0, np_ - n)))
+    return parts.transpose(1, 0, 2).reshape(m, 3 * np_)
 
 
 # ------------------------------------------------- (T, …)-layout adapters

@@ -21,11 +21,11 @@ Two layouts of the 8 f32 sublanes, chosen from the static batch size
   multiple of 8 with zero matrices, whose results are sliced off.
 * B = 1, rank rows on sublanes (R = 8): sublane s of a grid step holds
   ranks ``step·8T + s·T + lane`` of the one matrix, so a step covers 8T
-  ranks and no sublane computes a zero matrix.  The selectors differ per
-  sublane, so the gather takes one ``(m, n) @ (n, T)`` matmul per rank
-  row and fills the sublanes of each slab from the 8 products
-  (``common.rank_row_slabs``); the elimination reads the same slabs.
-  The matrix enters as it is, ``(1, m, n)``.
+  ranks and no sublane computes a zero matrix.  The matrix enters as its
+  exact bf16 parts side by side, ``(1, m, 3·np)``, and each rank row
+  takes one bf16 one-hot matmul whose f32 outputs are A's entries bit
+  for bit (``common.rank_row_slabs``); the elimination reads the slabs.
+  B ≥ 2 and the gradient gather f32 at ``Precision.HIGHEST``.
 
 ``q_start``, the whole steps and the tail of ``count``, and the
 flattened Pascal table are scalar-prefetch operands (SMEM).  The
@@ -45,8 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import (batched_det_ge, det_slabs, minor_slabs,
-                     onehot_gather_minors, radic_signs, rank_row_slabs,
+from .common import (batched_det_ge, det_slabs, minor_slabs, radic_signs,
+                     onehot_gather_minors, rank_row_operand, rank_row_slabs,
                      selector_slabs, sign_lanes, unrank_lanes, unrank_tile)
 
 __all__ = ["radic_partial_pallas", "radic_batched_combo_kernel",
@@ -118,7 +118,7 @@ def radic_batched_combo_kernel(n: int, m: int, tile: int, rows: int,
                                groups: int, qinfo_ref, table_ref, a_ref,
                                out_ref):
     """Forward: per-lane Σ sign·det of this tile for every matrix, or at
-    ``rows`` = 8 for the rank rows of the one matrix."""
+    ``rows`` = 8 for the rank rows of the one matrix, from its bf16 parts."""
     valid, combos, signs = _tile_setup(n, m, tile, rows, qinfo_ref,
                                        table_ref)
 
@@ -127,7 +127,7 @@ def radic_batched_combo_kernel(n: int, m: int, tile: int, rows: int,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     if rows > 1:
-        dets = det_slabs(rank_row_slabs(a_ref[0], combos, n))
+        dets = det_slabs(rank_row_slabs(a_ref[0], combos))
         out_ref[0] += jnp.where(valid, signs * dets, 0.0)
         return
     sel = selector_slabs(combos, n)
@@ -224,7 +224,7 @@ def radic_batched_partial_pallas(As: jax.Array, table: jax.Array,
     and a grid step covers ``8·tile`` ranks."""
     B, m, n = As.shape
     rows = rank_rows(B)
-    a = _grouped(As) if rows == 1 else As.astype(jnp.float32)
+    a = _grouped(As) if rows == 1 else rank_row_operand(As[0])[None]
     G = a.shape[0]
     out = _pallas_tiles(
         functools.partial(radic_batched_combo_kernel, n, m, tile, rows, G),

@@ -10,7 +10,8 @@ even cut) and unranks each window's start on the host with exact ints
     window w, step i ──offsets d < 2³¹ (int32)──► unrank relative to the
                      window's start (common.unrank_window_lanes)
                      ──► the B = 1 pipeline of radic_fused: 8 rank rows
-                     on the sublanes, gather, pivoted GE, sign · mask
+                     on the sublanes, gather from A's bf16 parts,
+                     pivoted GE, sign · mask
 
 The grid is ``(W, steps)``: each window's whole steps and tail (SMEM,
 ``qinfo``), its ``(n,)`` slacks (SMEM) and the Pascal table clipped at
@@ -30,7 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import det_slabs, rank_row_slabs, sign_lanes, unrank_window_lanes
+from .common import (det_slabs, rank_row_operand, rank_row_slabs,
+                     sign_lanes, unrank_window_lanes)
 from .radic_fused import SUBLANES, lane_ranks, resolve_interpret
 
 __all__ = ["radic_windowed_combo_kernel", "radic_windowed_partial_pallas"]
@@ -54,7 +56,7 @@ def radic_windowed_combo_kernel(n: int, m: int, tile: int, qinfo_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    dets = det_slabs(rank_row_slabs(a_ref[0], combos, n))
+    dets = det_slabs(rank_row_slabs(a_ref[0], combos))
     out_ref[0] += jnp.where(valid, signs * dets, 0.0)
 
 
@@ -71,6 +73,7 @@ def radic_windowed_partial_pallas(A: jax.Array, table: jax.Array,
     ``INT32_MAX``, ``padded_count`` the static grid extent of the longest
     window."""
     m, n = A.shape
+    parts = rank_row_operand(A)
     W = counts.shape[0]
     step = SUBLANES * tile
     counts = jnp.asarray(counts, jnp.int32)
@@ -81,7 +84,8 @@ def radic_windowed_partial_pallas(A: jax.Array, table: jax.Array,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(W, max(1, -(-padded_count // step))),
-            in_specs=[pl.BlockSpec((1, m, n), lambda w, i, *_: (0, 0, 0))],
+            in_specs=[pl.BlockSpec((1, *parts.shape),
+                                   lambda w, i, *_: (0, 0, 0))],
             out_specs=pl.BlockSpec((1, SUBLANES, tile),
                                    lambda w, i, *_: (w, 0, 0))),
         out_shape=jax.ShapeDtypeStruct((W, SUBLANES, tile), jnp.float32),
@@ -90,6 +94,6 @@ def radic_windowed_partial_pallas(A: jax.Array, table: jax.Array,
         interpret=resolve_interpret(interpret),
         name="radic_windowed_partial_pallas",
     )(qinfo, jnp.asarray(slacks, jnp.int32).reshape(-1),
-      table.astype(jnp.int32).reshape(-1), A[None].astype(jnp.float32))
+      table.astype(jnp.int32).reshape(-1), parts[None])
     # the sublanes and lanes of a window first, as the B = 1 kernel sums
     return out.sum(axis=(1, 2))

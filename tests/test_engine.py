@@ -296,3 +296,42 @@ def test_plan_build_span_carries_rank_rows(tmp_path):
     stats = [dict(ev.stats) for plane in planes for line in plane.lines
              for ev in line.events if ev.name == "det.plan.build"]
     assert [s.get("rank_rows") for s in stats] == [8, 1]
+
+
+# B = 1 and windowed plans gather with one bf16 pass over A's exact split;
+# a stack (a serving value bucket at 64, a gradient bucket at 16) at
+# Precision.HIGHEST, as every gradient kernel does.
+GATHER_PLANS = [
+    ((3, 7), {"batched": False, "backend": "pallas"}, "bf16x3"),
+    ((12, 38), {"batched": False, "backend": "pallas"}, "bf16x3"),
+    ((3, 7), {"capacity": 64, "backend": "pallas"}, "f32_highest"),
+    ((3, 7), {"capacity": 16, "backend": "pallas"}, "f32_highest"),
+]
+
+
+@pytest.mark.parametrize("shape,plan_kw,gather", GATHER_PLANS + [
+    ((3, 7), {"capacity": None, "backend": "pallas"}, None),
+    ((3, 7), {"batched": False, "backend": "jnp"}, None),
+])
+def test_plan_reports_kernel_gather(shape, plan_kw, gather):
+    """The engagement record of the value kernel's gather, derived from
+    the key as ``rank_rows`` is: None without a kernel or a fixed batch."""
+    plan = DetEngine().plan(*shape, **plan_kw)
+    assert plan.gather == gather
+    assert (gather == "bf16x3") == (plan.rank_rows == 8)
+
+
+def test_plan_build_span_carries_gather(tmp_path):
+    from jax.profiler import ProfileData
+    eng = DetEngine()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for shape, plan_kw, _ in GATHER_PLANS:
+            eng.plan(*shape, **plan_kw)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    planes = ProfileData.from_file(str(path)).planes
+    stats = [dict(ev.stats) for plane in planes for line in plane.lines
+             for ev in line.events if ev.name == "det.plan.build"]
+    assert [s.get("gather") for s in stats] == [g for *_, g in GATHER_PLANS]

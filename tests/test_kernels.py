@@ -146,6 +146,63 @@ def test_rank_rows_by_batch():
     assert [rank_rows(b) for b in (1, 2, 7, 8, 64)] == [8, 1, 1, 1, 1]
 
 
+# The B = 1 gather: one bf16 pass over A's three-part split, exact.
+
+def _split_inputs(kind, rng):
+    if kind == "exponents":
+        x = (rng.uniform(1, 2, 4096) * 2.0 ** rng.integers(-90, 91, 4096)
+             * rng.choice([-1, 1], 4096))
+    elif kind == "zeros":
+        x = np.array([0.0, -0.0, 1.0, -1.0])
+    elif kind == "powers_of_two":
+        x = np.concatenate([2.0 ** np.arange(-90, 91),
+                            -(2.0 ** np.arange(-90, 91))])
+    else:  # all 24 significand bits set, both signs, across exponents
+        x = (2.0 - 2.0 ** -23) * 2.0 ** np.arange(-90, 91)
+        x = np.concatenate([x, -x])
+    return x.astype(np.float32)
+
+
+@pytest.mark.parametrize("kind", ["exponents", "zeros", "powers_of_two",
+                                  "full_significand"])
+def test_split_bf16x3_is_exact(kind, rng):
+    """The three parts are bf16 values and re-add in f32 to A bit for bit
+    in every order, as the MXU's accumulator may add them."""
+    from repro.kernels.common import split_bf16x3
+    x = _split_inputs(kind, rng)
+    parts = split_bf16x3(jnp.asarray(x))
+    assert parts.dtype == jnp.bfloat16 and parts.shape == (3, *x.shape)
+    hi, mid, lo = np.asarray(parts.astype(jnp.float32))
+    for p in (hi, mid, lo):
+        assert not (p.view(np.uint32) & 0xFFFF).any()
+    for back in ((hi + mid) + lo, (hi + lo) + mid, (mid + lo) + hi):
+        assert back.dtype == np.float32
+        np.testing.assert_array_equal(back.view(np.uint32),
+                                      x.view(np.uint32))
+
+
+@pytest.mark.parametrize("m,n", [(12, 32), (12, 38), (6, 6)])
+def test_rank_row_slabs_gather_exactly(m, n, rng):
+    """Every gathered entry is ``A[a, combos[k] − 1]`` bit for bit, over
+    entries whose exponents span 2⁻⁶⁰…2⁶⁰."""
+    from repro.kernels.common import rank_row_operand, rank_row_slabs
+    S, T = 8, 128
+    A = (rng.normal(size=(m, n)) * 2.0 ** rng.integers(-60, 61, (m, n))
+         ).astype(np.float32)
+    combos = np.sort(np.stack([rng.permutation(n)[:m] + 1
+                               for _ in range(S * T)]), axis=1)
+    rows = [jnp.asarray(combos[:, k].reshape(S, T), jnp.int32)
+            for k in range(m)]
+    M = rank_row_slabs(rank_row_operand(jnp.asarray(A)), rows)
+    for k in range(m):
+        cols = combos[:, k].reshape(S, T) - 1
+        for a in range(m):
+            got = np.asarray(M[k][a])
+            assert got.dtype == np.float32
+            np.testing.assert_array_equal(got.view(np.uint32),
+                                          A[a][cols].view(np.uint32))
+
+
 @pytest.mark.parametrize("rows", [1, 8])
 def test_lane_ranks_bounded_by_count(rows):
     """The in-kernel rank offsets, outside a kernel: a grid that runs

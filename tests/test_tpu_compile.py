@@ -9,11 +9,11 @@ every other kernel test runs in, accepts all of those.
 Widths: the flat kernel at the paper's (12, 32) — C(32, 12) =
 225,792,840 < 2³¹ ranks — and the same B = 1 stack through the batched
 entry point, whose 8 rank rows per step must stay one kernel under the
-benchmark's name; the batched combo kernel at the serving bound
-``BucketPolicy.max_batch`` = 64 with (8, 24); the gradient kernel at
-B = 16 with (8, 24); the four-chip grains program at (12, 32); and the
-windowed B = 1 program at (12, 38), C(38, 12) = 2,707,475,148 > 2³¹ − 1
-ranks, in two rank windows.
+benchmark's name and gather from A's bf16 parts; the batched combo
+kernel at the serving bound ``BucketPolicy.max_batch`` = 64 with
+(8, 24); the gradient kernel at B = 16 with (8, 24); the four-chip
+grains program at (12, 32); and the windowed B = 1 program at (12, 38),
+C(38, 12) = 2,707,475,148 > 2³¹ − 1 ranks, in two rank windows.
 """
 
 import jax
@@ -79,6 +79,19 @@ def _op_names(compiled) -> set[str]:
     return set(_op_list(compiled))
 
 
+def _kernel_line(compiled, name: str) -> str:
+    (line,) = [x for x in compiled.as_text().splitlines()
+               if x.strip().startswith(f"%{name}")]
+    return line
+
+
+def _parts_operand(m: int, n: int) -> str:
+    """The B = 1 layout's gather operand: A's three bf16 parts side by
+    side, each padded to a whole bf16 tile of columns
+    (``common.rank_row_operand``)."""
+    return f"bf16[1,{m},{3 * -(-n // 16) * 16}]"
+
+
 def _rank_space(m, n):
     total = comb(n, m)
     assert total < 2 ** 31
@@ -109,6 +122,9 @@ def test_rank_rows_kernel_paper_width(one_chip, no_persistent_cache):
     _check(compiled, "rank rows (1, 12, 32)")
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert _op_list(compiled).count("radic_batched_partial_pallas") == 1
+    # it gathers from A's bf16 parts, not from f32 A
+    assert _parts_operand(m, n) in _kernel_line(
+        compiled, "radic_batched_partial_pallas")
 
 
 def test_batched_combo_kernel_max_batch(one_chip, no_persistent_cache):
@@ -174,6 +190,8 @@ def test_windowed_kernel_past_int32(one_chip, no_persistent_cache, m, n,
     ops_ = _op_list(compiled)
     assert ops_.count("radic_windowed_partial_pallas") == 1
     assert "radic_batched_partial_pallas" not in ops_
+    assert _parts_operand(m, n) in _kernel_line(
+        compiled, "radic_windowed_partial_pallas")
 
 
 def test_int32_paper_program_has_no_windowed_op(one_chip,
