@@ -56,10 +56,6 @@ FRONT_SPEEDUP_FLOOR = 1.5
 # must drop by this factor vs the Queue/Pipe pickle path
 SHM_OVERHEAD_FLOOR = 2.0
 
-# full-run acceptance floor for the combo-reuse batched kernel: at
-# serving batch depth (B >= 8) it must beat the legacy (B, tiles) grid
-COMBO_KERNEL_FLOOR = 1.3
-
 
 def _wall(fn) -> float:
     t0 = time.perf_counter()
@@ -449,48 +445,6 @@ def measure_shm_overhead(num: int = 24, shape: tuple[int, int] = (2048, 1024),
     }
 
 
-def measure_combo_kernel(batch: int = 8, shape: tuple[int, int] = (4, 12),
-                         *, tile: int = 256, repeat: int = 5) -> dict:
-    """Combo-reuse batched kernel vs the legacy ``(B, tiles)`` grid.
-
-    Both wrappers sit behind the same ops-level guards and agree to f32
-    rounding (``tests/test_kernel_parity.py``); this prices the reuse:
-    unranking/selectors/signs paid once per rank tile instead of B
-    times.  Timed in alternating pairs so machine-load drift lands on
-    both sides equally.  CPU interpret mode only: the legacy grid does
-    not compile for the chip (ROADMAP queue 3).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import ops
-
-    rng = np.random.default_rng(2)
-    m, n = shape
-    As = jnp.asarray(rng.normal(size=(batch, m, n)).astype(np.float32))
-
-    def combo():
-        jax.block_until_ready(ops.radic_det_batched_pallas(As, tile=tile))
-
-    def bygrid():
-        jax.block_until_ready(
-            ops.radic_det_batched_pallas_bygrid(As, tile=tile))
-
-    combo()   # compile
-    bygrid()  # compile
-    t_c = t_g = float("inf")
-    for _ in range(repeat):
-        t_g = min(t_g, _wall(bygrid))
-        t_c = min(t_c, _wall(combo))
-    return {
-        "batch": batch, "shape": shape, "tile": tile,
-        "bygrid_us": t_g * 1e6, "combo_us": t_c * 1e6,
-        "bygrid_us_per_mat": t_g * 1e6 / batch,
-        "combo_us_per_mat": t_c * 1e6 / batch,
-        "speedup": t_g / t_c,
-    }
-
-
 def measure_autoscale(num: int = 256, max_workers: int = 2, *,
                       rate: float = 20000.0, chunk: int = 2048,
                       backend: str = "jnp", max_batch: int = 32,
@@ -841,43 +795,28 @@ def main(argv=None):
                 assert best >= best_queue, (
                     f"front pool {best:.2f}x slower than the single "
                     f"queue {best_queue:.2f}x after {attempts} attempts")
-        # single-host hot-path floors, priced in isolation: the shm ring
-        # vs the Queue/Pipe pickle path on payload-bound traffic, and
-        # the combo-reuse batched kernel vs the legacy (B, tiles) grid
-        # at serving batch depth.  Same pooled-minima attempts logic as
-        # above: load noise is one-sided.
-        shm_best = combo_best = 0.0
+        # single-host hot-path floor, priced in isolation: the shm ring
+        # vs the Queue/Pipe pickle path on payload-bound traffic.  Same
+        # pooled-minima attempts logic as above: load noise is one-sided.
+        shm_best = 0.0
         shm_row: dict = {}
-        combo_row: dict = {}
         for attempt in range(attempts):
             sr = measure_shm_overhead(num=8 if args.smoke else 24,
                                       repeat=1 if args.smoke else 3)
             if sr["speedup"] > shm_best:
                 shm_best, shm_row = sr["speedup"], sr
-            kr = measure_combo_kernel(repeat=2 if args.smoke else 7)
-            if kr["speedup"] > combo_best:
-                combo_best, combo_row = kr["speedup"], kr
-            if (shm_best >= SHM_OVERHEAD_FLOOR
-                    and combo_best >= COMBO_KERNEL_FLOOR):
+            if shm_best >= SHM_OVERHEAD_FLOOR:
                 break
         print("hotpath,metric,baseline_us,fast_us,speedup")
         print(f"hotpath,shm_front_overhead_us_per_mat,"
               f"{shm_row['local_us_per_mat']:.0f},"
               f"{shm_row['shm_us_per_mat']:.0f},{shm_best:.2f}")
-        print(f"hotpath,combo_kernel_us_per_batch,"
-              f"{combo_row['bygrid_us']:.0f},"
-              f"{combo_row['combo_us']:.0f},{combo_best:.2f}")
         if not args.smoke:
             assert shm_best >= SHM_OVERHEAD_FLOOR, (
                 f"shm front overhead cut only {shm_best:.2f}x < "
                 f"{SHM_OVERHEAD_FLOOR}x floor vs the Queue/Pipe pickle "
                 f"path after {attempts} attempts")
-            assert combo_best >= COMBO_KERNEL_FLOOR, (
-                f"combo-reuse kernel {combo_best:.2f}x < "
-                f"{COMBO_KERNEL_FLOOR}x floor vs the legacy grid at "
-                f"B={combo_row.get('batch')} after {attempts} attempts")
         rows.append({"tier": "shm_overhead", **shm_row})
-        rows.append({"tier": "combo_kernel", **combo_row})
         return finish(rows)
 
     if args.arrival == "poisson":

@@ -14,8 +14,8 @@ Three implementations, all proven equal in tests:
   *lane-uniform in the candidate value* ``v``: one ``fori_loop`` of exactly
   ``n`` steps, per-lane state is only (position ``i``, remaining ``q``).
   This is the TPU-native shape of the paper's PRAM per-processor loop.
-* the Pallas kernel (:mod:`repro.kernels.unrank_kernel`) — same walk, run
-  on rank *tiles* inside VMEM.
+* :func:`repro.kernels.common.unrank_lanes` — same walk, run inside the
+  fused Pallas kernels on the lane rows of a rank tile in VMEM.
 
 Conventions: combinations are **1-indexed** ascending tuples, matching the
 paper (``B_0 = [1, 2, .., m]``).

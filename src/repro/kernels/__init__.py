@@ -1,11 +1,11 @@
-"""Pallas TPU kernels for the paper's compute hot-spot (the per-rank
-unrank → gather → determinant pipeline), validated in interpret mode on
-CPU against the numpy oracles in :mod:`repro.kernels.ref`."""
+"""Pallas TPU kernels for the paper's compute hot-spot: the per-rank
+unrank → gather → determinant pipeline, fused into the value, gradient
+and rank-window kernels that serve (:mod:`.radic_fused`,
+:mod:`.radic_windowed`) behind the plan-time guards of :mod:`.ops`.
+They compile for the TPU and run in interpret mode on the CPU, where the
+tests check them against the numpy oracles in :mod:`repro.kernels.ref`."""
 
 from . import ops, ref
-from .minor_det import minor_det_pallas
 from .radic_fused import radic_partial_pallas
-from .unrank_kernel import unrank_pallas
 
-__all__ = ["ops", "ref", "minor_det_pallas", "radic_partial_pallas",
-           "unrank_pallas"]
+__all__ = ["ops", "ref", "radic_partial_pallas"]

@@ -12,8 +12,9 @@ are A's exactly: f32 at ``Precision.HIGHEST`` (:func:`minor_slabs`), or
 at B = 1 one bf16 pass over A's exact three-part split
 (:func:`rank_row_slabs`, :func:`split_bf16x3`).  Both lose only what the
 TPU flushes: an entry under about 2⁻¹⁰⁰ whose low part is subnormal.
-The same functions run as ordinary jnp inside jit, so the fused kernels,
-their CPU interpret mode and the (T, …)-layout adapters share the math.
+The same functions run as ordinary jnp inside jit, so the fused kernels
+and their CPU interpret mode share the math, and the tests call them
+directly against the oracles of :mod:`repro.kernels.ref`.
 """
 
 from __future__ import annotations
@@ -23,8 +24,7 @@ import jax.numpy as jnp
 
 __all__ = ["det_slabs", "unrank_lanes", "selector_slabs", "minor_slabs",
            "rank_row_slabs", "sign_lanes", "unrank_window_lanes",
-           "batched_det_ge", "unrank_tile", "onehot_gather_minors",
-           "radic_signs", "split_bf16x3", "rank_row_operand"]
+           "split_bf16x3", "rank_row_operand"]
 
 
 def det_slabs(M: list[list[jax.Array]]) -> jax.Array:
@@ -238,42 +238,3 @@ def rank_row_operand(A: jax.Array) -> jax.Array:
     np_ = -(-n // 16) * 16
     parts = jnp.pad(split_bf16x3(A), ((0, 0), (0, 0), (0, np_ - n)))
     return parts.transpose(1, 0, 2).reshape(m, 3 * np_)
-
-
-# ------------------------------------------------- (T, …)-layout adapters
-# The standalone minor_det/unrank kernels, the legacy by-grid batched
-# kernel and the stage benchmark keep the rank-major ``(T, m)`` layout;
-# they call the lane-major math above through these adapters.
-
-def batched_det_ge(M: jax.Array) -> jax.Array:
-    """``M (T, m, m) -> (T,)`` through :func:`det_slabs`."""
-    T, m, m2 = M.shape
-    assert m == m2, M.shape
-    if m == 0:
-        return jnp.ones((T,), M.dtype)
-    slabs = [[M[:, r, c][None, :] for c in range(m)] for r in range(m)]
-    return det_slabs(slabs)[0]
-
-
-def unrank_tile(qs: jax.Array, n: int, m: int, table: jax.Array
-                ) -> jax.Array:
-    """``qs (T,) -> (T, m)`` 1-indexed, through :func:`unrank_lanes`;
-    ``table`` is the ``(n + 1, m + 1)`` Pascal table."""
-    rows = unrank_lanes(qs[None, :], n, m, table.reshape(-1))
-    return jnp.concatenate(rows, axis=0).T
-
-
-def onehot_gather_minors(A: jax.Array, combos: jax.Array) -> jax.Array:
-    """Column gather as a matmul: ``A (m,n), combos (T,m) -> (T,m,m)``,
-    the transposed minor (determinant-invariant)."""
-    T, m = combos.shape
-    jidx = jax.lax.broadcasted_iota(jnp.int32, (T, m, A.shape[1]), 2)
-    oh = (combos[:, :, None] - 1 == jidx).astype(A.dtype)
-    return jnp.einsum("tkn,an->tka", oh, A, preferred_element_type=A.dtype)
-
-
-def radic_signs(combos: jax.Array, m: int, dtype=jnp.float32) -> jax.Array:
-    """(−1)^(r+s) per row of ``combos (T, m)``."""
-    r = m * (m + 1) // 2
-    parity = (jnp.sum(combos, axis=1) + r) & 1
-    return (1 - 2 * parity).astype(dtype)

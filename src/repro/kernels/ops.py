@@ -12,35 +12,40 @@ from repro.core.engine import (plan_rank_windows, validate_rank_space,
                                window_count)
 from repro.core.pascal import INT32_MAX, binom_table
 
-from .minor_det import minor_det_pallas
 from .radic_fused import (radic_batched_grad_partial_pallas,
                           radic_batched_partial_pallas,
-                          radic_batched_partial_pallas_bygrid,
                           radic_partial_pallas)
 from .radic_windowed import radic_windowed_partial_pallas
-from .unrank_kernel import unrank_pallas
 
-__all__ = ["minor_det", "unrank", "radic_partial_pallas",
-           "radic_det_pallas", "capped_table",
+__all__ = ["radic_partial_pallas", "radic_det_pallas", "capped_table",
            "radic_windowed_partial_pallas", "radic_batched_partial_pallas",
-           "radic_det_batched_pallas", "radic_det_batched_pallas_bygrid",
-           "radic_det_grad_pallas", "radic_det_batched_grad_pallas"]
+           "radic_det_batched_pallas", "radic_det_grad_pallas",
+           "radic_det_batched_grad_pallas"]
 
 
-def minor_det(mats: jax.Array, *, tile: int = 128,
-              interpret: bool | None = None) -> jax.Array:
-    """Batched determinant of ``(B, m, m)`` minors."""
-    return minor_det_pallas(mats, tile=tile, interpret=interpret)
+def _grid_extent(ranks: int, tile: int) -> int:
+    """The static grid extent of a kernel over ``ranks`` ranks: at least
+    one tile, tile-aligned."""
+    return max(tile, ((ranks + tile - 1) // tile) * tile)
 
 
-def unrank(qs: jax.Array, n: int, m: int, *, tile: int = 256,
-           interpret: bool | None = None) -> jax.Array:
-    """Batched rank → 1-indexed combination."""
-    # same plan-time guard as the det wrappers: the kernel's int32 rank
-    # arithmetic is a hard limit, and an unguarded table would wrap
-    validate_rank_space(m, n, backend="pallas")
+def _rank_range(m: int, n: int, q_start: int, count: int | None,
+                tile: int, *, windowed: bool = False):
+    """The plan-time guard every entry point shares: C(n, m) held to the
+    kernels' int32 ranks (past them only ``windowed``, as rank windows),
+    ``count`` defaulting to the rest of the rank space, and the range
+    held inside it.  Returns ``(count, table, padded)``, the int32 Pascal
+    table and the grid extent of one kernel over the range; ``table`` and
+    ``padded`` are None where the range runs as rank windows."""
+    total = validate_rank_space(m, n, backend="pallas", windowed=windowed)
+    if count is None:
+        count = total - q_start
+    if q_start + count > total:
+        raise ValueError("rank range exceeds C(n, m)")
+    if windowed and window_count(total) is not None:
+        return count, None, None
     table = jnp.asarray(binom_table(n, m, dtype=np.int32))
-    return unrank_pallas(qs, n, m, table, tile=tile, interpret=interpret)
+    return count, table, _grid_extent(count, tile)
 
 
 def capped_table(n: int, m: int) -> np.ndarray:
@@ -65,24 +70,17 @@ def radic_det_pallas(A: jax.Array, q_start: int = 0, count: int | None = None,
     m, n = A.shape
     if m > n:
         return jnp.zeros((), A.dtype)
-    # shared plan validation: past int32 only as rank windows
-    total = validate_rank_space(m, n, backend="pallas", windowed=True)
-    if count is None:
-        count = total - q_start
-    if q_start + count > total:
-        raise ValueError("rank range exceeds C(n, m)")
-    if window_count(total) is not None:
+    count, table, padded = _rank_range(m, n, q_start, count, tile,
+                                       windowed=True)
+    if table is None:
         if windows is None:
             windows = plan_rank_windows(m, n, q_start, count)
-        longest = max(windows.lengths)
-        padded = max(tile, ((longest + tile - 1) // tile) * tile)
         parts = radic_windowed_partial_pallas(
             A, jnp.asarray(capped_table(n, m)),
-            np.asarray(windows.lengths, np.int32), windows.slacks, padded,
-            tile=tile, interpret=interpret)
+            np.asarray(windows.lengths, np.int32), windows.slacks,
+            _grid_extent(max(windows.lengths), tile), tile=tile,
+            interpret=interpret)
         return parts.sum().astype(A.dtype)
-    table = jnp.asarray(binom_table(n, m, dtype=np.int32))
-    padded = max(tile, ((count + tile - 1) // tile) * tile)
     return radic_partial_pallas(A, table, q_start, count, padded,
                                 tile=tile, interpret=interpret)
 
@@ -97,14 +95,7 @@ def radic_det_batched_pallas(As: jax.Array, q_start: int = 0,
     B, m, n = As.shape
     if m > n:
         return jnp.zeros((B,), As.dtype)
-    # shared plan validation: int32 rank width is a hard kernel limit
-    total = validate_rank_space(m, n, backend="pallas")
-    if count is None:
-        count = total - q_start
-    if q_start + count > total:
-        raise ValueError("rank range exceeds C(n, m)")
-    table = jnp.asarray(binom_table(n, m, dtype=np.int32))
-    padded = max(tile, ((count + tile - 1) // tile) * tile)
+    count, table, padded = _rank_range(m, n, q_start, count, tile)
     return radic_batched_partial_pallas(As, table, q_start, count, padded,
                                         tile=tile, interpret=interpret)
 
@@ -120,14 +111,7 @@ def radic_det_batched_grad_pallas(As: jax.Array, cts: jax.Array,
     B, m, n = As.shape
     if m > n:
         return jnp.zeros_like(As)
-    # shared plan validation: int32 rank width is a hard kernel limit
-    total = validate_rank_space(m, n, backend="pallas")
-    if count is None:
-        count = total - q_start
-    if q_start + count > total:
-        raise ValueError("rank range exceeds C(n, m)")
-    table = jnp.asarray(binom_table(n, m, dtype=np.int32))
-    padded = max(tile, ((count + tile - 1) // tile) * tile)
+    count, table, padded = _rank_range(m, n, q_start, count, tile)
     cts = jnp.reshape(jnp.asarray(cts, As.dtype), (B,))
     return radic_batched_grad_partial_pallas(
         As, cts, table, q_start, count, padded, tile=tile,
@@ -146,26 +130,3 @@ def radic_det_grad_pallas(A: jax.Array, ct, q_start: int = 0,
     cts = jnp.reshape(jnp.asarray(ct, A.dtype), (1,))
     return radic_det_batched_grad_pallas(
         A[None], cts, q_start, count, tile=tile, interpret=interpret)[0]
-
-
-def radic_det_batched_pallas_bygrid(As: jax.Array, q_start: int = 0,
-                                    count: int | None = None, *,
-                                    tile: int = 256,
-                                    interpret: bool | None = None
-                                    ) -> jax.Array:
-    """Legacy ``(B, num_tiles)``-grid batched dispatch, kept behind the
-    same guards as the default path so the parity tests and benchmarks
-    can price the combo-reuse kernel against it."""
-    B, m, n = As.shape
-    if m > n:
-        return jnp.zeros((B,), As.dtype)
-    # shared plan validation: int32 rank width is a hard kernel limit
-    total = validate_rank_space(m, n, backend="pallas")
-    if count is None:
-        count = total - q_start
-    if q_start + count > total:
-        raise ValueError("rank range exceeds C(n, m)")
-    table = jnp.asarray(binom_table(n, m, dtype=np.int32))
-    padded = max(tile, ((count + tile - 1) // tile) * tile)
-    return radic_batched_partial_pallas_bygrid(
-        As, table, q_start, count, padded, tile=tile, interpret=interpret)

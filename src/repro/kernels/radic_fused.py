@@ -34,6 +34,7 @@ masked per-lane terms into a ``(G, 8, T)`` block that the wrapper sums
 over lanes (and over sublanes at B = 1), the backward adds
 ``(G, 8·m, n)`` gradient partials.  HBM input per tile is zero beyond
 the resident A and table — ranks are generated from the grid index.
+The rank-window kernel (``radic_windowed.py``) runs the B = 1 layout.
 """
 
 from __future__ import annotations
@@ -45,15 +46,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import (batched_det_ge, det_slabs, minor_slabs, radic_signs,
-                     onehot_gather_minors, rank_row_operand, rank_row_slabs,
-                     selector_slabs, sign_lanes, unrank_lanes, unrank_tile)
+from .common import (det_slabs, minor_slabs, rank_row_operand,
+                     rank_row_slabs, selector_slabs, sign_lanes,
+                     unrank_lanes)
 
 __all__ = ["radic_partial_pallas", "radic_batched_combo_kernel",
            "radic_batched_partial_pallas",
            "radic_batched_grad_combo_kernel",
-           "radic_batched_grad_partial_pallas", "radic_batched_kernel",
-           "radic_batched_partial_pallas_bygrid", "rank_rows",
+           "radic_batched_grad_partial_pallas", "rank_rows",
            "resolve_interpret"]
 
 SUBLANES = 8  # an f32 sublane tile: matrices per group, rank rows at B = 1
@@ -268,64 +268,3 @@ def radic_batched_grad_partial_pallas(As: jax.Array, cts: jax.Array,
         padded_count, tile, table, q_start, count, [a, c],
         (G, SUBLANES * m, n), interpret, "radic_batched_grad_partial_pallas")
     return _ungrouped(out, B, m, n).astype(As.dtype)
-
-
-# ------------------------------------------------------ legacy by-grid path
-def radic_batched_kernel(n: int, m: int, tile: int,
-                         qinfo_ref, a_ref, table_ref, out_ref):
-    """Legacy batched variant: grid (B, num_tiles); block b sees matrix b.
-
-    Recomputes the rank tile per (b, tile) cell in the rank-major
-    ``(T, m)`` layout.  Interpret mode only — Mosaic refuses its 1-D
-    ``qinfo`` block and ``(1, 1)`` output block; kept as the CPU
-    reference the combo kernel is checked and priced against.
-    """
-    pid = pl.program_id(1)
-    q_start = qinfo_ref[0]
-    count = qinfo_ref[1]
-    offs = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)[:, 0]
-    offs = pid * tile + offs
-    valid = offs < count
-    qs = q_start + jnp.where(valid, offs, 0)
-    # in-kernel (T, m) unranking; guarded at the ops.py entry points
-    combos = unrank_tile(qs, n, m, table_ref[...])  # reprolint: disable=overflow-guard
-    A = a_ref[0].astype(jnp.float32)                        # block (1, m, n)
-    minors = onehot_gather_minors(A, combos)                # (T, m, m)
-    dets = batched_det_ge(minors)                           # (T,)
-    signs = radic_signs(combos, m, dets.dtype)
-    part = jnp.sum(jnp.where(valid, signs * dets, 0.0))
-
-    @pl.when(pid == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[0, 0] += part
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("padded_count", "tile", "interpret"))
-def radic_batched_partial_pallas_bygrid(As: jax.Array, table: jax.Array,
-                                        q_start: jax.Array | int,
-                                        count: jax.Array | int,
-                                        padded_count: int, *, tile: int = 256,
-                                        interpret: bool | None = None
-                                        ) -> jax.Array:
-    """Legacy (B, num_tiles)-grid batched partial — reference only; the
-    serving path dispatches :func:`radic_batched_partial_pallas`."""
-    B, m, n = As.shape
-    grid = (B, max(1, -(-padded_count // tile)))
-    qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
-                       jnp.asarray(count, jnp.int32)])
-    out = pl.pallas_call(
-        functools.partial(radic_batched_kernel, n, m, tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((2,), lambda b, i: (0,)),
-            pl.BlockSpec((1, m, n), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((n + 1, m + 1), lambda b, i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        interpret=resolve_interpret(interpret),
-    )(qinfo, As, table.astype(jnp.int32))
-    return out[:, 0].astype(As.dtype)

@@ -1,7 +1,11 @@
-"""Pure numpy/itertools oracles for every Pallas kernel in this package.
+"""Pure numpy/itertools oracles for the Pallas kernels in this package
+and the in-kernel routines they share.
 
-Each kernel's semantics are *defined* by the function here with the same
-name; tests sweep shapes/dtypes and assert allclose against these.
+Each function here *defines* the semantics of its kernel-side
+counterpart: :func:`unrank_ref` of ``common.unrank_lanes``,
+:func:`minor_det_ref` of ``common.det_slabs``, :func:`radic_partial_ref`
+of the fused kernels' rank-range partials; tests sweep shapes and assert
+allclose against these.
 """
 
 from __future__ import annotations

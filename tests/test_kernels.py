@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps vs the numpy oracles (interpret mode)."""
+"""The kernels and their in-kernel routines, swept over shapes against
+the numpy oracles (kernels in interpret mode)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -8,12 +9,26 @@ from repro.core.pascal import comb
 from repro.kernels import ops, ref
 
 
+def _det_slabs_of(mats: np.ndarray) -> np.ndarray:
+    """``common.det_slabs`` over a ``(B, m, m)`` stack: B padded with
+    identity matrices to whole ``(8, T)`` slabs, matrix ``s·T + t`` at
+    (sublane s, lane t), as the B = 1 kernel lays out its rank rows."""
+    from repro.kernels.common import det_slabs
+    B, m, _ = mats.shape
+    T = -(-B // 8)
+    pad = np.broadcast_to(np.eye(m, dtype=mats.dtype), (8 * T - B, m, m))
+    full = np.concatenate([mats, pad]).reshape(8, T, m, m)
+    slabs = [[jnp.asarray(full[:, :, r, c]) for c in range(m)]
+             for r in range(m)]
+    return np.asarray(det_slabs(slabs)).reshape(-1)[:B]
+
+
 @pytest.mark.parametrize("B,m", [(1, 1), (3, 2), (7, 3), (130, 4),
                                  (64, 5), (5, 8), (256, 2)])
 @pytest.mark.parametrize("dtype", [np.float32])
 def test_minor_det_sweep(B, m, dtype, rng):
     mats = rng.normal(size=(B, m, m)).astype(dtype)
-    got = np.asarray(ops.minor_det(jnp.asarray(mats), tile=32))
+    got = _det_slabs_of(mats)
     want = ref.minor_det_ref(mats)
     np.testing.assert_allclose(got, want, rtol=5e-4, atol=1e-4)
 
@@ -25,7 +40,7 @@ def test_minor_det_singular_and_permuted(rng):
     sing[2] = sing[0]  # rank-deficient
     perm = a[[1, 0, 2, 3]]  # one swap -> -det
     mats = np.stack([a, sing, perm, np.eye(m, dtype=np.float32)])
-    got = np.asarray(ops.minor_det(jnp.asarray(mats), tile=8))
+    got = _det_slabs_of(mats)
     np.testing.assert_allclose(
         got, [np.linalg.det(a), 0.0, -np.linalg.det(a), 1.0],
         rtol=1e-4, atol=1e-5)
@@ -33,13 +48,21 @@ def test_minor_det_singular_and_permuted(rng):
 
 @pytest.mark.parametrize("n,m", [(8, 5), (6, 3), (10, 2), (12, 6),
                                  (5, 5), (9, 1), (16, 3)])
-@pytest.mark.parametrize("tile", [8, 64])
-def test_unrank_sweep(n, m, tile):
+@pytest.mark.parametrize("rows", [1, 8])
+def test_unrank_sweep(n, m, rows):
+    """``common.unrank_lanes`` on the kernels' two row layouts: one
+    ``(1, T)`` row shared by a batch (B ≥ 2) and the ``(8, T)`` rank rows
+    of one matrix (B = 1), lane (s, t) holding rank ``s·T + t``."""
+    from repro.core.pascal import binom_table
+    from repro.kernels.common import unrank_lanes
     total = comb(n, m)
-    qs = np.arange(total, dtype=np.int32)
-    got = np.asarray(ops.unrank(jnp.asarray(qs), n, m, tile=tile))
-    want = ref.unrank_ref(qs, n, m)
-    assert (got == want).all()
+    T = -(-total // (rows * 128)) * 128
+    qs = np.minimum(np.arange(rows * T), total - 1).astype(np.int32)
+    table = jnp.asarray(binom_table(n, m, dtype=np.int32).reshape(-1))
+    combo = unrank_lanes(jnp.asarray(qs.reshape(rows, T)), n, m, table)
+    got = np.stack([np.asarray(c).reshape(-1) for c in combo], axis=1)
+    want = ref.unrank_ref(qs[:total], n, m)
+    assert (got[:total] == want).all()
 
 
 @pytest.mark.parametrize("m,n", [(2, 6), (3, 7), (4, 8), (5, 8),

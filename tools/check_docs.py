@@ -12,7 +12,7 @@ job, next to reprolint):
    the flag appears in a code span that also mentions ``det_serve`` —
    an inline backtick span, or one logical shell command inside a
    fenced block (backslash continuations joined).  Flags of *other*
-   tools (``benchmarks/run.py --save``, reprolint's ``--json``,
+   tools (``bench/run.py --workload``, reprolint's ``--json``,
    ``perf_serve --smoke``) live in spans without ``det_serve`` and are
    deliberately out of scope: this is a drift gate, not a universal
    flag registry.

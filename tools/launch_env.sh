@@ -2,7 +2,7 @@
 # Tuned process environment for the single-host serving stack.
 #
 #   tools/launch_env.sh python -m repro.launch.det_serve --workers 2 --shm
-#   DET_HOST_DEVICES=4 tools/launch_env.sh python -m benchmarks.run
+#   DET_HOST_DEVICES=4 tools/launch_env.sh python -m benchmarks.perf_serve --workers 2
 #
 # Two knobs, both no-ops when unavailable so the wrapper is always safe:
 #

@@ -629,9 +629,8 @@ class TracerHygienePass(LintPass):
 # --------------------------------------------------------------------------
 
 class OverflowGuardPass(LintPass):
-    """``binom_table`` / ``unrank_tile`` / ``unrank_lanes`` /
-    ``unrank_window_lanes`` call sites must be dominated by a plan-time
-    rank-space guard.
+    """``binom_table`` / ``unrank_lanes`` / ``unrank_window_lanes`` call
+    sites must be dominated by a plan-time rank-space guard.
 
     The Radic walk enumerates C(n, m) minors; the int32 rank arithmetic
     in the kernels silently wraps past 2**31-1, so every table build or
@@ -646,8 +645,7 @@ class OverflowGuardPass(LintPass):
     id = "overflow-guard"
     description = "unguarded binom_table / unrank_* call sites"
 
-    TARGETS = {"binom_table", "unrank_tile", "unrank_lanes",
-               "unrank_window_lanes"}
+    TARGETS = {"binom_table", "unrank_lanes", "unrank_window_lanes"}
     GUARDS = {"validate_rank_space", "plan_statics"}
     EXEMPT_SUFFIXES = ("repro/core/engine.py", "repro/core/pascal.py",
                        "repro/kernels/common.py")
