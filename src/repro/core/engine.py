@@ -310,6 +310,15 @@ def kernel_gather(key: PlanKey) -> str | None:
     return "bf16x3" if rows > 1 else "f32_highest"
 
 
+def kernel_walk(key: PlanKey) -> str | None:
+    """How the plan's value kernel finds each lane's combination, as the
+    kernel module answers for its :func:`kernel_rank_rows`
+    (``kernels.radic_fused.rank_walk``): ``"successor"``, ``"unrank"``
+    or ``None``."""
+    from repro.kernels.radic_fused import rank_walk  # kernels import core
+    return rank_walk(kernel_rank_rows(key))
+
+
 def kernel_windows(key: PlanKey) -> int | None:
     """Rank windows of the plan's value program: the scalar single-device
     Pallas plans past :data:`WINDOW_RANKS` run in :func:`window_count`
@@ -442,6 +451,12 @@ class DetPlan:
         ``"bf16x3"``, ``"f32_highest"`` or ``None`` (:func:`kernel_gather`)."""
         return kernel_gather(self.key)
 
+    @property
+    def walk(self) -> str | None:
+        """The engagement record of the value kernel's rank walk:
+        ``"successor"``, ``"unrank"`` or ``None`` (:func:`kernel_walk`)."""
+        return kernel_walk(self.key)
+
     def __call__(self, A: jax.Array) -> jax.Array:
         return self.executable(A)
 
@@ -522,8 +537,9 @@ class DetEngine:
         A miss restores or builds inside a ``det.plan.build`` profiler
         span (the mesh backend's grain planning and a windowed plan's
         ``det.plan.windows`` fall inside it), whose metadata ``rank_rows``,
-        ``gather`` and ``windows`` are the plan's :attr:`DetPlan.rank_rows`,
-        :attr:`DetPlan.gather` and :attr:`DetPlan.windows`."""
+        ``gather``, ``walk`` and ``windows`` are the plan's
+        :attr:`DetPlan.rank_rows`, :attr:`DetPlan.gather`,
+        :attr:`DetPlan.walk` and :attr:`DetPlan.windows`."""
         if backend not in ("jnp", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
         if kahan and batched:
@@ -548,6 +564,7 @@ class DetEngine:
         with jax.profiler.TraceAnnotation("det.plan.build",
                                           rank_rows=kernel_rank_rows(key),
                                           gather=kernel_gather(key),
+                                          walk=kernel_walk(key),
                                           windows=kernel_windows(key)):
             built = None
             consulted = self.store is not None and self._persistable(key)

@@ -15,7 +15,10 @@ Three implementations, all proven equal in tests:
   ``n`` steps, per-lane state is only (position ``i``, remaining ``q``).
   This is the TPU-native shape of the paper's PRAM per-processor loop.
 * :func:`repro.kernels.common.unrank_lanes` — same walk, run inside the
-  fused Pallas kernels on the lane rows of a rank tile in VMEM.
+  fused Pallas kernels on the lane rows of a rank tile in VMEM; at B = 1
+  only on each lane's run start, which
+  :func:`repro.kernels.common.successor_lanes` (the lane form of
+  :func:`successor_jnp`) then walks.
 
 Conventions: combinations are **1-indexed** ascending tuples, matching the
 paper (``B_0 = [1, 2, .., m]``).

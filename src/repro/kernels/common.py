@@ -22,9 +22,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["det_slabs", "unrank_lanes", "selector_slabs", "minor_slabs",
-           "rank_row_slabs", "sign_lanes", "unrank_window_lanes",
-           "split_bf16x3", "rank_row_operand"]
+__all__ = ["det_slabs", "unrank_lanes", "successor_lanes",
+           "selector_slabs", "minor_slabs", "rank_row_slabs", "sign_lanes",
+           "unrank_window_lanes", "split_bf16x3", "rank_row_operand"]
 
 
 def det_slabs(M: list[list[jax.Array]]) -> jax.Array:
@@ -97,6 +97,28 @@ def unrank_lanes(qs: jax.Array, n: int, m: int, table) -> list[jax.Array]:
     _, _, combo = jax.lax.fori_loop(1, n + 1, step,
                                     (zero, qs, (zero,) * m))
     return list(combo)
+
+
+def successor_lanes(combos: list[jax.Array], n: int) -> list[jax.Array]:
+    """Each lane's next combination in dictionary order: m ``(S, T)``
+    rows as :func:`unrank_lanes` lays them out -> the same of the
+    successors.  The lane form of ``core.unrank.successor_jnp``, the
+    paper's per-grain enumeration step: place i is below its cap when
+    ``c_i < n − m + i + 1``; the rightmost such place is bumped and the
+    places after it reset to a consecutive run, ``c_j = c_i* + 1 + j −
+    i*``.  With ``d = c_i* − i*`` that is ``d + 1 + j`` for ``j ≥ i*``.
+    The last member has no such place: ``i* = −1`` and ``d = n − m``
+    give ``n − m + 1 + j``, itself, so it maps to itself; callers mask.
+    """
+    m = len(combos)
+    istar = jnp.full_like(combos[0], -1)
+    d = jnp.full_like(combos[0], n - m)
+    for i, c in enumerate(combos):
+        can = c < n - m + i + 1
+        istar = jnp.where(can, i, istar)
+        d = jnp.where(can, c - i, d)
+    return [jnp.where(istar > j, c, d + (1 + j))
+            for j, c in enumerate(combos)]
 
 
 def selector_slabs(combos: list[jax.Array], n: int) -> list[jax.Array]:

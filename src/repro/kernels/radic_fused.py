@@ -3,13 +3,15 @@
 One kernel = the paper's whole per-processor pipeline, fused so minors
 never touch HBM:
 
-    rank tile ──unrank (VPU, n static steps)────► combos, m (R, T) rows
-              ──one-hot matmuls (MXU)───────────► minor slabs (8, T)
-              ──pivoted GE (VPU, unrolled in m)─► dets (8, T)
-              ──sign · mask─────────────────────► per-lane partials
+    B ≥ 2: rank tile ──unrank (VPU, n static steps)──► combos, m (1, T)
+    B = 1: run start ──unrank once, at step 0────────► combos, m (8, T)
+                     ──successor per step (VPU, ~7m)─► next combos
+           combos    ──one-hot matmuls (MXU)─────────► minor slabs (8, T)
+                     ──pivoted GE (VPU, unrolled in m)► dets (8, T)
+                     ──sign · mask───────────────────► per-lane partials
 
 Two layouts of the 8 f32 sublanes, chosen from the static batch size
-(:func:`rank_rows`):
+(:func:`rank_rows`), and with them the rank walk (:func:`rank_walk`):
 
 * B ≥ 2, matrices on sublanes (R = 1): the T ranks of a tile sit on the
   128-wide lane axis and the batch on the sublanes, 8 matrices per
@@ -19,22 +21,30 @@ Two layouts of the 8 f32 sublanes, chosen from the static batch size
   kernel group-major, ``(G, 8·m, n)`` with row ``a·8 + s`` of group g
   holding row a of matrix ``8g + s``; the wrappers pad B up to a
   multiple of 8 with zero matrices, whose results are sliced off.
-* B = 1, rank rows on sublanes (R = 8): sublane s of a grid step holds
-  ranks ``step·8T + s·T + lane`` of the one matrix, so a step covers 8T
-  ranks and no sublane computes a zero matrix.  The matrix enters as its
-  exact bf16 parts side by side, ``(1, m, 3·np)``, and each rank row
-  takes one bf16 one-hot matmul whose f32 outputs are A's entries bit
-  for bit (``common.rank_row_slabs``); the elimination reads the slabs.
-  B ≥ 2 and the gradient gather f32 at ``Precision.HIGHEST``.
+* B = 1, rank rows on sublanes (R = 8): each of the 8T lanes ``l = s·T
+  + t`` owns one contiguous run of ``K = ceil(count / 8T)`` ranks of the
+  one matrix (:func:`lane_runs`) and handles the run's ``pid``-th rank
+  at grid step ``pid``, so a step covers 8T ranks and no sublane
+  computes a zero matrix.  As in the paper's per-processor algorithm, a
+  lane unranks its run's start once, at step 0, into VMEM scratch that
+  persists across the sequential grid, and every step moves it on by
+  the dictionary successor (``common.successor_lanes``).  The matrix
+  enters as its exact bf16 parts side by side, ``(1, m, 3·np)``, and
+  each rank row takes one bf16 one-hot matmul whose f32 outputs are A's
+  entries bit for bit (``common.rank_row_slabs``); the elimination reads
+  the slabs.  B ≥ 2 and the gradient gather f32 at
+  ``Precision.HIGHEST``.
 
-``q_start``, the whole steps and the tail of ``count``, and the
+``q_start``, then the whole steps and the tail of ``count`` (B ≥ 2 and
+the gradient) or ``count`` and the run length K (B = 1), and the
 flattened Pascal table are scalar-prefetch operands (SMEM).  The
 outputs stay resident across the sequential grid: the forward adds its
 masked per-lane terms into a ``(G, 8, T)`` block that the wrapper sums
 over lanes (and over sublanes at B = 1), the backward adds
 ``(G, 8·m, n)`` gradient partials.  HBM input per tile is zero beyond
 the resident A and table — ranks are generated from the grid index.
-The rank-window kernel (``radic_windowed.py``) runs the B = 1 layout.
+The rank-window kernel (``radic_windowed.py``) runs the B = 1 layout
+and its walk.
 """
 
 from __future__ import annotations
@@ -48,12 +58,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .common import (det_slabs, minor_slabs, rank_row_operand,
                      rank_row_slabs, selector_slabs, sign_lanes,
-                     unrank_lanes)
+                     successor_lanes, unrank_lanes)
 
 __all__ = ["radic_partial_pallas", "radic_batched_combo_kernel",
            "radic_batched_partial_pallas",
            "radic_batched_grad_combo_kernel",
-           "radic_batched_grad_partial_pallas", "rank_rows",
+           "radic_batched_grad_partial_pallas", "rank_rows", "rank_walk",
            "resolve_interpret"]
 
 SUBLANES = 8  # an f32 sublane tile: matrices per group, rank rows at B = 1
@@ -74,6 +84,17 @@ def rank_rows(batch: int) -> int:
     return SUBLANES if batch == 1 else 1
 
 
+def rank_walk(rows: int | None) -> str | None:
+    """How the value kernel of :func:`rank_rows` ``rows`` finds each
+    lane's combination, its engagement record: ``"successor"`` where a
+    lane walks its own run of ranks (8 rank rows, B = 1 and the windowed
+    kernel: :func:`lane_runs`), ``"unrank"`` where every grid step
+    unranks its tile (1 row, B ≥ 2), ``None`` without a kernel."""
+    if rows is None:
+        return None
+    return "successor" if rows > 1 else "unrank"
+
+
 def _steps(count, step: int) -> list[jax.Array]:
     """``count`` ranks as whole grid steps of ``step`` ranks and a tail:
     the scalars :func:`lane_ranks` reads, so no rank offset in the kernel
@@ -82,33 +103,68 @@ def _steps(count, step: int) -> list[jax.Array]:
     return [count // step, count % step]
 
 
-def lane_ranks(pid, q_start, full, tail, rows: int, tile: int):
-    """Validity mask and ranks ``(rows, tile)`` of grid step ``pid``, for
-    ``count = full·step + tail`` ranks from ``q_start``, ``step =
-    rows·tile``: lane ``(s, t)`` is rank ``q_start + pid·step + s·tile +
-    t`` where that lies inside the range.
+def lane_ranks(pid, q_start, full, tail, tile: int):
+    """Validity mask and ranks ``(1, tile)`` of grid step ``pid``, for
+    ``count = full·tile + tail`` ranks from ``q_start``: lane t is rank
+    ``q_start + pid·tile + t`` where that lies inside the range.
 
     Every offset is bounded by ``count``: the base is taken at
     ``min(pid, full)`` steps and a masked lane adds nothing, so a grid
     that runs past the range — the padded grid extent, or a shard's
     shorter grain — forms no offset that could wrap int32.  A masked lane
     unranks a rank of ``[q_start, q_start + count]`` and is dropped."""
-    step = rows * tile
-    local = (jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 0) * tile
-             + jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1))
-    limit = jnp.where(pid < full, step, jnp.where(pid == full, tail, 0))
+    local = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+    limit = jnp.where(pid < full, tile, jnp.where(pid == full, tail, 0))
     valid = local < limit
-    base = jnp.minimum(pid, full) * step
+    base = jnp.minimum(pid, full) * tile
     return valid, q_start + base + jnp.where(valid, local, 0)
 
 
-def _tile_setup(n: int, m: int, tile: int, rows: int, qinfo_ref,
-                table_ref):
-    """The rank tile of this grid step: lane validity mask, combinations
-    and signs, ``(rows, tile)`` — shared by every matrix of the batch
-    when ``rows`` is 1."""
+def _runs(count, lanes: int) -> list[jax.Array]:
+    """``count`` ranks cut into one run per lane of ``lanes`` lanes: the
+    count and the run length ``K = ceil(count / lanes)``, the scalars
+    :func:`lane_runs` reads, formed without passing ``count``."""
+    count = jnp.asarray(count, jnp.int32)
+    return [count, count // lanes + (count % lanes > 0).astype(jnp.int32)]
+
+
+def lane_runs(pid, count, run, tile: int):
+    """The run layout of 8 rank rows: lane ``l = s·tile + t`` of an
+    ``(8, tile)`` slab owns the ranks ``[l·K, l·K + K)`` of a range of
+    ``count`` ranks, ``K = run``, clipped to the range, and handles the
+    ``pid``-th of them at grid step ``pid``.  Returns the validity mask of
+    that step and each lane's start offset, which it unranks once.
+
+    No offset wraps int32: ``l·K < 8·tile·K``, which for a power-of-two
+    tile is at most 2³¹ for any ``count`` under it; validity compares
+    ``pid`` with ``count − l·K`` instead of forming ``l·K + pid``; and a
+    lane whose run starts at or past the range's end starts at its last
+    rank, ``count − 1``, and is masked at every step."""
+    lane = (jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, tile), 0) * tile
+            + jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, tile), 1))
+    first = lane * run
+    valid = (pid < run) & (pid < count - first)
+    return valid, jnp.minimum(first, jnp.maximum(count - 1, 0))
+
+
+def walk_run_step(n: int, m: int, parts, combo_ref, valid, out_ref):
+    """One grid step of the run layout: the lanes' combinations from
+    ``combo_ref`` (m ``(8, T)`` int32 rows, VMEM scratch that persists
+    across the sequential grid), their gather from A's bf16 ``parts``,
+    elimination and masked signed terms into ``out_ref[0]``, then each
+    lane's successor back into ``combo_ref`` for the next step."""
+    combos = [combo_ref[k] for k in range(m)]
+    dets = det_slabs(rank_row_slabs(parts, combos))
+    out_ref[0] += jnp.where(valid, sign_lanes(combos, m) * dets, 0.0)
+    for k, c in enumerate(successor_lanes(combos, n)):
+        combo_ref[k] = c
+
+
+def _tile_setup(n: int, m: int, tile: int, qinfo_ref, table_ref):
+    """The rank tile of this grid step, shared by every matrix of the
+    batch: lane validity mask, combinations and signs, ``(1, tile)``."""
     valid, qs = lane_ranks(pl.program_id(0), qinfo_ref[0], qinfo_ref[1],
-                           qinfo_ref[2], rows, tile)
+                           qinfo_ref[2], tile)
     # in-kernel unranking; guarded at the ops.py entry points
     combos = unrank_lanes(qs, n, m, table_ref)  # reprolint: disable=overflow-guard
     return valid, combos, sign_lanes(combos, m)
@@ -116,20 +172,31 @@ def _tile_setup(n: int, m: int, tile: int, rows: int, qinfo_ref,
 
 def radic_batched_combo_kernel(n: int, m: int, tile: int, rows: int,
                                groups: int, qinfo_ref, table_ref, a_ref,
-                               out_ref):
+                               out_ref, *scratch):
     """Forward: per-lane Σ sign·det of this tile for every matrix, or at
-    ``rows`` = 8 for the rank rows of the one matrix, from its bf16 parts."""
-    valid, combos, signs = _tile_setup(n, m, tile, rows, qinfo_ref,
-                                       table_ref)
+    ``rows`` = 8 the next rank of each lane's run of the one matrix, from
+    its bf16 parts; ``scratch`` is then the runs' VMEM combinations."""
+    pid = pl.program_id(0)
 
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pid == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     if rows > 1:
-        dets = det_slabs(rank_row_slabs(a_ref[0], combos))
-        out_ref[0] += jnp.where(valid, signs * dets, 0.0)
+        (combo_ref,) = scratch
+        valid, start = lane_runs(pid, qinfo_ref[1], qinfo_ref[2], tile)
+
+        @pl.when(pid == 0)
+        def _unrank_starts():
+            # in-kernel unranking; guarded at the ops.py entry points
+            combos = unrank_lanes(  # reprolint: disable=overflow-guard
+                qinfo_ref[0] + start, n, m, table_ref)
+            for k, c in enumerate(combos):
+                combo_ref[k] = c
+
+        walk_run_step(n, m, a_ref[0], combo_ref, valid, out_ref)
         return
+    valid, combos, signs = _tile_setup(n, m, tile, qinfo_ref, table_ref)
     sel = selector_slabs(combos, n)
 
     def group(g, carry):
@@ -148,7 +215,7 @@ def radic_batched_grad_combo_kernel(n: int, m: int, tile: int, groups: int,
     cotangents back through that tile's terms (DESIGN_GRAD.md).  No
     residual crosses the tile boundary, so backward VMEM stays the
     forward's O(8·T·m²) per group."""
-    valid, combos, signs = _tile_setup(n, m, tile, 1, qinfo_ref, table_ref)
+    valid, combos, signs = _tile_setup(n, m, tile, qinfo_ref, table_ref)
     sel = selector_slabs(combos, n)
 
     @pl.when(pl.program_id(0) == 0)
@@ -184,30 +251,32 @@ def _ungrouped(a: jax.Array, B: int, m: int, n: int) -> jax.Array:
 
 
 def _pallas_tiles(body, padded_count: int, step: int, table, q_start,
-                  count, operands, out_shape, interpret, name: str):
+                  scalars, operands, out_shape, interpret, name: str,
+                  scratch=()):
     """One sequential grid step per ``step`` ranks; every operand and the
-    output are whole-array blocks resident in VMEM.  ``name`` is the HLO
+    output are whole-array blocks resident in VMEM, ``q_start`` and the
+    range's ``scalars`` (:func:`_steps` or :func:`_runs`) are in SMEM and
+    ``scratch`` is VMEM that persists across the grid.  ``name`` is the HLO
     instruction's name, which a device trace shows and the benchmark's
     kernel readers match (``tests/test_tpu_compile.py`` pins it)."""
 
     def whole(shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
 
-    qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
-                       *_steps(count, step)])
     return pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(max(1, -(-padded_count // step)),),
             in_specs=[whole(x.shape) for x in operands],
-            out_specs=whole(out_shape)),
+            out_specs=whole(out_shape), scratch_shapes=list(scratch)),
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=resolve_interpret(interpret),
         name=name,
-    )(qinfo, table.astype(jnp.int32).reshape(-1), *operands)
+    )(jnp.stack([jnp.asarray(q_start, jnp.int32), *scalars]),
+      table.astype(jnp.int32).reshape(-1), *operands)
 
 
 @functools.partial(jax.jit,
@@ -220,16 +289,24 @@ def radic_batched_partial_pallas(As: jax.Array, table: jax.Array,
     """Per-matrix Σ sign·det over ranks [q_start, q_start+count) for a
     shape-uniform stack ``As (B, m, n)`` -> ``(B,)``; ``padded_count`` is
     the static grid extent (≥ count, tile-aligned).  At B = 1 the kernel
-    puts 8 rank rows of the one matrix on the sublanes (:func:`rank_rows`)
-    and a grid step covers ``8·tile`` ranks."""
+    puts 8 rank rows of the one matrix on the sublanes (:func:`rank_rows`),
+    each lane walking its own run of ranks, and a grid step covers
+    ``8·tile`` ranks."""
     B, m, n = As.shape
     rows = rank_rows(B)
     a = _grouped(As) if rows == 1 else rank_row_operand(As[0])[None]
     G = a.shape[0]
+    step = rows * tile
+    if rows > 1:
+        scalars = _runs(count, step)
+        scratch = [pltpu.VMEM((m, SUBLANES, tile), jnp.int32)]
+    else:
+        scalars, scratch = _steps(count, step), []
     out = _pallas_tiles(
         functools.partial(radic_batched_combo_kernel, n, m, tile, rows, G),
-        padded_count, rows * tile, table, q_start, count, [a],
-        (G, SUBLANES, tile), interpret, "radic_batched_partial_pallas")
+        padded_count, step, table, q_start, scalars, [a],
+        (G, SUBLANES, tile), interpret, "radic_batched_partial_pallas",
+        scratch)
     if rows > 1:  # the sublanes first: a range under one row keeps its sum
         out = out.sum(axis=1)
     return out.reshape(-1, tile)[:B].sum(axis=1).astype(As.dtype)
@@ -240,7 +317,8 @@ def radic_partial_pallas(A: jax.Array, table: jax.Array,
                          padded_count: int, *, tile: int = 256,
                          interpret: bool | None = None) -> jax.Array:
     """Σ sign·det over ranks [q_start, q_start+count) of one ``A (m, n)``:
-    the batched kernel at B = 1, 8 rank rows per grid step."""
+    the batched kernel at B = 1, 8 rank rows per grid step, a run of
+    ranks per lane."""
     return radic_batched_partial_pallas(
         A[None], table, q_start, count, padded_count, tile=tile,
         interpret=interpret)[0]
@@ -265,6 +343,6 @@ def radic_batched_grad_partial_pallas(As: jax.Array, cts: jax.Array,
                 (0, G * SUBLANES - B)).reshape(G, SUBLANES, 1)
     out = _pallas_tiles(
         functools.partial(radic_batched_grad_combo_kernel, n, m, tile, G),
-        padded_count, tile, table, q_start, count, [a, c],
+        padded_count, tile, table, q_start, _steps(count, tile), [a, c],
         (G, SUBLANES * m, n), interpret, "radic_batched_grad_partial_pallas")
     return _ungrouped(out, B, m, n).astype(As.dtype)

@@ -7,19 +7,21 @@ contiguous windows of at most ``WINDOW_RANKS`` ranks (``plan_grains``'
 even cut) and unranks each window's start on the host with exact ints
 (``core.engine.plan_rank_windows``).  This kernel runs them all:
 
-    window w, step i ──offsets d < 2³¹ (int32)──► unrank relative to the
-                     window's start (common.unrank_window_lanes)
-                     ──► the B = 1 pipeline of radic_fused: 8 rank rows
-                     on the sublanes, gather from A's bf16 parts,
-                     pivoted GE, sign · mask
+    window w, step 0 ──each lane's run start, an int32 offset d < 2³¹──►
+                     unrank relative to the window's start, once
+                     (common.unrank_window_lanes) into VMEM scratch
+    window w, step i ──the B = 1 pipeline of radic_fused: 8 rank rows
+                     on the sublanes, a run of ranks per lane, gather
+                     from A's bf16 parts, pivoted GE, sign · mask, then
+                     each lane's successor (radic_fused.walk_run_step)
 
-The grid is ``(W, steps)``: each window's whole steps and tail (SMEM,
+The grid is ``(W, steps)``: each window's count and run length K (SMEM,
 ``qinfo``), its ``(n,)`` slacks (SMEM) and the Pascal table clipped at
-``INT32_MAX`` are scalar-prefetch operands; window w adds its per-lane
-terms into output block w, and the wrapper returns one partial per
-window.  Every window runs in one program with no host round trip.
-Plans within int32 never build this kernel: their programs are
-``radic_fused``'s, unchanged.
+``INT32_MAX`` are scalar-prefetch operands; lane l of window w walks the
+window's offsets ``[l·K, l·K + K)``, and window w adds its per-lane
+terms into output block w; the wrapper returns one partial per window.
+Every window runs in one program with no host round trip.  Plans within
+int32 never build this kernel: their programs are ``radic_fused``'s.
 """
 
 from __future__ import annotations
@@ -31,33 +33,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import (det_slabs, rank_row_operand, rank_row_slabs,
-                     sign_lanes, unrank_window_lanes)
-from .radic_fused import SUBLANES, lane_ranks, resolve_interpret
+from .common import rank_row_operand, unrank_window_lanes
+from .radic_fused import (SUBLANES, _runs, lane_runs, resolve_interpret,
+                          walk_run_step)
 
 __all__ = ["radic_windowed_combo_kernel", "radic_windowed_partial_pallas"]
 
 
 def radic_windowed_combo_kernel(n: int, m: int, tile: int, qinfo_ref,
-                                slack_ref, table_ref, a_ref, out_ref):
-    """Step ``i`` of window ``w``: 8 rank rows of the one matrix at window
-    offsets ``i·8T + s·T + lane``, as the B = 1 value kernel lays them
-    out; the window's per-lane terms go to block ``w``."""
+                                slack_ref, table_ref, a_ref, out_ref,
+                                combo_ref):
+    """Step ``i`` of window ``w``: the ``i``-th rank of each lane's run of
+    the window, as the B = 1 value kernel lays its runs out
+    (``radic_fused.lane_runs``); at ``i = 0`` every lane unranks its run's
+    start offset relative to the window's start.  The window's per-lane
+    terms go to block ``w``."""
     w = pl.program_id(0)
     pid = pl.program_id(1)
-    valid, ds = lane_ranks(pid, 0, qinfo_ref[2 * w], qinfo_ref[2 * w + 1],
-                           SUBLANES, tile)
-    # int32 window offsets; the windows are planned at the ops.py entry
-    combos = unrank_window_lanes(  # reprolint: disable=overflow-guard
-        ds, n, m, table_ref, slack_ref, w * n - 1)
-    signs = sign_lanes(combos, m)
+    valid, start = lane_runs(pid, qinfo_ref[2 * w], qinfo_ref[2 * w + 1],
+                             tile)
 
     @pl.when(pid == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
+        # int32 window offsets; the windows are planned at the ops.py entry
+        combos = unrank_window_lanes(  # reprolint: disable=overflow-guard
+            start, n, m, table_ref, slack_ref, w * n - 1)
+        for k, c in enumerate(combos):
+            combo_ref[k] = c
 
-    dets = det_slabs(rank_row_slabs(a_ref[0], combos))
-    out_ref[0] += jnp.where(valid, signs * dets, 0.0)
+    walk_run_step(n, m, a_ref[0], combo_ref, valid, out_ref)
 
 
 @functools.partial(jax.jit,
@@ -76,8 +81,7 @@ def radic_windowed_partial_pallas(A: jax.Array, table: jax.Array,
     parts = rank_row_operand(A)
     W = counts.shape[0]
     step = SUBLANES * tile
-    counts = jnp.asarray(counts, jnp.int32)
-    qinfo = jnp.stack([counts // step, counts % step], axis=1).reshape(-1)
+    qinfo = jnp.stack(_runs(counts, step), axis=1).reshape(-1)
 
     out = pl.pallas_call(
         functools.partial(radic_windowed_combo_kernel, n, m, tile),
@@ -87,7 +91,8 @@ def radic_windowed_partial_pallas(A: jax.Array, table: jax.Array,
             in_specs=[pl.BlockSpec((1, *parts.shape),
                                    lambda w, i, *_: (0, 0, 0))],
             out_specs=pl.BlockSpec((1, SUBLANES, tile),
-                                   lambda w, i, *_: (w, 0, 0))),
+                                   lambda w, i, *_: (w, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((m, SUBLANES, tile), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((W, SUBLANES, tile), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),

@@ -319,6 +319,10 @@ def test_plan_reports_kernel_gather(shape, plan_kw, gather):
     plan = DetEngine().plan(*shape, **plan_kw)
     assert plan.gather == gather
     assert (gather == "bf16x3") == (plan.rank_rows == 8)
+    # the rank walk follows the same layout: a run of successors per lane
+    # at B = 1 and windowed, an unranked tile per step for a stack
+    assert plan.walk == {"bf16x3": "successor",
+                         "f32_highest": "unrank"}.get(gather)
 
 
 def test_plan_build_span_carries_gather(tmp_path):
@@ -335,3 +339,5 @@ def test_plan_build_span_carries_gather(tmp_path):
     stats = [dict(ev.stats) for plane in planes for line in plane.lines
              for ev in line.events if ev.name == "det.plan.build"]
     assert [s.get("gather") for s in stats] == [g for *_, g in GATHER_PLANS]
+    assert [s.get("walk") for s in stats] == ["successor", "successor",
+                                              "unrank", "unrank"]

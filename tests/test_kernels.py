@@ -128,24 +128,34 @@ def test_rank_rows_full(m, n, rng):
     assert abs(got - want) <= 2e-3 * max(1.0, abs(want))
 
 
-@pytest.mark.parametrize("q0,cnt", [(3, 5), (5, 61), (13, 64), (61, 59),
-                                    (9, 111), (119, 1)])
-def test_rank_rows_partial_ranges(q0, cnt, rng):
-    """Ranges of C(10, 3) = 120 whose start falls inside a sub-tile."""
-    A = rng.normal(size=(3, 10)).astype(np.float32)
+@pytest.mark.parametrize("m,n,q0,cnt", [
+    (3, 10, 3, 5), (3, 10, 5, 61), (3, 10, 13, 64), (3, 10, 61, 59),
+    (3, 10, 9, 111), (3, 10, 119, 1),
+    # runs of K > 1 ranks per lane, the last lanes short or empty
+    (6, 16, 0, 8008), (4, 16, 3, 1800)])
+def test_rank_rows_partial_ranges(m, n, q0, cnt, rng):
+    """Ranges of C(10, 3) = 120 whose start falls inside a sub-tile, and
+    ranges longer than a step: the whole (6, 16) rank space, K = 126
+    ranks per lane, and K = 29 over (4, 16) with the last lane empty."""
+    A = rng.normal(size=(m, n)).astype(np.float32)
     got = float(ops.radic_det_pallas(jnp.asarray(A), q_start=q0, count=cnt,
                                      tile=8))
     want = ref.radic_partial_ref(A, q0, cnt)
     assert abs(got - want) <= 1e-3 * max(1.0, abs(want))
 
 
-@pytest.mark.parametrize("cuts", [[0, 5, 69, 70, 120], [0, 64, 120],
-                                  [0, 1, 2, 119, 120]])
-def test_rank_rows_partials_compose(cuts, rng):
-    A = rng.normal(size=(3, 10)).astype(np.float32)
+@pytest.mark.parametrize("m,n,cuts", [
+    (3, 10, [0, 5, 69, 70, 120]), (3, 10, [0, 64, 120]),
+    (3, 10, [0, 1, 2, 119, 120]),
+    (4, 16, [0, 1, 63, 64, 65, 1819, 1820])])
+def test_rank_rows_partials_compose(m, n, cuts, rng):
+    A = rng.normal(size=(m, n)).astype(np.float32)
     parts = [float(ops.radic_det_pallas(jnp.asarray(A), q_start=a,
                                         count=b - a, tile=8))
              for a, b in zip(cuts[:-1], cuts[1:])]
+    for a, b, got in zip(cuts[:-1], cuts[1:], parts):
+        want = ref.radic_partial_ref(A, a, b - a)
+        assert abs(got - want) <= 1e-3 * max(1.0, abs(want))
     whole = float(ops.radic_det_pallas(jnp.asarray(A), tile=8))
     want = ref.radic_det_oracle(A)
     assert abs(sum(parts) - want) <= 2e-3 * max(1.0, abs(want))
@@ -226,24 +236,68 @@ def test_rank_row_slabs_gather_exactly(m, n, rng):
                                           A[a][cols].view(np.uint32))
 
 
+def _run_tally(count, run, tile, grid):
+    """Over grid steps ``0 .. grid − 1`` of :func:`lane_runs`: each lane's
+    number of valid steps and its last valid step (−1 if none)."""
+    import jax
+    from repro.kernels.radic_fused import SUBLANES, lane_runs
+    chunk = 1024
+
+    def body(c, carry):
+        hits, last = carry
+        pid = (c * chunk + jnp.arange(chunk, dtype=jnp.int32))[:, None, None]
+        valid, _ = lane_runs(pid, count, run, tile)
+        valid = valid & (pid < grid)
+        return (hits + valid.sum(axis=0, dtype=jnp.int32),
+                jnp.maximum(last, jnp.where(valid, pid, -1).max(axis=0)))
+
+    zero = jnp.zeros((SUBLANES, tile), jnp.int32)
+    hits, last = jax.jit(lambda: jax.lax.fori_loop(
+        0, -(-grid // chunk), body, (zero, zero - 1)))()
+    return np.asarray(hits, np.int64), np.asarray(last, np.int64)
+
+
 @pytest.mark.parametrize("rows", [1, 8])
 def test_lane_ranks_bounded_by_count(rows):
     """The in-kernel rank offsets, outside a kernel: a grid that runs
     past a range ending near 2³¹ − 1 forms no offset beyond the range,
-    so nothing wraps int32, and exactly ``count`` lanes are valid."""
-    from repro.kernels.radic_fused import _steps, lane_ranks
+    so nothing wraps int32, and exactly ``count`` (lane, step) pairs are
+    valid.  ``rows`` 1: the tile layout of a stack (B ≥ 2), each step
+    its own tile.  ``rows`` 8: the run layout of B = 1, each lane its own
+    run of ranks, walked by successors from the one start it unranks; the
+    runs' valid steps tile the range, so each rank appears once."""
+    from repro.kernels.radic_fused import (_runs, _steps, lane_ranks,
+                                           lane_runs)
     tile = 256
     step = rows * tile
     q0 = 5
     count = 2 ** 31 - 1 - q0 - 3            # ends 3 ranks short of 2³¹ − 1
-    full, tail = (int(x) for x in _steps(count, step))
-    assert full * step + tail == count
     grid = -(-count // step) + 2            # two steps past the range
     assert grid * step > 2 ** 31 - 1        # the old offsets would wrap
+    if rows == 8:
+        count_, run = (int(x) for x in _runs(count, step))
+        assert count_ == count and run == -(-count // step)
+        _, start = lane_runs(jnp.int32(0), jnp.int32(count), jnp.int32(run),
+                             tile)
+        start = np.asarray(start, np.int64)
+        lane = np.arange(step).reshape(rows, tile)
+        np.testing.assert_array_equal(start, np.minimum(lane * run,
+                                                        count - 1))
+        assert (q0 + start >= q0).all() and (q0 + start < q0 + count).all()
+        hits, last = _run_tally(count, run, tile, grid)
+        np.testing.assert_array_equal(last, hits - 1)   # a prefix of steps
+        assert hits.sum() == count
+        hits, start = hits.reshape(-1), start.reshape(-1)
+        ends = np.cumsum(hits)                           # runs tile the range
+        live = hits > 0
+        np.testing.assert_array_equal(start[live], (ends - hits)[live])
+        return
+    full, tail = (int(x) for x in _steps(count, step))
+    assert full * step + tail == count
     seen = 0
     for pid in (0, full - 1, full, full + 1, grid - 1):
         valid, qs = lane_ranks(jnp.int32(pid), jnp.int32(q0),
-                               jnp.int32(full), jnp.int32(tail), rows, tile)
+                               jnp.int32(full), jnp.int32(tail), tile)
         valid, qs = np.asarray(valid), np.asarray(qs, np.int64)
         assert (qs >= q0).all() and (qs <= q0 + count).all()
         want = q0 + pid * step + np.arange(step).reshape(rows, tile)
@@ -254,3 +308,28 @@ def test_lane_ranks_bounded_by_count(rows):
         elif pid > full:
             assert not valid.any()
     assert seen == 2 * step + tail
+
+
+@pytest.mark.parametrize("n,m", [(5, 1), (10, 3), (32, 12), (38, 12),
+                                 (6, 6)])
+def test_successor_lanes(n, m):
+    """``common.successor_lanes`` on ``(8, T)`` slabs, as the run layout
+    reads them, against ``successor_py``: random ranks, the first member,
+    and the last member, which maps to itself."""
+    import random
+    from repro.core.unrank import successor_py, unrank_py
+    from repro.kernels.common import successor_lanes
+    S, T = 8, 128
+    total = comb(n, m)
+    rnd = random.Random(n * 100 + m)
+    qs = [0, total - 1] + [rnd.randrange(total) for _ in range(S * T - 2)]
+    combos = np.array([unrank_py(q, n, m) for q in qs], np.int32)
+    rows = [jnp.asarray(combos[:, k].reshape(S, T)) for k in range(m)]
+    got = np.stack([np.asarray(r).reshape(-1)
+                    for r in successor_lanes(rows, n)], axis=1)
+    for q, c, g in zip(qs, combos, got):
+        want = successor_py(tuple(c), n)
+        if q == total - 1:
+            assert want is None and tuple(g) == tuple(c)
+        else:
+            assert tuple(g) == want == unrank_py(q + 1, n, m)
