@@ -6,34 +6,27 @@ its grain start once (combinatorial addition) and then walks successors
 inside the grain.  Here a "processor" is a mesh device; the tree-sum of the
 PRAM CREW analysis becomes a single ``psum`` over the mesh axes.
 
-Two modes:
-
 * ``"grains"`` (default) — grain starts are unranked on the **host with
-  exact bigints** (no integer-width limit, works for astronomically large
-  ``C(n,m)``); devices enumerate successors lock-step across their local
-  grains via a vectorized ``scan``.  This is the faithful port of the
+  exact bigints** (no integer-width limit); devices enumerate successors
+  lock-step across their local grains via a vectorized ``scan``: the
   paper's per-processor loop.
 * ``"flat"`` — every rank is unranked independently on-device (the
-  maximally-parallel PRAM-CRCW shape).  Requires ``C(n,m) < 2**31``
-  (int32 ranks); supports the fused Pallas kernel backend.
+  PRAM-CRCW shape).  Requires ``C(n,m) < 2**31`` (int32 ranks).
 
-With ``backend="pallas"`` both modes run the fused kernel: a device's
-grains are one contiguous rank range, handed to the kernel as
-``(q_start, count)``, so the int32 limit applies to grains too.
+With ``backend="pallas"`` both modes run the fused kernel on a device's
+grains as one rank range ``(q_start, count)``; past ``WINDOW_RANKS``
+ranks, as that range's rank windows (:func:`plan_grain_windows`).
 
 This module is the engine's mesh backend (DESIGN_ENGINE.md): the
-``make_*_evaluator`` makers bind the plan-time half — validation, grain
-planning with host-bigint unranking, Pascal table, the ``shard_map``-built
-worker — once per shape, and the public ``radic_det*_distributed``
-wrappers route through :class:`repro.core.engine.DetEngine` so repeated
-same-shape calls reuse the planned worker instead of re-unranking grain
-starts every call.  All ``shard_map`` use goes through
+``make_*_evaluator`` makers bind the plan-time half (validation, grain
+planning with host-bigint unranking, tables, the ``shard_map``-built
+worker) once per shape; ``radic_det*_distributed`` route through
+:class:`repro.core.engine.DetEngine`.  All ``shard_map`` use goes through
 :mod:`repro.parallel.compat`.
 
 Straggler mitigation: ``grains_per_device > 1`` oversubscribes grains so a
-slow device's tail work can be speculatively re-executed by the runtime
-(see ``repro.runtime.stragglers``); the reduction is idempotent because
-grain partials are keyed by grain id.
+slow device's tail work can be re-executed (``repro.runtime.stragglers``);
+grain partials are keyed by grain id, so the reduction is idempotent.
 """
 
 from __future__ import annotations
@@ -49,14 +42,16 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.parallel.compat import psum_scalar, pvary, shard_map
 
-from .engine import rank_table, validate_rank_space
-from .pascal import INT32_MAX
+from .engine import (RankWindows, plan_rank_windows, rank_table,
+                     validate_rank_space, window_count)
+from .pascal import INT32_MAX, comb
 from .radic import signed_minor_sum, signed_minor_sum_batched
 from .unrank import successor_jnp, unrank_jnp, unrank_py
 
 __all__ = ["radic_det_distributed", "radic_det_batched_distributed",
            "make_distributed_evaluator", "make_batched_distributed_evaluator",
-           "make_batched_distributed_grad_evaluator", "plan_grains"]
+           "make_batched_distributed_grad_evaluator", "plan_grains",
+           "plan_grain_windows"]
 
 
 def plan_grains(total: int, num_grains: int):
@@ -83,24 +78,29 @@ def make_distributed_evaluator(
     mode: Literal["grains", "flat"] = "grains",
     chunk: int = 1024,
     backend: Literal["jnp", "pallas"] = "jnp",
+    windows: RankWindows | None = None,
 ):
     """Bind the host-side half of a mesh evaluation once for one (m, n).
 
-    Grain planning (including the host-bigint grain-start unranking — the
+    Grain planning (with the host-bigint grain-start unranking, the
     expensive part for astronomical C(n, m)), the Pascal table and the
-    ``shard_map``-built worker are all constructed here; the returned
-    ``evaluate(A: (m, n)) -> scalar`` only enters device code.  ``A`` is
-    replicated (it is tiny); the rank space is sharded; the result is a
-    replicated scalar.  m > n is normalized by the engine before this
-    maker runs.
+    ``shard_map``-built worker are built here; the returned
+    ``evaluate(A: (m, n)) -> scalar`` only enters device code: ``A``
+    replicated, the rank space sharded, the result a replicated scalar.
+    m > n is normalized by the engine first.  Past ``WINDOW_RANKS`` ranks
+    the Pallas backend runs ``windows`` (:func:`plan_grain_windows`, which
+    a plan computes once; without it they are planned here).
     """
     axes = tuple(axis_names if axis_names is not None else mesh.axis_names)
     D = math.prod(mesh.shape[a] for a in axes)
-    # With the Pallas backend a device's grains are one contiguous rank
-    # range, which the kernel takes as (q_start, count): the int32 path.
+    # Pallas: a device's grains are one contiguous rank range, (q_start,
+    # count) within int32, the grain's rank windows past WINDOW_RANKS.
     kernel = mode == "flat" or backend == "pallas"
-    total = validate_rank_space(m, n, backend=backend,
-                                mesh_grains=not kernel)
+    total = validate_rank_space(m, n, backend=backend, mesh_grains=not kernel,
+                                windowed=backend == "pallas")
+    if backend == "pallas" and window_count(total) is not None:
+        return _make_windowed(m, n, mesh, axes, windows if windows is not None
+                              else plan_grain_windows(m, n, D))
     if kernel:
         return _make_flat(m, n, mesh, axes, D, total, chunk, backend)
     G = D * grains_per_device
@@ -185,6 +185,59 @@ def _make_flat(m, n, mesh, axes, D, total, chunk, backend):
 
     def evaluate(A: jax.Array) -> jax.Array:
         return worker(jnp.asarray(A), table, starts_q, lengths_a)
+
+    return evaluate
+
+
+def plan_grain_windows(m: int, n: int, devices: int) -> RankWindows:
+    """The rank windows of a Pallas mesh plan past ``WINDOW_RANKS`` ranks.
+
+    :func:`plan_grains` cuts ``[0, C(n, m))`` into one grain per device,
+    and :func:`~repro.core.engine.plan_rank_windows` cuts each grain into
+    its own windows, each start unranked on the host with exact ints (a
+    ``det.plan.windows`` span per grain, metadata ``grain``).  A grain
+    with fewer windows than the most, W_d, is padded with empty windows
+    (no ranks, zero slacks), so window ``d·W_d + w`` is window w of
+    device d's grain: D·W_d windows in all, grain-major.
+    """
+    starts_q, lengths = plan_grains(comb(n, m), devices)
+    grains = [plan_rank_windows(m, n, q, count, grain=g)
+              for g, (q, count) in enumerate(zip(starts_q, lengths))]
+    W = max(len(w) for w in grains)
+    starts, counts, slacks = [], [], []
+    for w in grains:
+        pad = W - len(w)
+        starts += list(w.starts) + [w.starts[-1] + w.lengths[-1]] * pad
+        counts += list(w.lengths) + [0] * pad
+        slacks += [w.slacks, np.zeros((pad, n), np.int32)]
+    return RankWindows(starts=tuple(starts), lengths=tuple(counts),
+                       slacks=np.concatenate(slacks))
+
+
+def _make_windowed(m, n, mesh, axes, windows: RankWindows):
+    """Each device's grain as rank windows: device d runs its W_d windows
+    (rows ``[d·W_d, (d + 1)·W_d)`` of the counts and slacks, sharded over
+    ``axes``) in one program of the windowed kernel and adds their
+    partials, and one ``psum`` adds the devices'.  The clipped Pascal
+    table and the grid extent of the longest window are bound here."""
+    from repro.kernels import ops  # lazy: kernels depend on core
+    table = jnp.asarray(ops.capped_table(n, m))
+    padded, _ = ops.window_grid(windows.lengths)
+    counts = jnp.asarray(np.asarray(windows.lengths, np.int32))
+    slacks = jnp.asarray(windows.slacks)
+
+    # check_vma=False: pallas_call outputs don't carry vma metadata yet
+    @jax.jit
+    @functools.partial(
+        shard_map, mesh=mesh, check_vma=False,
+        in_specs=(P(), P(), P(axes), P(axes)), out_specs=P())
+    def worker(A_rep, tab, cnt, slk):
+        parts = ops.radic_windowed_partial_pallas(A_rep, tab, cnt, slk,
+                                                  padded)
+        return psum_scalar(parts.sum().astype(A_rep.dtype), axes)
+
+    def evaluate(A: jax.Array) -> jax.Array:
+        return worker(jnp.asarray(A), table, counts, slacks)
 
     return evaluate
 

@@ -35,6 +35,8 @@ pallas                ``kernels.ops.radic_det[_batched]_pallas``
 pallas, scalar, past  ``kernels.ops.radic_det_pallas`` over the
   ``WINDOW_RANKS``    plan's rank windows (values only)
 mesh                  ``core.distributed`` maker (via compat.py)
+mesh, pallas, scalar  the same maker, each device's grain as its
+  past WINDOW_RANKS   own rank windows (values only)
 ====================  ==========================================
 
 All shard_map use stays inside :mod:`repro.core.distributed` and hence
@@ -45,6 +47,7 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import math
 import os
 import threading
 from collections import OrderedDict
@@ -94,12 +97,13 @@ def validate_rank_space(m: int, n: int, *, backend: str = "jnp",
     — no path may enter a kernel with an overflowing rank space.
 
     * ``pallas`` — the kernels compute ranks in int32 regardless of x64,
-      so batched, mesh and gradient programs require ``C(n, m) < 2**31``.
+      so batched and gradient programs require ``C(n, m) < 2**31``.
       ``windowed`` is the one exception: the values of one matrix (B = 1)
-      past :data:`WINDOW_RANKS` ranks run as contiguous rank windows
-      (:func:`plan_rank_windows`), and the windowed kernel unranks each
-      lane relative to its window's start, in int32.  The caller plans
-      the windows; :func:`window_count` says whether there are any.
+      past :data:`WINDOW_RANKS` ranks, on one device or over a mesh, run
+      as contiguous rank windows (:func:`plan_rank_windows`), and the
+      windowed kernel unranks each lane relative to its window's start,
+      in int32.  The caller plans the windows; :func:`window_count` says
+      whether there are any.
     * ``jnp`` — int32 ranks unless x64 is enabled (then int64).
     * ``mesh_grains`` — grain starts are unranked on the host with exact
       bigints; no width limit at all.
@@ -113,9 +117,10 @@ def validate_rank_space(m: int, n: int, *, backend: str = "jnp",
                 f"C({n},{m}) = {total} exceeds int32 (the Pallas kernels "
                 "compute ranks in int32 regardless of x64).  Past int32 "
                 "the Pallas backend computes the value of one matrix, in "
-                "rank windows (radic_det(A, backend='pallas')); batched, "
-                "mesh and gradient programs need C(n, m) < 2**31.  Use "
-                "the jnp distributed grain mode for those.")
+                "rank windows, on one device or over a mesh (radic_det or "
+                "radic_det_distributed with backend='pallas'); batched "
+                "and gradient programs need C(n, m) < 2**31.  Use the jnp "
+                "distributed grain mode for those.")
     else:
         if total > INT32_MAX and not jax.config.jax_enable_x64:
             raise OverflowError(
@@ -171,17 +176,19 @@ class RankWindows:
         return len(self.starts)
 
 
-def plan_rank_windows(m: int, n: int, q_start: int,
-                      count: int) -> RankWindows:
+def plan_rank_windows(m: int, n: int, q_start: int, count: int, *,
+                      grain: int | None = None) -> RankWindows:
     """Cut ranks ``[q_start, q_start + count)`` into the fewest windows of
     at most :data:`WINDOW_RANKS` ranks, ``plan_grains``' even cut, and
     unrank each window's start on the host with exact ints.  Runs in a
     ``det.plan.windows`` profiler span (metadata ``windows``,
-    ``window_ranks``: the count and the longest)."""
+    ``window_ranks``: the count and the longest; and ``grain``, the
+    index of a mesh device's grain, where given)."""
     from .distributed import plan_grains  # lazy: distributed builds on us
     W = window_count(count) or 1
+    meta = {} if grain is None else {"grain": grain}
     with jax.profiler.TraceAnnotation("det.plan.windows", windows=W,
-                                      window_ranks=-(-count // W)):
+                                      window_ranks=-(-count // W), **meta):
         starts, lengths = plan_grains(count, W)
         starts = tuple(q_start + q for q in starts)
         slacks = np.stack([window_slacks(q, n, m) if length
@@ -319,14 +326,26 @@ def kernel_walk(key: PlanKey) -> str | None:
     return rank_walk(kernel_rank_rows(key))
 
 
+def _mesh_devices(key: PlanKey) -> int:
+    """Devices a mesh plan cuts the rank space over: the product of the
+    sizes of its axes (every axis where the key names none)."""
+    axes = key.axis_names or key.mesh.axis_names
+    return math.prod(key.mesh.shape[a] for a in axes)
+
+
 def kernel_windows(key: PlanKey) -> int | None:
-    """Rank windows of the plan's value program: the scalar single-device
-    Pallas plans past :data:`WINDOW_RANKS` run in :func:`window_count`
-    windows; ``None`` for every other plan."""
-    if (key.backend != "pallas" or key.batched or key.mesh is not None
-            or key.m > key.n):
+    """Rank windows of the plan's value program: a scalar Pallas plan past
+    :data:`WINDOW_RANKS` runs :func:`window_count` windows on one device,
+    and over a mesh of D devices D · W_d, each device's grain
+    (``plan_grains``) in W_d windows, the count of its longest grain;
+    ``None`` for every other plan."""
+    if key.backend != "pallas" or key.batched or key.m > key.n:
         return None
-    return window_count(comb(key.n, key.m))
+    total = comb(key.n, key.m)
+    if key.mesh is None or window_count(total) is None:
+        return window_count(total)
+    D = _mesh_devices(key)
+    return D * (window_count(-(-total // D)) or 1)
 
 
 def _make_differentiable(primal: Callable, grad_fn: Callable) -> Callable:
@@ -420,7 +439,8 @@ class DetPlan:
     grad_executable: Callable = field(repr=False)
     differentiable: Callable = field(repr=False)
     # Rank windows of the value program (:func:`kernel_windows`): W for a
-    # scalar Pallas plan past WINDOW_RANKS ranks, None for the others.
+    # scalar Pallas plan past WINDOW_RANKS ranks (D · W_d over a mesh),
+    # None for the others.
     windows: int | None = None
 
     @property
@@ -492,6 +512,7 @@ class DetEngine:
         "_store_hits": ("_lock",),
         "_store_misses": ("_lock",),
         "_windows": ("_lock",),
+        "_grid_ranks": ("_lock",),
     }
 
     def __init__(self, max_plans: int = 128,
@@ -507,6 +528,7 @@ class DetEngine:
         self._store_hits = 0
         self._store_misses = 0
         self._windows = 0
+        self._grid_ranks = 0
         # Optional durable plan store (DESIGN_PERSIST.md): consulted on
         # cache misses, written back asynchronously after fresh builds.
         self.store = None
@@ -612,14 +634,18 @@ class DetEngine:
     # ------------------------------------------------------------- the cache
     def cache_info(self) -> dict:
         """Plan-cache counters; ``windows`` counts the rank windows that
-        windowed plans have dispatched (W per call)."""
+        windowed plans have dispatched (W per call, D · W_d over a mesh),
+        ``grid_ranks`` the rank slots their grids covered: per call W ·
+        steps · 8 · tile (``kernels.ops.window_grid``), real ranks and
+        padding alike."""
         with self._lock:
             return {"size": len(self._plans), "max_plans": self.max_plans,
                     "hits": self._hits, "misses": self._misses,
                     "evictions": self._evictions,
                     "store_hits": self._store_hits,
                     "store_misses": self._store_misses,
-                    "windows": self._windows}
+                    "windows": self._windows,
+                    "grid_ranks": self._grid_ranks}
 
     def cached_keys(self) -> list[PlanKey]:
         """LRU order, oldest first (introspection/tests)."""
@@ -908,14 +934,23 @@ class DetEngine:
     def _build_windowed(self, key: PlanKey, total: int, ops) -> DetPlan:
         """One matrix past WINDOW_RANKS ranks: the windows are planned
         here, once, and every call runs them all in one program of the
-        windowed kernel.  Values only: no gradient program is built."""
+        windowed kernel."""
         windows = plan_rank_windows(key.m, key.n, 0, total)
-        run = functools.partial(ops.radic_det_pallas, q_start=0,
-                                count=total, windows=windows)
+        return self._windowed_plan(key, total, windows, functools.partial(
+            ops.radic_det_pallas, q_start=0, count=total, windows=windows))
 
-        def execute(A, _run=run, _w=len(windows)):
+    def _windowed_plan(self, key: PlanKey, total: int, windows,
+                       run: Callable) -> DetPlan:
+        """The plan of a windowed program ``run`` over ``windows``, on one
+        device or over a mesh: each call counts its windows and its grid's
+        rank slots.  Values only: no gradient program is built."""
+        from repro.kernels.ops import window_grid  # kernels import core
+        _, slots = window_grid(windows.lengths)
+
+        def execute(A, _run=run, _w=len(windows), _s=slots):
             with self._lock:
                 self._windows += _w
+                self._grid_ranks += _s
             return _run(A)
 
         why = (f"C({key.n},{key.m}) = {total} exceeds int32: the windowed "
@@ -935,7 +970,14 @@ class DetEngine:
     def _build_mesh(self, key: PlanKey, total: int) -> DetPlan:
         from .distributed import (make_batched_distributed_evaluator,
                                   make_batched_distributed_grad_evaluator,
-                                  make_distributed_evaluator)
+                                  make_distributed_evaluator,
+                                  plan_grain_windows)
+        if not key.batched and kernel_windows(key) is not None:
+            windows = plan_grain_windows(key.m, key.n, _mesh_devices(key))
+            return self._windowed_plan(
+                key, total, windows, make_distributed_evaluator(
+                    key.m, key.n, mesh=key.mesh, axis_names=key.axis_names,
+                    backend=key.backend, windows=windows))
         if key.batched:
             execute = make_batched_distributed_evaluator(
                 key.m, key.n, mesh=key.mesh, axis_names=key.axis_names,

@@ -12,15 +12,15 @@ from repro.core.engine import (plan_rank_windows, validate_rank_space,
                                window_count)
 from repro.core.pascal import INT32_MAX, binom_table
 
-from .radic_fused import (radic_batched_grad_partial_pallas,
+from .radic_fused import (SUBLANES, radic_batched_grad_partial_pallas,
                           radic_batched_partial_pallas,
                           radic_partial_pallas)
-from .radic_windowed import radic_windowed_partial_pallas
+from .radic_windowed import grid_steps, radic_windowed_partial_pallas
 
 __all__ = ["radic_partial_pallas", "radic_det_pallas", "capped_table",
            "radic_windowed_partial_pallas", "radic_batched_partial_pallas",
            "radic_det_batched_pallas", "radic_det_grad_pallas",
-           "radic_det_batched_grad_pallas"]
+           "radic_det_batched_grad_pallas", "window_grid"]
 
 
 def _grid_extent(ranks: int, tile: int) -> int:
@@ -130,3 +130,12 @@ def radic_det_grad_pallas(A: jax.Array, ct, q_start: int = 0,
     cts = jnp.reshape(jnp.asarray(ct, A.dtype), (1,))
     return radic_det_batched_grad_pallas(
         A[None], cts, q_start, count, tile=tile, interpret=interpret)[0]
+
+
+def window_grid(lengths, tile: int = 256) -> tuple[int, int]:
+    """The windowed kernel's grid over windows of ``lengths`` ranks: the
+    static grid extent of the longest, and the rank slots the whole grid
+    covers, W · steps · 8 · ``tile``, which ``DetEngine`` counts as
+    ``grid_ranks``."""
+    padded = _grid_extent(max(lengths), tile)
+    return padded, len(lengths) * grid_steps(padded, tile) * SUBLANES * tile

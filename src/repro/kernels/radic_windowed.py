@@ -87,7 +87,7 @@ def radic_windowed_partial_pallas(A: jax.Array, table: jax.Array,
         functools.partial(radic_windowed_combo_kernel, n, m, tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(W, max(1, -(-padded_count // step))),
+            grid=(W, grid_steps(padded_count, tile)),
             in_specs=[pl.BlockSpec((1, *parts.shape),
                                    lambda w, i, *_: (0, 0, 0))],
             out_specs=pl.BlockSpec((1, SUBLANES, tile),
@@ -102,3 +102,9 @@ def radic_windowed_partial_pallas(A: jax.Array, table: jax.Array,
       table.astype(jnp.int32).reshape(-1), parts[None])
     # the sublanes and lanes of a window first, as the B = 1 kernel sums
     return out.sum(axis=(1, 2))
+
+
+def grid_steps(padded_count: int, tile: int) -> int:
+    """Grid steps of each window: the longest window's grid extent
+    ``padded_count`` in steps of 8 · ``tile`` ranks, at least one."""
+    return max(1, -(-padded_count // (SUBLANES * tile)))

@@ -12,8 +12,9 @@ entry point, whose 8 rank rows per step must stay one kernel under the
 benchmark's name and gather from A's bf16 parts; the batched combo
 kernel at the serving bound ``BucketPolicy.max_batch`` = 64 with
 (8, 24); the gradient kernel at B = 16 with (8, 24); the four-chip
-grains program at (12, 32); and the windowed B = 1 program at (12, 38),
-C(38, 12) = 2,707,475,148 > 2³¹ − 1 ranks, in two rank windows.
+grains program at (12, 32); the windowed B = 1 program at (12, 38),
+C(38, 12) = 2,707,475,148 > 2³¹ − 1 ranks, in two rank windows; and the
+four-chip windowed program at (12, 42), two rank windows per chip.
 """
 
 import jax
@@ -165,6 +166,32 @@ def test_mesh_grains_four_chips(topo, no_persistent_cache, monkeypatch):
     _check(compiled, "mesh grains (12, 32) on 2x2")
     assert "all-reduce" in compiled.as_text()
     assert "radic_batched_partial_pallas" in _op_names(compiled)
+    # within int32 the mesh program holds no windowed op
+    assert "radic_windowed_partial_pallas" not in _op_names(compiled)
+
+
+def test_mesh_windows_four_chips_past_int32(topo, no_persistent_cache,
+                                            monkeypatch):
+    """The (12, 42) program of ``radic_det_distributed(backend="pallas")``
+    over the 2x2 host: C(42, 12) = 11,058,116,888 ranks, each chip's grain
+    in 2 rank windows, one windowed kernel per chip (the SPMD program is
+    each chip's) gathering from A's bf16 parts, and an all-reduce."""
+    from repro.core.distributed import make_distributed_evaluator
+    from repro.kernels import radic_windowed as rw
+    steer = lambda i: False if i is None else i  # noqa: E731
+    monkeypatch.setattr(rf, "resolve_interpret", steer)
+    monkeypatch.setattr(rw, "resolve_interpret", steer)
+    mesh = Mesh(np.array(topo.devices), ("workers",))
+    ev = make_distributed_evaluator(12, 42, mesh=mesh, backend="pallas")
+    compiled = jax.jit(ev).lower(
+        _spec((12, 42), NamedSharding(mesh, P()))).compile()
+    _check(compiled, "mesh windows (12, 42) on 2x2")
+    assert "all-reduce" in compiled.as_text()
+    ops_ = _op_list(compiled)
+    assert ops_.count("radic_windowed_partial_pallas") == 1
+    assert "radic_batched_partial_pallas" not in ops_
+    assert _parts_operand(12, 42) in _kernel_line(
+        compiled, "radic_windowed_partial_pallas")
 
 
 @pytest.mark.parametrize("m,n,windows", [(12, 38, 2), (20, 40, 65)])

@@ -8,7 +8,9 @@ its window's start.  Here: the cut tiles the rank space; the windowed
 unranking, as plain jnp, equals ``unrank_py`` of the global rank; the
 kernel in interpret mode, with the window limit patched small, equals
 the oracle; the counters and spans report the windows; and what stays
-refused past int32 (batched, mesh, gradient) says so.
+refused past int32 (batched plans, on one device or over a mesh, and
+gradients) says so.  The mesh's windowed path runs in
+``tests/test_distributed.py``.
 """
 
 import functools
@@ -197,6 +199,8 @@ def test_cache_info_counts_dispatched_windows(monkeypatch):
     plan(A)
     plan(A)
     assert eng.cache_info()["windows"] == 2 * plan.windows == 16
+    # 8 windows of at most 99 ranks: one grid step of 8 · 256 each
+    assert eng.cache_info()["grid_ranks"] == 2 * 8 * 1 * 8 * 256
 
 
 def test_plan_spans_carry_the_windows(monkeypatch, tmp_path):
@@ -227,12 +231,35 @@ def test_batched_past_int32_refused():
         ops.radic_det_batched_pallas(jnp.ones((2, 12, 38), jnp.float32))
 
 
-def test_mesh_past_int32_refused():
+def _one_device_mesh():
     from jax.sharding import Mesh
-    mesh = Mesh(np.array(jax.devices()[:1]), ("workers",))
-    with pytest.raises(OverflowError, match="mesh"):
-        DetEngine().plan(12, 38, batched=False, backend="pallas",
-                         mesh=mesh)
+    return Mesh(np.array(jax.devices()[:1]), ("workers",))
+
+
+def test_mesh_batched_past_int32_refused():
+    """A scalar Pallas mesh plan runs past int32 as rank windows; a
+    batched one is still refused, with a message that says so."""
+    with pytest.raises(OverflowError, match="batched"):
+        DetEngine().plan(12, 38, capacity=1, backend="pallas",
+                         mesh=_one_device_mesh())
+
+
+def test_mesh_gradient_past_int32_refused_before_any_kernel(monkeypatch):
+    """A scalar Pallas mesh plan past int32 builds no gradient program:
+    jax.grad and plan.grad raise at once and the kernel never runs."""
+    ran = []
+    monkeypatch.setattr(ops, "radic_windowed_partial_pallas",
+                        lambda *a, **k: ran.append(1))
+    eng = DetEngine()
+    plan = eng.plan(12, 38, batched=False, backend="pallas",
+                    mesh=_one_device_mesh())
+    assert plan.windows == 2
+    A = jnp.ones((12, 38), jnp.float32)
+    with pytest.raises(OverflowError, match="values only"):
+        jax.grad(plan.differentiable)(A)
+    with pytest.raises(OverflowError, match="values only"):
+        plan.grad(A, 1.0)
+    assert ran == [] and eng.cache_info()["windows"] == 0
 
 
 def test_gradient_past_int32_refused_before_any_kernel(monkeypatch):
